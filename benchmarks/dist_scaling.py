@@ -152,6 +152,8 @@ def bench_dist_scaling():
 
 
 def main() -> None:
+    from repro import env
+    env.enable_compile_cache()
     t0 = time.perf_counter()
     rows, derived = bench_dist_scaling()
     us = (time.perf_counter() - t0) * 1e6

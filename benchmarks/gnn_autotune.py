@@ -103,6 +103,7 @@ def main() -> None:
 
     from repro import env
     env.pin_for_benchmarks()
+    env.enable_compile_cache()
     rows, derived = bench_gnn_autotune(budget=args.budget,
                                        backend=args.backend)
     print(derived)

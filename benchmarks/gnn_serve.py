@@ -185,6 +185,7 @@ def main() -> None:
 
     from repro import env
     env.pin_for_benchmarks()
+    env.enable_compile_cache()
     backends = tuple(b.strip() for b in args.backends.split(",") if b.strip())
     rows, derived = bench_gnn_serve(backends=backends, plan=args.plan,
                                     tune_budget=args.tune_budget)

@@ -164,6 +164,8 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro import env
+    env.enable_compile_cache()
     t0 = time.perf_counter()
     runs = {}
     for mode in ("targeted", "full"):

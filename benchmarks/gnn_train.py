@@ -197,6 +197,7 @@ def main() -> None:
 
     from repro import env
     env.pin_for_benchmarks()
+    env.enable_compile_cache()
     backends = tuple(b.strip() for b in args.backends.split(",") if b.strip())
     payload = {
         "train": bench_training(backends=backends, plan=args.plan,

@@ -26,6 +26,7 @@ def _run(name: str, fn, *args):
 def main() -> None:
     from repro import env
     env.pin_for_benchmarks()
+    env.enable_compile_cache()
 
     from benchmarks.gnn_autotune import bench_gnn_autotune
     from benchmarks.gnn_serve import bench_gnn_serve

@@ -98,7 +98,7 @@ def trace_stability(fn, calls, *, name: str,
 # --------------------------------------------------------------------------
 
 def _iter_sub_jaxprs(params: dict):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     for v in params.values():
         vs = v if isinstance(v, (list, tuple)) else (v,)
         for item in vs:

@@ -13,8 +13,8 @@ Three pieces:
     ``shard_map`` (data axis = contiguous dst-shard row groups, model
     axis = feature blocks).
 
-:mod:`repro.dist.compat` papers over jax-version differences in mesh
-construction (``AxisType`` only exists on jax >= 0.5).
+:mod:`repro.dist.compat` builds meshes with explicit ``AxisType.Auto``
+axes.
 """
 from repro.dist.compat import abstract_mesh, make_mesh
 from repro.dist.hlo_analysis import (CollectiveStats, analyze_collectives,

@@ -1,51 +1,20 @@
-"""Mesh construction across jax versions.
+"""Mesh construction with explicit Auto axis types.
 
-jax >= 0.5 takes ``AbstractMesh(shape, axes, axis_types=...)`` and
-``jax.make_mesh(..., axis_types=...)``; jax 0.4.x has neither ``AxisType``
-nor the positional-axes AbstractMesh signature (and the oldest 0.4.x lack
-``AbstractMesh``/``jax.make_mesh`` entirely). Everything in this repo that
-builds a mesh goes through these two helpers so launch/mesh.py,
-tests/test_dist.py and the sharded GNN runtime work on any of them —
-importing this module never raises; only ``abstract_mesh`` raises (at
-call time) when the running jax truly has no AbstractMesh.
+Everything in this repo that builds a mesh goes through these two helpers
+(launch/mesh.py, tests/test_dist.py, the sharded GNN runtime), so every
+mesh carries the same ``AxisType.Auto`` axes.
 """
 from __future__ import annotations
 
-import math
-
 import jax
-
-try:
-    from jax.sharding import AbstractMesh
-except ImportError:  # very old jax 0.4.x
-    AbstractMesh = None
-
-try:
-    from jax.sharding import AxisType
-except ImportError:  # jax < 0.5
-    AxisType = None
+from jax.sharding import AbstractMesh, AxisType
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Concrete device mesh with Auto axis types where they exist."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    import numpy as np
-    devices = np.asarray(
-        jax.devices()[: math.prod(shape)]).reshape(shape)
-    return jax.sharding.Mesh(devices, axes)
+    """Concrete device mesh over the first ``prod(shape)`` devices."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def abstract_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     """Device-free AbstractMesh (sharding-rule tests / dry planning)."""
-    if AbstractMesh is None:
-        raise ImportError(
-            "jax.sharding.AbstractMesh unavailable (jax too old); "
-            "upgrade jax or use a concrete make_mesh(...)")
-    if AxisType is not None:
-        return AbstractMesh(shape, axes,
-                            axis_types=(AxisType.Auto,) * len(axes))
-    return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

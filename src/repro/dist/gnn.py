@@ -40,7 +40,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.hlo_analysis import analyze_collectives
@@ -53,6 +53,13 @@ SUPPORTED_ARCHS = ("gcn", "sage_mean", "gin")
 PARTITION_METHODS = ("contiguous", "fennel")
 
 _F32 = 4
+
+# mesh layout of each graph jit argument, as the shard_map reads it
+_CONTIGUOUS_ARG_SPECS = (P("data", None, None, None),)          # blocks
+_FENNEL_ARG_SPECS = (P("data", None, None, None),  # permuted blocks
+                     P(None),                      # perm_src
+                     P("data", None), P("data", None),  # hub/halo send
+                     P(None), P(None))             # hub/halo recv
 
 
 def _pad_last(x, size: int):
@@ -125,11 +132,7 @@ class ShardedExecutable(Executable):
         # (re-)partition instead of computing it twice
         self._pending_build = None
         if partition == "contiguous":
-            pad = self.S_pad - gt.S
-            # pad==0 is the common case (S divisible by n_data); jnp.pad
-            # would still copy the dense grid — the largest tensor here
-            self._blocks_padded = gt.blocks if pad == 0 else jnp.pad(
-                gt.blocks, ((0, pad), (0, pad), (0, 0), (0, 0)))
+            self._blocks_padded = self._padded_blocks(gt)
             self.partition: PartitionPlan = partition_graph(
                 gt, self.n_data, pad=True)
             self._cached_args = (self._blocks_padded,)
@@ -139,7 +142,23 @@ class ShardedExecutable(Executable):
             self._inv_slots = jnp.asarray(self.partition.slot_of)
         super().__init__(**kw)
 
-    # -- fennel plan -> jit arguments ---------------------------------------
+    # -- graph arrays -> jit arguments ---------------------------------------
+
+    def _place(self, args, specs) -> tuple:
+        """Commit graph arrays to the mesh in the layout the shard_map
+        reads them — each data group's rows on its own devices — instead
+        of leaving every array on the first device for each call to
+        re-distribute."""
+        return tuple(jax.device_put(a, NamedSharding(self.mesh, s))
+                     for a, s in zip(args, specs))
+
+    def _padded_blocks(self, gt):
+        """The contiguous method's grid, zero-padded to S_pad rows/cols
+        (no copy when S divides by n_data) and placed on the mesh."""
+        pad = self.S_pad - gt.S
+        blocks = gt.blocks if pad == 0 else jnp.pad(
+            gt.blocks, ((0, pad), (0, pad), (0, 0), (0, 0)))
+        return self._place((blocks,), _CONTIGUOUS_ARG_SPECS)[0]
 
     def _permute_blocks(self, gt, perm: np.ndarray):
         """Apply the plan's row permutation to the dense normalized grid:
@@ -179,11 +198,11 @@ class ShardedExecutable(Executable):
         # the graph tensors): a re-partition after a streaming delta swaps
         # them without retracing as long as the capacities hold
         args = (self._permute_blocks(gt, plan.perm),
-                jnp.asarray(np.where(plan.perm < 0, gt.S * gt.n,
-                                     plan.perm).astype(np.int32)),
-                jnp.asarray(plan.hub_send), jnp.asarray(plan.halo_send),
-                jnp.asarray(plan.hub_recv), jnp.asarray(plan.halo_recv))
-        return plan, args
+                np.where(plan.perm < 0, gt.S * gt.n,
+                         plan.perm).astype(np.int32),
+                plan.hub_send, plan.halo_send, plan.hub_recv,
+                plan.halo_recv)
+        return plan, self._place(args, _FENNEL_ARG_SPECS)
 
     # -- graph argument plumbing --------------------------------------------
 
@@ -192,11 +211,9 @@ class ShardedExecutable(Executable):
         fennel plans) plus the plan's index arrays; linear-aggregation
         archs never touch the per-shard COO arrays."""
         if self.partition_method == "contiguous":
-            pad = self.S_pad - gt.S
             if gt is self.gt and hasattr(self, "_blocks_padded"):
                 return (self._blocks_padded,)
-            return (gt.blocks if pad == 0 else jnp.pad(
-                gt.blocks, ((0, pad), (0, pad), (0, 0), (0, 0))),)
+            return (self._padded_blocks(gt),)
         if gt is self.gt:
             return self._cached_args
         if self._pending_build is not None and self._pending_build[0] is gt:
@@ -225,9 +242,7 @@ class ShardedExecutable(Executable):
         n = super().update_graph(gt, h_grouped, stale_nodes=stale_nodes)
         # refresh the cached padded grid and the comm/balance plan for the
         # post-delta graph (template checks already passed in super())
-        pad = self.S_pad - gt.S
-        self._blocks_padded = gt.blocks if pad == 0 else jnp.pad(
-            gt.blocks, ((0, pad), (0, pad), (0, 0), (0, 0)))
+        self._blocks_padded = self._padded_blocks(gt)
         self.partition = partition_graph(gt, self.n_data, pad=True)
         self._cached_args = (self._blocks_padded,)
         return n
@@ -298,11 +313,12 @@ class ShardedExecutable(Executable):
                 return h_loc
 
             p_specs = jax.tree.map(lambda _: P(), self.params)
-            smap = shard_map(device_fn, mesh=mesh,
-                             in_specs=(p_specs, P("data", None, None, None),
-                                       P("data", None, None)),
-                             out_specs=P("data", None, None),
-                             check_rep=False)
+            smap = jax.shard_map(device_fn, mesh=mesh,
+                                 in_specs=(p_specs,
+                                           P("data", None, None, None),
+                                           P("data", None, None)),
+                                 out_specs=P("data", None, None),
+                                 check_vma=False)
 
             def fwd(p, h, blocks_padded):
                 # the padded grid enters as a jit argument (streaming
@@ -365,13 +381,13 @@ class ShardedExecutable(Executable):
             return h_loc
 
         p_specs = jax.tree.map(lambda _: P(), self.params)
-        smap = shard_map(
+        smap = jax.shard_map(
             device_fn, mesh=mesh,
             in_specs=(p_specs, P("data", None, None, None),
                       P(None, None, None), P("data", None),
                       P("data", None), P(None), P(None)),
             out_specs=P("data", None, None),
-            check_rep=False)
+            check_vma=False)
 
         def fwd(p, h, blocks_perm, perm_src, hub_send, halo_send,
                 hub_recv, halo_recv):
@@ -412,18 +428,21 @@ class ShardedExecutable(Executable):
         # the comm contract; this reuses the bucketless jitted gather
         return self._jit_gather(out, self._inv_slots)[: self.gt.num_nodes]
 
-    def _forward_fn(self):
+    def _forward_with_args(self):
         if self.partition_method == "contiguous":
-            return super()._forward_fn()
+            return super()._forward_with_args()
         g = self._forward_graph_fn()
-        args = self._graph_args()
-        inv = self._inv_slots
         num_nodes = self.gt.num_nodes
+
         # training/analysis view: the inverse permutation rides INSIDE
         # this composition (fit's step jit needs end-to-end
         # differentiability); train comm checks are lower bounds, so the
         # extra gather collectives there are fine
-        return lambda p, h: g(p, h, *args)[inv][:num_nodes]
+        def fn(p, h, *ga):
+            *graph, inv = ga
+            return g(p, h, *graph)[inv][:num_nodes]
+
+        return fn, (*self._graph_args(), self._inv_slots)
 
     # -- communication accounting ------------------------------------------
 
@@ -461,7 +480,10 @@ class ShardedExecutable(Executable):
             self.params)
         h_aval = jax.ShapeDtypeStruct((self.gt.S, self.gt.n,
                                        self.spec.in_dim), jnp.float32)
-        g_avals = [jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a))
+        # the graph arrays keep their mesh placement: the verified
+        # program is the one the forward runs
+        g_avals = [jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
+                                        sharding=a.sharding)
                    for a in self._graph_args()]
         hlo = self._jit_forward.lower(p_avals, h_aval,
                                       *g_avals).compile().as_text()

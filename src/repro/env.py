@@ -11,32 +11,32 @@ describable* environment.
 
 Ordering matters: ``XLA_FLAGS``/``JAX_PLATFORMS`` only take effect
 before jax initializes its backends, so the setters mutate ``os.environ``
-and warn (rather than silently no-op) when jax is already live. Always
-call these at the top of a ``main()``, before the first repro/jax import
-does real work.
+and warn (rather than silently no-op) once jax is imported. Always call
+these at the top of a ``main()``, before the first repro/jax import does
+real work.
+
+:func:`enable_compile_cache` is the one place the persistent compilation
+cache is turned on (entry points only, never tests).
 """
 from __future__ import annotations
 
 import os
+import pathlib
 import re
 import sys
 import warnings
 
 _HOST_DEV_FLAG = "--xla_force_host_platform_device_count"
+# src/repro/env.py -> the checkout root
+_CHECKOUT_ROOT = pathlib.Path(__file__).resolve().parents[2]
 
 
 def _jax_initialized() -> bool:
-    """True once jax has picked its backends (env changes stop mattering)."""
-    mod = sys.modules.get("jax")
-    if mod is None:
-        return False
-    try:
-        # jax.config reads don't initialize backends; the backend registry
-        # does, and it exposes whether it already ran
-        from jax._src import xla_bridge
-        return xla_bridge.backends_are_initialized()
-    except Exception:   # noqa: BLE001 — private API moved: assume live
-        return True
+    """True once jax is imported. jax reads ``JAX_PLATFORMS`` at import
+    and ``XLA_FLAGS`` when its backends start, and offers no public way
+    to ask whether they have started — so from import on, an environment
+    change may already be too late."""
+    return sys.modules.get("jax") is not None
 
 
 def _warn_if_late(knob: str) -> None:
@@ -84,13 +84,12 @@ def configure(*, platform: str | None = None, x64: bool | None = None,
 def pin_for_benchmarks(*, platform: str | None = None) -> dict:
     """The pinned measurement environment for benchmarks and tuning runs.
 
-    Pins the platform (default: keep an explicit ``JAX_PLATFORMS`` if the
-    caller exported one, else cpu — benchmark numbers must never silently
-    move between devices) and 32-bit arrays (the kernels' dtype), then
-    returns :func:`describe` for embedding into the result record.
+    Pins ``platform`` when one is given — otherwise jax picks the
+    accelerator it finds, and the record names it — plus 32-bit arrays
+    (the kernels' dtype), then returns :func:`describe` for embedding
+    into the result record.
     """
-    configure(platform=platform or os.environ.get("JAX_PLATFORMS") or "cpu",
-              x64=False)
+    configure(platform=platform, x64=False)
     return describe()
 
 
@@ -101,7 +100,25 @@ def describe() -> dict:
     return {
         "jax_version": jax.__version__,
         "jax_platform": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "device_count": jax.device_count(),
         "x64": bool(jax.config.read("jax_enable_x64")),
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
     }
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when exported, is the cache (jax reads
+    it itself) and no other directory is set. Otherwise the cache lives
+    at ``.jax_cache/`` in the checkout root: a fixed path, because the
+    path is part of what a later run must find again. Entry points call
+    this at the top of ``main()``; tests never do.
+    """
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_CHECKOUT_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -54,7 +54,7 @@ def dense_engine_matmul(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """act(x @ w + b) with explicit VMEM tiling.
 
@@ -74,8 +74,9 @@ def dense_engine_matmul(
     ]
     args = [x, w]
     if b is not None:
-        in_specs.append(pl.BlockSpec((bn,), lambda i, j, kk: (j,)))
-        args.append(b)
+        # bias as a (1, N) row: a 1-D block would need Mosaic's 1-D tiling
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)))
+        args.append(b.reshape(1, n))
         kernel = functools.partial(_kernel, activation=activation, nk=nk)
     else:
         kernel = functools.partial(

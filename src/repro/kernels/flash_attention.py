@@ -79,7 +79,7 @@ def flash_attention(
     scale: float | None = None,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """softmax(q kᵀ · scale + mask) v, blockwise.
 

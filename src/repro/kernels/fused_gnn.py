@@ -71,7 +71,7 @@ def fused_gnn_layer(
     *,
     block_b: int = 128,
     activation: str = "none",
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """act((A · H) · W) without materializing A·H in HBM.
 

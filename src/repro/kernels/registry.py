@@ -84,7 +84,7 @@ class KernelBackend(Protocol):
 def _with_ref_vjp(kernel_fn, ref_fn):
     """custom_vjp wrapper: FORWARD runs the Pallas kernel, BACKWARD
     differentiates the pure-jnp oracle (recomputing the forward pass —
-    kernels in interpret mode are not ad-traceable, and shipping explicit
+    Pallas calls are not ad-traceable, and shipping explicit
     VJPs per kernel is exactly what production kernel libraries do; the
     oracle-derived gradient is validated in tests/test_kernels_grad.py)."""
     @jax.custom_vjp
@@ -103,8 +103,24 @@ def _with_ref_vjp(kernel_fn, ref_fn):
 
 
 def _interpret() -> bool:
-    # interpret unless we are actually on TPU
+    """Compile the kernels on TPU; interpret them on any other platform
+    (CPU tests). Every kernel call below passes this explicitly."""
     return jax.default_backend() != "tpu"
+
+
+def _feature_block(d: int, block_b: int) -> tuple[int, int]:
+    """(kernel block, padded dim) for a requested block over a lane dim.
+
+    Mosaic only accepts a block whose lane (last) dim is a multiple of
+    128 or the whole (padded) array dim. A requested block below that is
+    rounded up to whole lanes; one that covers the dim becomes a single
+    full-width block. The feature blocks B of the graph kernels and the
+    dense kernel's K/N tiles all go through here."""
+    full = round_up(d, 8)
+    bb = round_up(block_b, 128)
+    if bb >= full:
+        return full, full
+    return bb, round_up(d, bb)
 
 
 def _pad(x, size, axis):
@@ -207,9 +223,9 @@ class JaxBackend(ReferenceBackend):
 # --------------------------------------------------------------------------
 
 class PallasBackend:
-    """The Pallas kernels (interpret mode off-TPU). Inputs are padded to
-    the kernels' block multiples and sliced back; backward passes come
-    from the oracles via custom_vjp."""
+    """The Pallas kernels (compiled on TPU, interpreted elsewhere). Inputs
+    are padded to the kernels' block multiples and sliced back; backward
+    passes come from the oracles via custom_vjp."""
 
     name = "pallas"
 
@@ -218,9 +234,10 @@ class PallasBackend:
         def kernel(x, w, *opt_b):
             m, k = x.shape
             n = w.shape[1]
-            bm_, bn_, bk_ = (min(bm, round_up(m, 8)), min(bn, round_up(n, 8)),
-                             min(bk, round_up(k, 8)))
-            mp, kp, np_ = round_up(m, bm_), round_up(k, bk_), round_up(n, bn_)
+            bm_ = min(bm, round_up(m, 8))
+            mp = round_up(m, bm_)
+            bk_, kp = _feature_block(k, bk)
+            bn_, np_ = _feature_block(n, bn)
             xp = _pad(_pad(x, mp, 0), kp, 1)
             wp = _pad(_pad(w, kp, 0), np_, 1)
             bp = _pad(opt_b[0], np_, 0) if opt_b else None
@@ -239,8 +256,7 @@ class PallasBackend:
     def graph_aggregate(self, blocks, h, *, block_b=128):
         def kernel(blocks, h):
             d = h.shape[-1]
-            bb = min(block_b, round_up(d, 8))
-            dp = round_up(d, bb)
+            bb, dp = _feature_block(d, block_b)
             out = _ss.shard_spmm(blocks, _pad(h, dp, 2), block_b=bb,
                                  interpret=_interpret())
             return out[..., :d]
@@ -251,8 +267,7 @@ class PallasBackend:
                                 block_b=128):
         def kernel(blocks, h, w):
             d = h.shape[-1]
-            bb = min(block_b, round_up(d, 8))
-            dp = round_up(d, bb)
+            bb, dp = _feature_block(d, block_b)
             return _fg.fused_gnn_layer(
                 blocks, _pad(h, dp, 2), _pad(w, dp, 0),
                 block_b=bb, activation=activation, interpret=_interpret())
@@ -271,8 +286,7 @@ class PallasBackend:
         # cotangents are float0)
         def kernel(e_src, e_dst, e_val, h):
             d = h.shape[-1]
-            bb = min(block_b, round_up(d, 8))
-            dp = round_up(d, bb)
+            bb, dp = _feature_block(d, block_b)
             out = _sg.seg_gather_aggregate(
                 e_src, e_dst, e_val, _pad(h, dp, 2), op=op,
                 block_b=bb, interpret=_interpret())
@@ -290,11 +304,13 @@ class PallasBackend:
         bq_, bk_ = min(bq, sq), min(bk, skv)
         if sq % bq_ or skv % bk_:
             # Padding the sequence axes would shift the causal-offset
-            # alignment (qpos = skv - sq + i); rather than re-deriving masks
-            # for padded layouts we require block-multiple shapes for the
-            # kernel path and fall back to the oracle otherwise.
-            return ref.flash_attention(q, k, v, causal=causal, scale=scale,
-                                       window=window)
+            # alignment (qpos = skv - sq + i), so the kernel path needs
+            # block-multiple shapes; other shapes belong to another backend
+            raise ValueError(
+                f"pallas attention needs sequence lengths that are block "
+                f"multiples: sq={sq} (bq={bq_}), skv={skv} (bk={bk_}); "
+                f"pick block sizes that divide them or use the "
+                f"'reference' backend")
 
         def kernel(q, k, v):
             return _fa.flash_attention(q, k, v, causal=causal, window=window,

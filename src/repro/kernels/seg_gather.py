@@ -7,8 +7,11 @@ gathers source rows, and the SIMD Reduce lane scatter-reduces into the
 destination scratchpad — all on an (n × B) dimension block resident in
 VMEM, with the same (blockD, dst, src) loop nest as shard_spmm.
 
-Edge ids are int32 and live in VMEM blocks (on real TPU one would prefetch
-them to SMEM with PrefetchScalarGridSpec; functionally identical).
+TPU form: each edge is packed into one int32 (dst·n + src, -1 for an
+empty slot); a shard pair's packed list is DMA'd from HBM into SMEM (so
+the scalar core can index it), and the body loops over the edges, moving
+one (1 × B) source row per edge with dynamic sublane slices — Mosaic has
+no vector gather/scatter over VMEM rows.
 """
 from __future__ import annotations
 
@@ -20,28 +23,37 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -3.0e38  # python float: jnp constants would be captured as consts
+_TILE = 1024    # Mosaic's tiling of a 1-D int32 array in HBM
 
 
-def _kernel(src_ref, dst_ref, valid_ref, h_ref, o_ref, acc_ref, *, ns: int, op: str):
-    j = pl.program_id(2)
+def _kernel(key_hbm, h_ref, o_ref, acc_ref, key_smem, sem, *, ns: int,
+            ne: int, n: int, op: str):
+    i, j = pl.program_id(1), pl.program_id(2)
+    # Edge Fetcher: this shard pair's packed edge list, HBM -> SMEM
+    start = pl.multiple_of((i * ns + j) * ne, _TILE)
+    fetch = pltpu.make_async_copy(key_hbm.at[pl.ds(start, ne)], key_smem,
+                                  sem)
+    fetch.start()
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.full_like(acc_ref, _NEG if op == "max" else 0.0)
 
-    src = src_ref[...]
-    dst = dst_ref[...]
-    valid = valid_ref[...] != 0
-    h = h_ref[...].astype(jnp.float32)          # (n_src, B) resident block
-    gathered = h[src]                            # (E, B) Feature Fetcher
-    acc = acc_ref[...]
-    if op == "max":
-        gathered = jnp.where(valid[:, None], gathered, _NEG)
-        acc = acc.at[dst].max(gathered, mode="drop")
-    else:  # sum
-        gathered = jnp.where(valid[:, None], gathered, 0.0)
-        acc = acc.at[dst].add(gathered, mode="drop")
-    acc_ref[...] = acc
+    fetch.wait()
+
+    def edge(e, carry):
+        key = key_smem[e]
+
+        @pl.when(key >= 0)
+        def _reduce():
+            row = h_ref[pl.ds(key % n, 1), :].astype(jnp.float32)
+            dst = pl.ds(key // n, 1)
+            cur = acc_ref[dst, :]
+            acc_ref[dst, :] = (jnp.maximum(cur, row) if op == "max"
+                               else cur + row)
+        return carry
+
+    jax.lax.fori_loop(0, ne, edge, 0)
 
     @pl.when(j == ns - 1)
     def _writeback():
@@ -60,7 +72,7 @@ def seg_gather_aggregate(
     *,
     op: str = "max",
     block_b: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Edge-list shard-grid aggregation, feature-blocked.
 
@@ -72,20 +84,29 @@ def seg_gather_aggregate(
     assert s == s2 == s3, (edge_src.shape, h.shape)
     assert d % block_b == 0, (d, block_b)
     assert op in ("max", "sum"), op
-    valid = edge_valid.astype(jnp.int8)
+    assert n * n < 2 ** 31, n          # packed (dst, src) fits an int32
+    # one int32 per edge: dst·n + src, or -1 for an empty slot
+    # flattened 1-D in HBM, each pair's list padded to whole 1-D tiles so
+    # every per-pair DMA slice is tile-aligned
+    ne = -(-e // _TILE) * _TILE
+    key = jnp.where(edge_valid.astype(bool),
+                    edge_dst.astype(jnp.int32) * n
+                    + edge_src.astype(jnp.int32), -1)
+    key = jnp.pad(key, ((0, 0), (0, 0), (0, ne - e)),
+                  constant_values=-1).reshape(-1)
     grid = (d // block_b, s, s)  # (blockD, dst, src)
 
     return pl.pallas_call(
-        functools.partial(_kernel, ns=s, op=op),
+        functools.partial(_kernel, ns=s, ne=ne, n=n, op=op),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, None, e), lambda bd, i, j: (i, j, 0)),
-            pl.BlockSpec((None, None, e), lambda bd, i, j: (i, j, 0)),
-            pl.BlockSpec((None, None, e), lambda bd, i, j: (i, j, 0)),
+            pl.BlockSpec(memory_space=pltpu.HBM),
             pl.BlockSpec((None, n, block_b), lambda bd, i, j: (j, 0, bd)),
         ],
         out_specs=pl.BlockSpec((None, n, block_b), lambda bd, i, j: (i, 0, bd)),
         out_shape=jax.ShapeDtypeStruct((s, n, d), h.dtype),
-        scratch_shapes=[pltpu.VMEM((n, block_b), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, block_b), jnp.float32),
+                        pltpu.SMEM((ne,), jnp.int32),
+                        pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
-    )(edge_src, edge_dst, valid, h)
+    )(key, h)

@@ -50,7 +50,7 @@ def shard_spmm(
     h: jax.Array,
     *,
     block_b: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """out[i] = sum_j A[i, j] @ h[j], feature-blocked.
 

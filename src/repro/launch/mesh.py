@@ -14,7 +14,7 @@ pod count without code changes).
 ``make_mesh_for`` is the elastic variant the GNN runtime uses:
 ``runtime.compile(spec, graph, mesh=make_mesh_for(jax.device_count()))``
 returns a sharded Executable (see dist/gnn.py). Mesh construction goes
-through dist/compat.py so both jax 0.4.x and >= 0.5 work.
+through dist/compat.py.
 """
 from __future__ import annotations
 

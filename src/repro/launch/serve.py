@@ -213,6 +213,10 @@ def _serve_gnn(args) -> None:
     print(_latency_line(outcomes))
     print(f"served {len(done)}/{len(tickets)} requests in {dt:.2f}s "
           f"({len(done) / dt:.1f} req/s)")
+    missed = [o for o in outcomes if not isinstance(o, Completed)]
+    if missed:
+        raise SystemExit(f"{len(missed)} of {len(tickets)} requests were "
+                         f"not completed; first: {missed[0]}")
 
 
 def main() -> None:
@@ -273,6 +277,9 @@ def main() -> None:
     ap.add_argument("--deadline-ms", type=float, default=None,
                     help="per-request deadline; queued past it -> Expired")
     args = ap.parse_args()
+
+    from repro import env
+    env.enable_compile_cache()
 
     if args.mode == "gnn":
         _serve_gnn(args)

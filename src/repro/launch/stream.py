@@ -186,6 +186,9 @@ def main() -> None:
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args()
 
+    from repro import env
+    env.enable_compile_cache()
+
     out = run(args)
     raise SystemExit(0 if out["ok"] else 1)
 

@@ -31,6 +31,9 @@ def main() -> None:
     ap.add_argument("--compress-grads", action="store_true")
     args = ap.parse_args()
 
+    from repro import env
+    env.enable_compile_cache()
+
     from repro.configs.registry import get_config, get_smoke
     from repro.checkpoint.manager import CheckpointManager
     from repro.dist.shardings import ShardingRules

@@ -79,6 +79,9 @@ def main() -> None:
                          "via Executable.load_params for a serving reload)")
     args = ap.parse_args()
 
+    from repro import env
+    env.enable_compile_cache()
+
     from repro import runtime
     from repro.gnn.models import ZooSpec
     from repro.graphs.datasets import make_dataset

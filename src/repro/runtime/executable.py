@@ -172,14 +172,22 @@ class Executable:
 
         return fwd
 
-    def _forward_fn(self):
-        """Back-compat 2-arg view ``(params, h_grouped) -> (N, C)`` with
-        the current graph bound as constants — the shape the jaxpr static
-        analyzer walks."""
+    def _forward_with_args(self):
+        """``(fn, graph_args)`` with ``fn(params, h_grouped, *graph_args)
+        -> (N, C)`` logits — the view a jitted train step closes over.
+        The graph arrays travel as ARGUMENTS: a jit that closed over them
+        would embed the (S, S, n, n) grid in its program as a constant
+        (1.6 GB for full pubmed)."""
         g = self._forward_graph_fn()
-        args = self._graph_args()
         num_nodes = self.gt.num_nodes
-        return lambda p, h: g(p, h, *args)[:num_nodes]
+        return (lambda p, h, *ga: g(p, h, *ga)[:num_nodes]), \
+            self._graph_args()
+
+    def _forward_fn(self):
+        """2-arg view ``(params, h_grouped) -> (N, C)`` with the current
+        graph bound — the shape the jaxpr static analyzer walks."""
+        fn, args = self._forward_with_args()
+        return lambda p, h: fn(p, h, *args)
 
     # -- forward entry points ---------------------------------------------
 

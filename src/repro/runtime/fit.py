@@ -37,6 +37,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from repro.core.engines import GraphTensors
 from repro.core.sharding import shard_graph
@@ -118,9 +119,10 @@ class TrainableExecutable:
         if sampler is None:
             h = exe._h_grouped if exe._h_grouped is not None \
                 else exe.gt.group(jnp.asarray(features))
+            fwd, graph_args = exe._forward_with_args()
             self._full_batch = (h, jnp.asarray(self._labels),
-                                jnp.asarray(self._train_mask))
-            self._jit_step = jax.jit(self._make_full_step(),
+                                jnp.asarray(self._train_mask), *graph_args)
+            self._jit_step = jax.jit(self._make_full_step(fwd),
                                      donate_argnums=(0, 1))
         else:
             if getattr(exe, "mesh", None) is not None:
@@ -139,13 +141,14 @@ class TrainableExecutable:
 
     # -- step construction -------------------------------------------------
 
-    def _make_full_step(self) -> Callable:
-        fwd = self.executable._forward_fn()
+    def _make_full_step(self, fwd: Callable) -> Callable:
+        """``fwd`` is :meth:`Executable._forward_with_args`'s function; the
+        graph arrays arrive as trailing step arguments."""
         opt_cfg, schedule = self.opt_cfg, self._schedule
 
-        def step(params, opt_state, h, labels, mask):
+        def step(params, opt_state, h, labels, mask, *graph):
             def loss_fn(p):
-                logits = fwd(p, h)
+                logits = fwd(p, h, *graph)
                 return masked_cross_entropy(logits, labels, mask), logits
 
             (loss, logits), grads = jax.value_and_grad(
@@ -355,9 +358,15 @@ class TrainableExecutable:
                              "Executable (runtime.fit(..., mesh=...))")
         aval = lambda x: jax.ShapeDtypeStruct(jnp.shape(x),
                                               jnp.result_type(x))
+        # the graph arrays keep their mesh placement, so the lowered step
+        # is the one that runs
+        placed = lambda x: jax.ShapeDtypeStruct(
+            jnp.shape(x), jnp.result_type(x),
+            sharding=x.sharding if isinstance(x.sharding, NamedSharding)
+            else None)
         args = (jax.tree.map(aval, self.params),
                 jax.tree.map(aval, self.opt_state),
-                *(aval(b) for b in self._full_batch))
+                *(placed(b) for b in self._full_batch))
         hlo = self._jit_step.lower(*args).compile().as_text()
         stats = analyze_collectives(hlo)
         return {

@@ -200,8 +200,7 @@ def test_forward_nodes_bucket_shares_traces(tiny):
 # --------------------------------------------------------------------------
 
 def test_dtype_f64_promotion_flagged():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(lambda x: jnp.sin(x) * 2.0)(
             jnp.ones(3, jnp.float64))
     fs = jaxpr_lint.dtype_findings(closed, name="fix")

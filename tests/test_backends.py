@@ -88,6 +88,20 @@ class TestRegistryParity:
         _check("attention", lambda: (q, k, v), causal=True, window=window,
                bq=32, bk=32)
 
+    def test_pallas_attention_refuses_non_block_multiples(self):
+        q = RNG.standard_normal((1, 2, 48, 16)).astype(np.float32)
+        with pytest.raises(ValueError, match="block multiples"):
+            registry.get_backend("pallas").attention(q, q, q, bq=32, bk=32)
+
+    @pytest.mark.parametrize("d,b", [(500, 16), (1433, 64), (16, 16),
+                                     (7, 128), (256, 128), (300, 256)])
+    def test_feature_block_is_lane_legal(self, d, b):
+        """Mosaic's rule: a lane block is whole 128-lane tiles or the
+        whole padded dim, and it tiles the padded dim exactly."""
+        bb, dp = registry._feature_block(d, b)
+        assert dp >= d and dp % bb == 0
+        assert bb % 128 == 0 or bb == dp
+
 
 class TestResolution:
     def test_env_selects_backend(self, monkeypatch):

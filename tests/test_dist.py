@@ -13,20 +13,13 @@ if importlib.util.find_spec("repro.dist") is None:
     # inside an existing repro.dist must still fail loudly
     pytest.skip("repro.dist not present in this build",
                 allow_module_level=True)
-from repro.dist import compat
-
-if compat.AbstractMesh is None:
-    # pre-AbstractMesh jax: keep the old graceful module-level skip
-    pytest.skip("jax too old for AbstractMesh", allow_module_level=True)
-abstract_mesh = compat.abstract_mesh
+from repro.dist.compat import abstract_mesh
 from repro.dist.hlo_analysis import analyze_collectives, type_bytes
 from repro.dist.shardings import ShardingRules
 from repro.nn.layers import Axes
 
 
 def _mesh(shape=(16, 16), axes=("data", "model")):
-    # dist.compat builds the AbstractMesh on both jax 0.4.x (no AxisType)
-    # and jax >= 0.5 (axis_types required by newer constructors)
     return abstract_mesh(shape, axes)
 
 

@@ -71,12 +71,16 @@ class TestBenchmarkPinning:
             os.environ["XLA_FLAGS"]
 
     def test_pin_for_benchmarks_pins_and_describes(self):
+        import jax
+        os.environ.pop("JAX_PLATFORMS", None)
         with warnings.catch_warnings():
             warnings.simplefilter("ignore", RuntimeWarning)
             d = env.pin_for_benchmarks()
-        assert os.environ["JAX_PLATFORMS"] == "cpu"
+        # no platform is chosen for the caller: jax's own pick is recorded
+        assert "JAX_PLATFORMS" not in os.environ
         assert d["x64"] is False
-        assert d["jax_platform"] == "cpu"
+        assert d["jax_platform"] == jax.default_backend()
+        assert d["device_kind"] == jax.devices()[0].device_kind
         assert d["device_count"] >= 1
         assert d["jax_version"]
 
@@ -87,3 +91,31 @@ class TestBenchmarkPinning:
             d = env.pin_for_benchmarks()
         assert os.environ["JAX_PLATFORMS"] == "cpu"
         assert "xla_flags" in d
+
+
+class TestCompileCache:
+    """The helper's choice of directory, observed through jax.config
+    updates it makes (stubbed, so no test turns the cache on)."""
+
+    @pytest.fixture
+    def updates(self, monkeypatch):
+        import jax
+        seen = {}
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: seen.__setitem__(k, v))
+        return seen
+
+    def test_exported_dir_is_used_and_nothing_else_set(self, monkeypatch,
+                                                       updates, tmp_path):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert env.enable_compile_cache() == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in updates
+
+    def test_default_is_fixed_dir_in_checkout(self, monkeypatch, updates):
+        import pathlib
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = env.enable_compile_cache()
+        root = pathlib.Path(env.__file__).resolve().parents[2]
+        assert path == str(root / ".jax_cache")
+        assert updates["jax_compilation_cache_dir"] == path
+        assert env.enable_compile_cache() == path      # stable across calls

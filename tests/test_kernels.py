@@ -28,7 +28,8 @@ def _rand(shape, dtype):
 ])
 def test_dense_engine(m, k, n, bm, bk, bn, dtype):
     x, w, b = _rand((m, k), dtype), _rand((k, n), dtype), _rand((n,), dtype)
-    out = dense_engine_matmul(x, w, b, activation="relu", bm=bm, bn=bn, bk=bk)
+    out = dense_engine_matmul(x, w, b, activation="relu", bm=bm, bn=bn, bk=bk,
+                              interpret=True)
     exp = ref.dense_engine(x, w, b, activation="relu")
     tol = 1e-4 if dtype == np.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -40,7 +41,7 @@ def test_dense_engine(m, k, n, bm, bk, bn, dtype):
 def test_shard_spmm(s, n, d, bb, dtype):
     a = (RNG.random((s, s, n, n)) < 0.2).astype(np.float32)
     h = _rand((s, n, d), dtype)
-    out = shard_spmm(a, h, block_b=bb)
+    out = shard_spmm(a, h, block_b=bb, interpret=True)
     exp = ref.shard_spmm(a, h)
     tol = 1e-4 if dtype == np.float32 else 1e-1
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -52,7 +53,8 @@ def test_fused_gnn(s, n, d, f, bb):
     a = (RNG.random((s, s, n, n)) < 0.2).astype(np.float32)
     h = _rand((s, n, d), np.float32)
     w = _rand((d, f), np.float32)
-    out = fused_gnn_layer(a, h, w, block_b=bb, activation="relu")
+    out = fused_gnn_layer(a, h, w, block_b=bb, activation="relu",
+                          interpret=True)
     exp = ref.fused_gnn(a, h, w, activation="relu")
     np.testing.assert_allclose(out, exp, atol=1e-4, rtol=1e-4)
 
@@ -64,7 +66,8 @@ def test_seg_gather(op, s, n, e, d, bb):
     ed = RNG.integers(0, n, (s, s, e)).astype(np.int32)
     ev = RNG.random((s, s, e)) < 0.6
     h = _rand((s, n, d), np.float32)
-    out = seg_gather_aggregate(es, ed, ev, h, op=op, block_b=bb)
+    out = seg_gather_aggregate(es, ed, ev, h, op=op, block_b=bb,
+                               interpret=True)
     # oracle: combine per-pair refs across the src axis
     import os
     os.environ["REPRO_KERNEL_BACKEND"] = "ref"
@@ -86,7 +89,8 @@ def test_flash_attention(b, hq, hkv, sq, skv, dh, window, dtype):
     q = _rand((b, hq, sq, dh), dtype)
     k = _rand((b, hkv, skv, dh), dtype)
     v = _rand((b, hkv, skv, dh), dtype)
-    out = flash_attention(q, k, v, causal=True, window=window, bq=32, bk=32)
+    out = flash_attention(q, k, v, causal=True, window=window, bq=32, bk=32,
+                          interpret=True)
     exp = ref.flash_attention(q, k, v, causal=True, window=window)
     tol = 2e-4 if dtype == np.float32 else 8e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -108,7 +112,7 @@ def test_spmm_matches_dense_matmul(s, n, d, bb, seed):
     r = np.random.default_rng(seed)
     a = (r.random((s, s, n, n)) < 0.3).astype(np.float32)
     h = r.standard_normal((s, n, d)).astype(np.float32)
-    out = shard_spmm(a, h, block_b=bb)
+    out = shard_spmm(a, h, block_b=bb, interpret=True)
     # flatten the block-structured adjacency to (S*n, S*n)
     a_flat = a.transpose(0, 2, 1, 3).reshape(s * n, s * n)
     exp = (a_flat @ h.reshape(s * n, d)).reshape(s, n, d)
@@ -127,8 +131,8 @@ def test_blocking_invariance(b, d, seed):
     s, n = 2, 16
     a = (r.random((s, s, n, n)) < 0.3).astype(np.float32)
     h = r.standard_normal((s, n, d)).astype(np.float32)
-    full = shard_spmm(a, h, block_b=d)      # conventional dataflow (B = D)
-    blocked = shard_spmm(a, h, block_b=b)   # dimension-blocked
+    full = shard_spmm(a, h, block_b=d, interpret=True)      # conventional dataflow (B = D)
+    blocked = shard_spmm(a, h, block_b=b, interpret=True)   # dimension-blocked
     np.testing.assert_allclose(full, blocked, atol=1e-5, rtol=1e-5)
 
 
@@ -141,8 +145,9 @@ def test_fusion_invariance(seed, act):
     a = (r.random((s, s, n, n)) < 0.3).astype(np.float32)
     h = r.standard_normal((s, n, d)).astype(np.float32)
     w = r.standard_normal((d, f)).astype(np.float32)
-    fused = fused_gnn_layer(a, h, w, block_b=16, activation=act)
-    agg = shard_spmm(a, h, block_b=16)
+    fused = fused_gnn_layer(a, h, w, block_b=16, activation=act,
+                            interpret=True)
+    agg = shard_spmm(a, h, block_b=16, interpret=True)
     twostep = ref.dense_engine(agg.reshape(s * n, d), w, activation=act)
     np.testing.assert_allclose(fused, twostep.reshape(s, n, f),
                                atol=1e-3, rtol=1e-3)

@@ -223,3 +223,26 @@ def test_temperature_sampling_runs():
     outs = eng.generate([Request(p, max_new_tokens=5, temperature=1.0)],
                         seed=3)
     assert outs[0].shape == (5,)
+
+
+def _gnn_launcher_args(**over):
+    import argparse
+    args = dict(graphs="cora", models="gcn", backend="reference", layers=2,
+                hidden=8, heads=2, mesh=0, partition="contiguous",
+                hub_cache=0, model_parallel=1, plan="analytic",
+                tune_budget=1, shard_n=64, scale=0.05, nodes_per_req=4,
+                deadline_ms=None, num_requests=4, batch_size=4,
+                max_wait_ms=0.0, queue_depth=16)
+    args.update(over)
+    return argparse.Namespace(**args)
+
+
+def test_gnn_launcher_exit_status_follows_tickets(capsys):
+    """The GNN serve launcher returns normally only when every ticket
+    completed; expired tickets (deadline 0) make it exit non-zero."""
+    from repro.launch import serve
+    serve._serve_gnn(_gnn_launcher_args())
+    assert "served 4/4" in capsys.readouterr().out
+    with pytest.raises(SystemExit, match="4 of 4 requests were not "
+                                         "completed"):
+        serve._serve_gnn(_gnn_launcher_args(deadline_ms=0.0))

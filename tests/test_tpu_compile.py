@@ -1,0 +1,181 @@
+"""Compile the Pallas kernels for a described TPU v5e, no chip attached.
+
+Interpret mode accepts tilings and VMEM footprints that Mosaic refuses;
+compiling for ``v5e:2x2`` here catches those refusals before a chip run.
+Each kernel is compiled with ``interpret=False`` at the shapes the pallas
+backend hands it for full cora and full pubmed gcn: the planner's shard
+size n and grid S, and its feature block B after the backend's lane
+legalization (``registry._feature_block``).
+
+The topology is described inside a module-scoped fixture, never while
+this file is imported: only one process may hold the TPU library, and
+under several test workers only the worker running this file may load it.
+"""
+import functools
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.analyze import plan_lint
+from repro.gnn.executor import plan_model
+from repro.gnn.models import ZooSpec
+from repro.graphs.datasets import make_dataset
+from repro.kernels import dense_engine, fused_gnn, seg_gather, shard_spmm
+from repro.kernels.registry import _feature_block
+
+GRAPHS = ("cora", "pubmed")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    saved_log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # entries compiled for a described chip cannot be read back without
+    # one; keep the persistent cache out of these compiles
+    saved_cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    from jax.experimental import topologies
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        desc = None
+        reason = f"no v5e:2x2 topology can be described here: {e}"
+    try:
+        if desc is None:
+            pytest.skip(reason)
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", saved_cache)
+        if saved_log_dir is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+        else:
+            os.environ["TPU_LOG_DIR"] = saved_log_dir
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def graphs():
+    """Full-size datasets + their gcn plans (hidden 16, 2 layers)."""
+    out = {}
+    for name in GRAPHS:
+        ds = make_dataset(name, seed=0)
+        prof = ds.profile
+        spec = ZooSpec("gcn", prof.feature_dim, 16, prof.num_classes,
+                       num_layers=2)
+        out[name] = (ds, spec, plan_model(spec, prof.num_nodes,
+                                          int(ds.edges.shape[0])))
+    return out
+
+
+def _compile(fn, *avals):
+    compiled = jax.jit(fn).lower(*avals).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _layer_shapes(graphs, name, layer):
+    """(S, n, padded d, kernel B, out dim) of one gcn layer."""
+    _, spec, plan = graphs[name]
+    lp = plan.layers[layer]
+    bb, dp = _feature_block(lp.d_agg, lp.B)
+    return lp.S, plan.shard_n, dp, bb, spec.layer_dims[layer][1]
+
+
+def _max_pair_edges(ds, n: int) -> int:
+    """Largest per-shard-pair edge count (self loops included) — the E of
+    the padded (S, S, E) edge lists the gather kernel walks."""
+    num = ds.profile.num_nodes
+    loops = np.arange(num)
+    src = np.concatenate([ds.edges[:, 0], loops])
+    dst = np.concatenate([ds.edges[:, 1], loops])
+    s = -(-num // n)
+    counts = np.zeros((s, s), np.int64)
+    np.add.at(counts, (dst // n, src // n), 1)
+    return int(counts.max())
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+@pytest.mark.parametrize("name", GRAPHS)
+def test_shard_spmm_compiles(graphs, one_chip, name, layer):
+    s, n, dp, bb, _ = _layer_shapes(graphs, name, layer)
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                            sharding=one_chip)
+    _compile(functools.partial(shard_spmm.shard_spmm, block_b=bb,
+                               interpret=False),
+             f32((s, s, n, n)), f32((s, n, dp)))
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+@pytest.mark.parametrize("name", GRAPHS)
+def test_fused_gnn_layer_compiles(graphs, one_chip, name, layer):
+    s, n, dp, bb, f = _layer_shapes(graphs, name, layer)
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                            sharding=one_chip)
+    _compile(functools.partial(fused_gnn.fused_gnn_layer, block_b=bb,
+                               activation="relu", interpret=False),
+             f32((s, s, n, n)), f32((s, n, dp)), f32((dp, f)))
+
+
+@pytest.mark.parametrize("width", ["extract", "pool"])
+@pytest.mark.parametrize("layer", [0, 1])
+@pytest.mark.parametrize("name", GRAPHS)
+def test_dense_engine_matmul_compiles(graphs, one_chip, name, layer, width):
+    """The layer's Dense Engine matmul with bias, tiled as the pallas
+    backend tiles it: the feature extraction (d -> f) run two-stage, and
+    sage_max's pooling transform at the layer's width (d -> d)."""
+    s, n, _, _, f = _layer_shapes(graphs, name, layer)
+    d = graphs[name][1].layer_dims[layer][0]
+    out = f if width == "extract" else d
+    bk, kp = _feature_block(d, 128)
+    bn, np_ = _feature_block(out, 128)
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                            sharding=one_chip)
+    _compile(functools.partial(dense_engine.dense_engine_matmul,
+                               activation="relu", bm=128, bn=bn, bk=bk,
+                               interpret=False),
+             f32((s * n, kp)), f32((kp, np_)), f32((np_,)))
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+@pytest.mark.parametrize("name", GRAPHS)
+def test_seg_gather_aggregate_compiles(graphs, one_chip, name, layer):
+    s, n, dp, bb, _ = _layer_shapes(graphs, name, layer)
+    e = _max_pair_edges(graphs[name][0], n)
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    _compile(functools.partial(seg_gather.seg_gather_aggregate, op="max",
+                               block_b=bb, interpret=False),
+             i32((s, s, e)), i32((s, s, e)),
+             jax.ShapeDtypeStruct((s, s, e), jnp.bool_, sharding=one_chip),
+             jax.ShapeDtypeStruct((s, n, dp), jnp.float32,
+                                  sharding=one_chip))
+
+
+def test_fused_over_vmem_is_refused_and_flagged(graphs, one_chip):
+    """n=2048, B=128 overflows the fused kernel's VMEM: the compiler
+    refuses it, and the plan pass (PL003) flags the same plan."""
+    _, _, plan = graphs["pubmed"]
+    lp = plan.layers[0]
+    n, b = 2048, 128
+    big = plan_lint.LayerPlan(**{**lp.to_json(), "n": n, "B": b,
+                                 "S": -(-plan.num_nodes // n),
+                                 "fused": True})
+    rules = {f.rule for f in plan_lint.check_layer(plan, big,
+                                                   backend_name="pallas")}
+    assert "PL003" in rules
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                            sharding=one_chip)
+    with pytest.raises(Exception, match="vmem"):
+        _compile(functools.partial(fused_gnn.fused_gnn_layer, block_b=b,
+                                   interpret=False),
+                 f32((1, 1, n, n)), f32((1, n, b)), f32((b, 16)))
