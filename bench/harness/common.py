@@ -1,0 +1,170 @@
+"""What every cell shares: finding a cell's files by name, the compile
+cache and compile counter, the device check, and small statistics."""
+from __future__ import annotations
+
+import dataclasses
+import importlib.util
+import json
+import math
+import os
+import pathlib
+import threading
+
+BENCH = pathlib.Path(__file__).resolve().parents[1]
+ROOT = BENCH.parent
+
+
+class NoAccelerator(Exception):
+    """JAX found no TPU, or fewer chips than the cell asks for."""
+
+
+def load_json(path) -> dict:
+    return json.loads(pathlib.Path(path).read_text())
+
+
+def load_module(path, name: str | None = None):
+    """Import a Python file by path (drivers, references and metric readers
+    are found by name, not imported as a package)."""
+    path = pathlib.Path(path)
+    spec = importlib.util.spec_from_file_location(
+        name or f"bench_{path.stem.replace('.', '_').replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@dataclasses.dataclass
+class Cell:
+    """One entry of BENCHMARK.json's ``workloads`` with everything the
+    harness finds for it by name."""
+
+    name: str
+    chips: int
+    config: dict
+    traffic: dict
+    limits: dict
+    end_to_end: list
+    per_layer: list
+    driver_path: pathlib.Path
+    reference_path: pathlib.Path
+
+
+def _applies(metric: dict, cell: str) -> bool:
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+def resolve(workload: str, bench_json=None) -> Cell:
+    """Resolve a cell purely by name: its configuration file, its traffic
+    file (``traffic/<name>.json``), the driver of the traffic's ``kind``
+    (``drivers/<kind>.py``), the reference of the configuration's ``arch``
+    (``references/<arch>.py``), its limits (``limits/<cell>.json``) and the
+    metrics that apply to it.
+
+    A cell that BENCHMARK.json does not list may be a candidate, measured
+    but not admitted: ``candidates/<cell>.json`` then holds its workload
+    entry and its own metrics, in BENCHMARK.json's form."""
+    spec = load_json(bench_json or ROOT / "BENCHMARK.json")
+    cells = {w["name"]: w for w in spec["workloads"]}
+    candidate = BENCH / "candidates" / f"{workload}.json"
+    if workload not in cells and candidate.is_file():
+        extra = load_json(candidate)
+        cells[workload] = extra["workload"]
+        spec["end_to_end"] = spec["end_to_end"] + extra["end_to_end"]
+        spec["per_layer"] = spec["per_layer"] + extra["per_layer"]
+    if workload not in cells:
+        raise KeyError(f"unknown workload {workload!r}; known: "
+                       f"{sorted(cells)}")
+    w = cells[workload]
+    configs = {c["name"]: c for c in spec["configs"]}
+    config = load_json(ROOT / configs[w["config"]]["file"])
+    traffic = load_json(BENCH / "traffic" / f"{w['traffic']}.json")
+    return Cell(
+        name=workload, chips=int(w["chips"]), config=config, traffic=traffic,
+        limits=load_json(BENCH / "limits" / f"{workload}.json"),
+        end_to_end=[m for m in spec["end_to_end"] if _applies(m, workload)],
+        per_layer=[m for m in spec["per_layer"] if _applies(m, workload)],
+        driver_path=BENCH / "drivers" / f"{traffic['kind']}.py",
+        reference_path=BENCH / "references" / f"{config['arch']}.py")
+
+
+def metric_reader(name: str):
+    """The reader of a per-layer metric: ``metrics/<name>.py``'s ``read``."""
+    return load_module(BENCH / "metrics" / f"{name}.py").read
+
+
+def enable_compile_cache() -> str:
+    """JAX's persistent compilation cache: ``$JAX_COMPILATION_CACHE_DIR``
+    when set, else ``.jax_cache/`` at the checkout root (a fixed path, so a
+    later run finds it). Every program is written to it, however quick to
+    compile, so that only a cell's first run in a checkout compiles."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
+
+
+class CompileCounter:
+    """Counts programs JAX loads (compiled or read from the persistent
+    cache) and the persistent-cache hits among them, from JAX's own
+    monitoring events."""
+
+    _LOAD = "/jax/core/compile/backend_compile_duration"
+    _HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        import jax.monitoring as mon
+        self._lock = threading.Lock()
+        self.loads = 0
+        self.hits = 0
+        mon.register_event_duration_secs_listener(self._on_duration)
+        mon.register_event_listener(self._on_event)
+
+    def _on_duration(self, event: str, _secs: float, **_kw) -> None:
+        if event == self._LOAD:
+            with self._lock:
+                self.loads += 1
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == self._HIT:
+            with self._lock:
+                self.hits += 1
+
+    def snapshot(self) -> tuple[int, int]:
+        with self._lock:
+            return self.loads, self.hits
+
+
+def device_info(chips: int) -> dict:
+    """The device as JAX reports it; raises NoAccelerator without a TPU or
+    with fewer chips than the cell needs."""
+    import jax
+    devs = jax.devices()
+    if devs[0].platform != "tpu":
+        raise NoAccelerator(f"needs a TPU, JAX found {devs[0].platform}")
+    if len(devs) < chips:
+        raise NoAccelerator(f"needs {chips} chips, JAX found {len(devs)}")
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": chips}
+
+
+def peak_memory_bytes(chips: int) -> int | None:
+    import jax
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.devices()[:chips]]
+    peaks = [p for p in peaks if p is not None]
+    return max(peaks) if peaks else None
+
+
+def percentile(values, q: float) -> float:
+    """The q-th percentile by linear interpolation between order
+    statistics (numpy's default), over every value given."""
+    xs = sorted(values)
+    if not xs:
+        return math.nan
+    pos = (len(xs) - 1) * q / 100.0
+    lo = math.floor(pos)
+    hi = min(lo + 1, len(xs) - 1)
+    return xs[lo] + (xs[hi] - xs[lo]) * (pos - lo)
