@@ -1,0 +1,13 @@
+"""The dense extraction kernel's share of its roofline in full-graph
+inference: the least time the forward's extractions need (per operation,
+from the configuration's shapes) times the forwards, over the device time
+of the ``gnn_dense_engine`` kernel."""
+from bench.harness import kernels
+
+
+def read(ctx):
+    t = kernels.device_s(ctx["trace"], kernels.DENSE)
+    if t is None:
+        return None
+    need = kernels.roofline_s(ctx, "dense")
+    return 100.0 * need * ctx["counters"]["forwards"] / t

@@ -125,12 +125,16 @@ class GNNeratorController:
         """act((A · H) · W) — GCN-style layer body on grouped features."""
         if self.fuse and b is None:
             be = self.graph.backend or resolve("fused_aggregate_extract")
-            return be.fused_aggregate_extract(
-                gt.blocks, h, w, activation=activation,
-                block_b=self.graph.block_b)
-        agg = self.graph.aggregate(gt, h, op="linear")
+            with jax.named_scope("fused"):
+                return be.fused_aggregate_extract(
+                    gt.blocks, h, w, activation=activation,
+                    block_b=self.graph.block_b)
+        with jax.named_scope("aggregate"):
+            agg = self.graph.aggregate(gt, h, op="linear")
         s, n, d = agg.shape
-        out = self.dense(agg.reshape(s * n, d), w, b, activation=activation)
+        with jax.named_scope("extract"):
+            out = self.dense(agg.reshape(s * n, d), w, b,
+                             activation=activation)
         return out.reshape(s, n, -1)
 
     def dense_first(self, gt: GraphTensors, h: jax.Array, w_pool: jax.Array,

@@ -92,8 +92,6 @@ class PatchState:
         self.loops = bool(add_self_loops)
         self.slack = float(slack)
         self.n = int(n)
-        self.stats = {"applies": 0, "shards_patched_total": 0,
-                      "compactions": 0, "apply_ms_total": 0.0}
         self._init_from(np.asarray(edges, dtype=np.int64), int(num_nodes),
                         edge_capacity=edge_capacity)
 
@@ -269,16 +267,12 @@ class PatchState:
         Shapes (S and/or E_cap) may change; the consumer must re-check
         its template."""
         self._init_from(new_edges, new_num)
-        self.stats["compactions"] += 1
         return self._result(t0, rebuilt=True, reason=reason,
                             shards_patched=self.S * self.S, pairs=None,
                             **res_kw)
 
     def _result(self, t0: float, **kw) -> PatchResult:
         ms = (time.perf_counter() - t0) * 1e3
-        self.stats["applies"] += 1
-        self.stats["shards_patched_total"] += kw.get("shards_patched", 0)
-        self.stats["apply_ms_total"] += ms
         return PatchResult(shards_total=self.S * self.S, apply_ms=ms, **kw)
 
     # -- consumers ---------------------------------------------------------

@@ -21,6 +21,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import _activate
 
+# the kernel's name in the compiled program and in the profiler's trace
+KERNEL_NAME = "gnn_dense_engine"
+
 
 def _kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, activation: str, nk: int):
     k = pl.program_id(2)
@@ -93,4 +96,5 @@ def dense_engine_matmul(
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(*args)

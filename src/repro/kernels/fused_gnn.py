@@ -28,6 +28,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import _activate
 
+# the kernel's name in the compiled program and in the profiler's trace
+KERNEL_NAME = "gnn_fused_aggregate_extract"
+
 
 def _kernel(a_ref, h_ref, w_ref, o_ref, agg_ref, acc_ref, *, nd: int, ns: int,
             activation: str):
@@ -100,4 +103,5 @@ def fused_gnn_layer(
             pltpu.VMEM((n, f), jnp.float32),        # Dense Engine accumulator
         ],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(blocks, h, w)

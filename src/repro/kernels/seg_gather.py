@@ -22,6 +22,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# the kernel's name in the compiled program and in the profiler's trace
+KERNEL_NAME = "gnn_seg_gather"
+
 _NEG = -3.0e38  # python float: jnp constants would be captured as consts
 _TILE = 1024    # Mosaic's tiling of a 1-D int32 array in HBM
 
@@ -109,4 +112,5 @@ def seg_gather_aggregate(
                         pltpu.SMEM((ne,), jnp.int32),
                         pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(key, h)

@@ -27,6 +27,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# the kernel's name in the compiled program and in the profiler's trace
+KERNEL_NAME = "gnn_shard_spmm"
+
 
 def _kernel(a_ref, h_ref, o_ref, acc_ref, *, ns: int):
     j = pl.program_id(2)  # src shard (innermost, accumulated)
@@ -79,4 +82,5 @@ def shard_spmm(
         out_shape=jax.ShapeDtypeStruct((s, n, d), h.dtype),
         scratch_shapes=[pltpu.VMEM((n, block_b), jnp.float32)],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(blocks, h)

@@ -10,6 +10,7 @@ planner/shard/init/forward.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import os
 
@@ -66,6 +67,7 @@ def _as_graph(graph):
         f"tuple, got {type(graph).__name__}")
 
 
+@functools.partial(jax.profiler.annotate_function, name="gnn.compile")
 def compile(spec: ZooSpec, graph, *,
             platform: Platform = GNNERATOR,
             backend: str | registry.KernelBackend | None = None,
@@ -202,24 +204,27 @@ def compile(spec: ZooSpec, graph, *,
             "hub_cache": int(hub_cache) if partition == "fennel" else 0}
 
     plan_source, tune_report = "analytic", None
-    if plan == "autotune":
-        if mesh is not None:
-            raise ValueError(
-                "plan='autotune' measures the single-device forward and "
-                "cannot tune sharded (mesh=) execution yet; compile with "
-                "plan='analytic' on a mesh")
-        from repro import tune
-        rec = tune.autotune_plan(
-            spec, edges, num_nodes, backend=be, features=features,
-            params=params, budget=tune_budget, seed=tune_seed,
-            reps=tune_reps, warmup=tune_warmup, timeout_s=tune_timeout_s,
-            cache_dir=plan_cache_dir, store=the_store, graph_key=graph_key,
-            **{k: v for k, v in plan_kwargs.items() if k != "cache_dir"})
-        mplan, plan_source, tune_report = rec.plan, rec.plan_source, \
-            rec.report()
-    else:
-        mplan = plan_model(spec, num_nodes, int(edges.shape[0]),
-                           **plan_kwargs)
+    if plan == "autotune" and mesh is not None:
+        raise ValueError(
+            "plan='autotune' measures the single-device forward and "
+            "cannot tune sharded (mesh=) execution yet; compile with "
+            "plan='analytic' on a mesh")
+    with jax.profiler.TraceAnnotation("gnn.compile.plan"):
+        if plan == "autotune":
+            from repro import tune
+            rec = tune.autotune_plan(
+                spec, edges, num_nodes, backend=be, features=features,
+                params=params, budget=tune_budget, seed=tune_seed,
+                reps=tune_reps, warmup=tune_warmup,
+                timeout_s=tune_timeout_s, cache_dir=plan_cache_dir,
+                store=the_store, graph_key=graph_key,
+                **{k: v for k, v in plan_kwargs.items()
+                   if k != "cache_dir"})
+            mplan, plan_source, tune_report = rec.plan, rec.plan_source, \
+                rec.report()
+        else:
+            mplan = plan_model(spec, num_nodes, int(edges.shape[0]),
+                               **plan_kwargs)
 
     entry = the_store.get(graph_key, edges, num_nodes, mplan.shard_n,
                           spec.arch, features=features,

@@ -20,12 +20,14 @@ private one so its capacity and stats are isolated per engine instance.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.engines import GraphTensors
 from repro.gnn.models import graph_signature
@@ -83,7 +85,7 @@ class GraphStore:
         ``mutable=True`` builds through a PatchState with ``edge_slack``
         slack capacity so later :meth:`patch` calls stay in-template.
         """
-        from repro.runtime.forward import build_graph_tensors
+        from repro.core.sharding import shard_graph
 
         norm, loops = graph_signature(arch)
         key = (graph_key, version, norm, loops, shard_n)
@@ -101,16 +103,21 @@ class GraphStore:
                 return entry
             self.stats["misses"] += 1
             t0 = time.perf_counter()
-            if mutable:
-                from repro.graphs.patch import PatchState
-                ps = PatchState(edges, num_nodes, shard_n, normalize=norm,
-                                add_self_loops=loops, slack=edge_slack)
-                gt = ps.to_graph_tensors()
-            else:
-                ps = None
-                gt = build_graph_tensors(edges, num_nodes, shard_n, arch)
-            h = gt.group(jnp.asarray(features)) \
-                if features is not None else None
+            with TraceAnnotation("gnn.compile.shard"):
+                if mutable:
+                    from repro.graphs.patch import PatchState
+                    ps = PatchState(edges, num_nodes, shard_n,
+                                    normalize=norm, add_self_loops=loops,
+                                    slack=edge_slack)
+                else:
+                    ps = None
+                    sg = shard_graph(edges, num_nodes, shard_n,
+                                     normalize=norm, add_self_loops=loops)
+            with TraceAnnotation("gnn.compile.upload"):
+                gt = ps.to_graph_tensors() if mutable \
+                    else GraphTensors.from_sharded(sg)
+                h = gt.group(jnp.asarray(features)) \
+                    if features is not None else None
             entry = GraphEntry(gt=gt, h_grouped=h,
                                built_ms=(time.perf_counter() - t0) * 1e3,
                                version=version, patch_state=ps)
@@ -121,6 +128,7 @@ class GraphStore:
                 self.stats["evictions"] += 1
             return entry
 
+    @functools.partial(jax.profiler.annotate_function, name="graph.patch")
     def patch(self, graph_key, delta, *, old_version: int,
               new_version: int, features: np.ndarray | None = None) -> dict:
         """Advance every ``(graph_key, old_version)`` entry through one
@@ -160,10 +168,14 @@ class GraphStore:
                 ps = entry.patch_state
                 res = ps.apply(delta)
                 prev = None if res.rebuilt else entry.gt
-                entry.gt = ps.to_graph_tensors(prev=prev, pairs=res.pairs)
+                with TraceAnnotation("graph.upload"):
+                    entry.gt = ps.to_graph_tensors(prev=prev,
+                                                   pairs=res.pairs)
+                    if features is not None and \
+                            entry.h_grouped is not None:
+                        entry.h_grouped = entry.gt.group(
+                            jnp.asarray(features))
                 entry.version = new_version
-                if features is not None and entry.h_grouped is not None:
-                    entry.h_grouped = entry.gt.group(jnp.asarray(features))
                 self._entries[(graph_key, new_version) + k[2:]] = entry
                 self.stats["patches"] += 1
                 if res.rebuilt:

@@ -19,6 +19,7 @@ Executable.
 """
 from __future__ import annotations
 
+import functools
 import json
 import pathlib
 import time
@@ -141,8 +142,13 @@ class Executable:
         # node-batch gather, jitted over PADDED id vectors: ids arrive
         # bucketed to a power of two (`_gather_bucket`), so arbitrary batch
         # sizes share O(log max_batch) traces instead of one per distinct
-        # shape (the per-request dispatch-compile the retrace pass flags)
-        self._jit_gather = jax.jit(lambda logits, ids: logits[ids])
+        # shape (the per-request dispatch-compile the retrace pass flags).
+        # A function of its own per Executable, so that the jit cache the
+        # retrace pass counts holds this unit's traces only.
+        def gnn_node_gather(logits, ids):
+            return logits[ids]
+
+        self._jit_gather = jax.jit(gnn_node_gather)
 
     @staticmethod
     def _graph_args_of(gt: GraphTensors) -> tuple:
@@ -164,13 +170,14 @@ class Executable:
         spec, plan, backend = self.spec, self.plan, self.backend
         n, S = self.gt.n, self.gt.S
 
-        def fwd(p, h, blocks, e_src, e_dst, e_valid):
+        # the function's name names the jitted program (``jit_gnn_forward``)
+        def gnn_forward(p, h, blocks, e_src, e_dst, e_valid):
             g = GraphTensors(blocks=blocks, edge_src=e_src, edge_dst=e_dst,
                              edge_valid=e_valid, num_nodes=S * n, n=n, S=S)
             return _fwd.forward(spec, p, g, h, plans=plan.layers,
                                 backend=backend)
 
-        return fwd
+        return gnn_forward
 
     def _forward_with_args(self):
         """``(fn, graph_args)`` with ``fn(params, h_grouped, *graph_args)
@@ -195,6 +202,7 @@ class Executable:
     def backend_name(self) -> str:
         return self.backend.name
 
+    @functools.partial(jax.profiler.annotate_function, name="gnn.forward")
     def forward(self, params: dict | None = None,
                 features: np.ndarray | jax.Array | None = None) -> jax.Array:
         """Full-graph logits (N, num_classes).
@@ -218,7 +226,8 @@ class Executable:
                 out = self._jit_forward(p, h, *ga)
         # true-N slice OUTSIDE jit: node-count changes within the S·n
         # padding never perturb the trace
-        return out[: self.gt.num_nodes]
+        with jax.profiler.TraceAnnotation("gnn.forward.slice"):
+            return out[: self.gt.num_nodes]
 
     def _check_node_ids(self, node_ids) -> np.ndarray:
         """Validate ids against the compiled graph. Negative ids would
@@ -262,10 +271,12 @@ class Executable:
         parameter set, then every node-batch request is a pure gather."""
         if self._probs is None:
             logits = self.forward()
-            # the ONE deliberate materialization point: the softmax cache
-            # lives on host so every later request is a numpy gather
-            host = jax.device_get(logits)  # analyze: allow(host-sync)
-            self._probs = _softmax(np.asarray(host, dtype=np.float32))
+            with jax.profiler.TraceAnnotation("serve.softmax"):
+                # the ONE deliberate materialization point: the softmax
+                # cache lives on host so every later request is a numpy
+                # gather
+                host = jax.device_get(logits)  # analyze: allow(host-sync)
+                self._probs = _softmax(np.asarray(host, dtype=np.float32))
             self._stale = None      # one full recompute clears staleness
         return self._probs
 

@@ -32,6 +32,7 @@ deterministic resume (the sampler is seeded by step), straggler logging.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Sequence
 
 import jax
@@ -63,6 +64,26 @@ def _masked_accuracy(logits, labels, mask):
     m = mask.astype(jnp.float32)
     hit = (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
     return jnp.sum(hit * m) / jnp.maximum(jnp.sum(m), 1.0)
+
+
+def _loss(logits, labels, mask):
+    with jax.named_scope("loss"):
+        return masked_cross_entropy(logits, labels, mask)
+
+
+def _grad_and_update(loss_fn, params, opt_state, labels, mask, opt_cfg,
+                     schedule):
+    """The body both train steps share: value and gradient of
+    ``loss_fn`` (which returns ``(loss, logits)``), then one AdamW update.
+    Returns ``(params, opt_state, metrics)``."""
+    (loss, logits), grads = jax.value_and_grad(
+        loss_fn, has_aux=True)(params)
+    with jax.named_scope("adamw"):
+        params, opt_state, stats = adamw_update(
+            grads, opt_state, params, opt_cfg, schedule)
+    metrics = {"loss": loss, "acc": _masked_accuracy(logits, labels, mask),
+               **stats}
+    return params, opt_state, metrics
 
 
 def _pad_axis(x: np.ndarray, size: int, axis: int) -> np.ndarray:
@@ -146,21 +167,15 @@ class TrainableExecutable:
         graph arrays arrive as trailing step arguments."""
         opt_cfg, schedule = self.opt_cfg, self._schedule
 
-        def step(params, opt_state, h, labels, mask, *graph):
+        def gnn_train_step(params, opt_state, h, labels, mask, *graph):
             def loss_fn(p):
                 logits = fwd(p, h, *graph)
-                return masked_cross_entropy(logits, labels, mask), logits
+                return _loss(logits, labels, mask), logits
 
-            (loss, logits), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-            params, opt_state, stats = adamw_update(
-                grads, opt_state, params, opt_cfg, schedule)
-            metrics = {"loss": loss,
-                       "acc": _masked_accuracy(logits, labels, mask),
-                       **stats}
-            return params, opt_state, metrics
+            return _grad_and_update(loss_fn, params, opt_state, labels,
+                                    mask, opt_cfg, schedule)
 
-        return step
+        return gnn_train_step
 
     def _make_minibatch_builder(self) -> Callable:
         """numpy side of the mini-batch path: sample -> shard -> pad to
@@ -207,8 +222,8 @@ class TrainableExecutable:
         s_sub, n_sub, _ = self._mb_shape
         plans = self.minibatch_plan.layers
 
-        def step(params, opt_state, blocks, e_src, e_dst, e_valid,
-                 h, labels, mask):
+        def gnn_train_step(params, opt_state, blocks, e_src, e_dst, e_valid,
+                           h, labels, mask):
             gt = GraphTensors(blocks=blocks, edge_src=e_src, edge_dst=e_dst,
                               edge_valid=e_valid, num_nodes=budget,
                               n=n_sub, S=s_sub)
@@ -216,18 +231,12 @@ class TrainableExecutable:
             def loss_fn(p):
                 logits = _fwd.forward(spec, p, gt, h, plans=plans,
                                       backend=backend)
-                return masked_cross_entropy(logits, labels, mask), logits
+                return _loss(logits, labels, mask), logits
 
-            (loss, logits), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-            params, opt_state, stats = adamw_update(
-                grads, opt_state, params, opt_cfg, schedule)
-            metrics = {"loss": loss,
-                       "acc": _masked_accuracy(logits, labels, mask),
-                       **stats}
-            return params, opt_state, metrics
+            return _grad_and_update(loss_fn, params, opt_state, labels,
+                                    mask, opt_cfg, schedule)
 
-        return step
+        return gnn_train_step
 
     def update_sampler(self, sampler: NeighborSampler, *,
                        features: np.ndarray | None = None,
@@ -289,6 +298,8 @@ class TrainableExecutable:
             return self._full_batch
         return self._mb(step)
 
+    @functools.partial(jax.profiler.annotate_function,
+                       name="gnn.train_step")
     def step_fn(self, params, opt_state, batch):
         return self._jit_step(params, opt_state, *batch)
 

@@ -95,6 +95,47 @@ def _gat_layer(spec: ZooSpec, layer: dict, gt: GraphTensors, h: jax.Array,
     return out
 
 
+def _layer(spec: ZooSpec, layer: dict, gt: GraphTensors, h: jax.Array,
+           ctrl: GNNeratorController, act: str) -> jax.Array:
+    """One layer of ``spec.arch``, each stage under the named scope
+    (``aggregate``, ``extract``, ``fused`` or ``attention``) that its
+    operations carry in the compiled program's metadata."""
+    if spec.arch == "gcn":
+        # graph_first scopes its own stages: fused, or aggregate + extract
+        return ctrl.graph_first(gt, h, layer["w"], activation=act)
+    if spec.arch == "gat":
+        with jax.named_scope("attention"):
+            return _gat_layer(spec, layer, gt, h, ctrl, activation=act)
+    s, n, d = h.shape
+    if spec.arch == "sage_mean":
+        with jax.named_scope("aggregate"):
+            agg = ctrl.graph.aggregate(gt, h, op="linear")  # mean-normalized
+        with jax.named_scope("extract"):
+            cat = jnp.concatenate([agg, h], axis=-1).reshape(s * n, 2 * d)
+            return ctrl.dense(cat, layer["w"],
+                              activation=act).reshape(s, n, -1)
+    if spec.arch == "sage_max":
+        with jax.named_scope("extract"):
+            z = ctrl.dense(h.reshape(s * n, d), layer["w_pool"],
+                           layer["b_pool"], activation="relu")
+        with jax.named_scope("aggregate"):
+            zbar = ctrl.graph.aggregate(gt, z.reshape(s, n, d), op="max")
+        with jax.named_scope("extract"):
+            cat = jnp.concatenate([zbar, h], axis=-1).reshape(s * n, 2 * d)
+            return ctrl.dense(cat, layer["w"],
+                              activation=act).reshape(s, n, -1)
+    if spec.arch == "gin":
+        with jax.named_scope("aggregate"):
+            agg = ctrl.graph.aggregate(gt, h, op="linear")  # Σ, no self loop
+        with jax.named_scope("extract"):
+            x = (1.0 + layer["eps"]) * h + agg
+            hid = ctrl.dense(x.reshape(s * n, d), layer["w1"], layer["b1"],
+                             activation="relu")
+            return ctrl.dense(hid, layer["w2"], layer["b2"],
+                              activation=act).reshape(s, n, -1)
+    return h
+
+
 def forward(spec: ZooSpec, params: dict, gt: GraphTensors,
             h: jax.Array, *, plans: Sequence | None = None,
             backend: KernelBackend | None = None) -> jax.Array:
@@ -109,28 +150,6 @@ def forward(spec: ZooSpec, params: dict, gt: GraphTensors,
         plan = plans[i] if plans is not None else None
         ctrl = _controller(plan, backend)
         act = layer_activation(spec, i)
-        if spec.arch == "gcn":
-            h = ctrl.graph_first(gt, h, layer["w"], activation=act)
-        elif spec.arch == "sage_mean":
-            agg = ctrl.graph.aggregate(gt, h, op="linear")  # mean-normalized
-            s, n, d = h.shape
-            cat = jnp.concatenate([agg, h], axis=-1).reshape(s * n, 2 * d)
-            h = ctrl.dense(cat, layer["w"], activation=act).reshape(s, n, -1)
-        elif spec.arch == "sage_max":
-            s, n, d = h.shape
-            z = ctrl.dense(h.reshape(s * n, d), layer["w_pool"],
-                           layer["b_pool"], activation="relu")
-            zbar = ctrl.graph.aggregate(gt, z.reshape(s, n, d), op="max")
-            cat = jnp.concatenate([zbar, h], axis=-1).reshape(s * n, 2 * d)
-            h = ctrl.dense(cat, layer["w"], activation=act).reshape(s, n, -1)
-        elif spec.arch == "gin":
-            agg = ctrl.graph.aggregate(gt, h, op="linear")  # Σ, no self loop
-            x = (1.0 + layer["eps"]) * h + agg
-            s, n, d = x.shape
-            hid = ctrl.dense(x.reshape(s * n, d), layer["w1"], layer["b1"],
-                             activation="relu")
-            h = ctrl.dense(hid, layer["w2"], layer["b2"],
-                           activation=act).reshape(s, n, -1)
-        elif spec.arch == "gat":
-            h = _gat_layer(spec, layer, gt, h, ctrl, activation=act)
+        with jax.named_scope(f"layer{i}"):
+            h = _layer(spec, layer, gt, h, ctrl, act)
     return gt.ungroup(h)

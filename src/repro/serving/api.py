@@ -42,6 +42,8 @@ import threading
 import time
 from typing import Any, Hashable, Protocol, Sequence, runtime_checkable
 
+from jax.profiler import TraceAnnotation
+
 from repro.analyze.lock_sanitizer import new_condition, new_lock
 from repro.serving.scheduler import (MicroBatchScheduler, QueueEntry,
                                      SchedulerConfig)
@@ -208,11 +210,11 @@ class Server:
         the engine. Returns the number of tickets resolved (completed +
         expired + failed); 0 means nothing was dispatchable. Safe to call
         while a driver thread runs: step passes are serialized."""
-        with self._step_lock:
-            return self._step(force)
+        with self._step_lock, TraceAnnotation("serve.step") as span:
+            return self._step(force, span)
 
-    def _step(self, force: bool) -> int:
-        with self._cv:
+    def _step(self, force: bool, span: TraceAnnotation) -> int:
+        with self._cv, TraceAnnotation("serve.schedule"):
             now = self._clock()
             expired = self._sched.sweep_expired(now)
             for e in expired:
@@ -224,10 +226,13 @@ class Server:
                 return len(expired)
             key, entries = formed
             dispatch_s = now
+            span.set_metadata(batch=self._sched.stats["batches"],
+                              size=len(entries))
         payloads = [e.payload for e in entries]
         t0 = time.perf_counter()
         try:
-            results = list(self._engine.step(key, payloads))
+            with TraceAnnotation("serve.engine"):
+                results = list(self._engine.step(key, payloads))
             if len(results) != len(entries):
                 raise RuntimeError(
                     f"engine step returned {len(results)} results for "

@@ -46,12 +46,15 @@ computed across the mesh (``launch/serve.py --mesh N`` wires this up).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 import warnings
 from collections import OrderedDict
 from typing import Sequence
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import runtime
 from repro.gnn.executor import ModelPlan
@@ -259,6 +262,7 @@ class GNNServeEngine:
 
     # -- streaming mutation path -------------------------------------------
 
+    @functools.partial(jax.profiler.annotate_function, name="graph.mutate")
     def mutate(self, graph: str, delta) -> dict:
         """Apply one :class:`~repro.graphs.delta.GraphDelta` to a
         registered graph: mutate the GraphData in place (version bump),
@@ -289,7 +293,8 @@ class GNNServeEngine:
         t0 = time.perf_counter()
         edges_before = np.array(data.edges, copy=True)
         num_before = data.profile.num_nodes
-        apply_to_graph_data(data, delta)   # validates before first write
+        with TraceAnnotation("graph.apply_delta"):
+            apply_to_graph_data(data, delta)   # validates before 1st write
         old_v = self._graph_versions.get(graph, 0)
         new_v = int(data.version)
         self._graph_versions[graph] = new_v
@@ -308,59 +313,60 @@ class GNNServeEngine:
                                   else np.union1d(prev, touched))
 
         per_model = []
-        for key in [k for k in self._executables if k[1] == graph]:
-            model = key[0]
-            exe = self._executables[key]
-            spec = self._models[model].spec
-            norm, loops = graph_signature(spec.arch)
-            hit = patched.get((norm, loops, exe.plan.shard_n))
-            if hit is None:
-                # no surviving build for this signature (immutable entry
-                # dropped, or evicted under LRU) — recompile lazily
-                del self._executables[key]
-                self._stats["graph_recompiles"] += 1
-                per_model.append({"model": model, "recompile": True})
-                continue
-            entry, res = hit
-            targeted = (self.invalidation == "targeted"
-                        and not res.rebuilt)
-            stale = None
-            if targeted:
-                ps = entry.patch_state
-                seeds = seed_nodes(delta, edges_before, ps.edges,
-                                   num_before, norm)
-                stale = affected_nodes(ps.edges, seeds,
-                                       len(spec.layer_dims) - 1,
+        with TraceAnnotation("graph.invalidate"):
+            for key in [k for k in self._executables if k[1] == graph]:
+                model = key[0]
+                exe = self._executables[key]
+                spec = self._models[model].spec
+                norm, loops = graph_signature(spec.arch)
+                hit = patched.get((norm, loops, exe.plan.shard_n))
+                if hit is None:
+                    # no surviving build for this signature (immutable entry
+                    # dropped, or evicted under LRU) — recompile lazily
+                    del self._executables[key]
+                    self._stats["graph_recompiles"] += 1
+                    per_model.append({"model": model, "recompile": True})
+                    continue
+                entry, res = hit
+                targeted = (self.invalidation == "targeted"
+                            and not res.rebuilt)
+                stale = None
+                if targeted:
+                    ps = entry.patch_state
+                    seeds = seed_nodes(delta, edges_before, ps.edges,
+                                       num_before, norm)
+                    stale = affected_nodes(ps.edges, seeds,
+                                           len(spec.layer_dims) - 1,
+                                           data.profile.num_nodes)
+                try:
+                    rows = (exe._probs.shape[0]
+                            if exe.has_cached_probs else 0)
+                    # placement re-score hint for fennel-partitioned sharded
+                    # units: the patch's affected shard rows/cols (available
+                    # even under invalidation="full"); plain executables
+                    # ignore it
+                    refine = pair_rows(res.pairs, exe.gt.n,
                                        data.profile.num_nodes)
-            try:
-                rows = (exe._probs.shape[0]
-                        if exe.has_cached_probs else 0)
-                # placement re-score hint for fennel-partitioned sharded
-                # units: the patch's affected shard rows/cols (available
-                # even under invalidation="full"); plain executables
-                # ignore it
-                refine = pair_rows(res.pairs, exe.gt.n,
-                                   data.profile.num_nodes)
-                n_inv = exe.update_graph(entry.gt, entry.h_grouped,
-                                         stale_nodes=stale,
-                                         refine_nodes=refine)
-                exe.graph_version = new_v
-            except ValueError:
-                # compaction changed the template: drop + recompile lazily
-                del self._executables[key]
-                self._stats["graph_recompiles"] += 1
-                per_model.append({"model": model, "recompile": True})
-                continue
-            if targeted:
-                self._stats["targeted_invalidations"] += 1
-            else:
-                self._stats["full_invalidations"] += 1
-            self._stats["nodes_invalidated"] += n_inv
-            per_model.append({
-                "model": model, "recompile": False, "targeted": targeted,
-                "rows_invalidated": n_inv, "rows_cached": rows,
-                "affected_nodes": int(stale.size) if stale is not None
-                else data.profile.num_nodes})
+                    n_inv = exe.update_graph(entry.gt, entry.h_grouped,
+                                             stale_nodes=stale,
+                                             refine_nodes=refine)
+                    exe.graph_version = new_v
+                except ValueError:
+                    # compaction changed the template: drop + recompile lazily
+                    del self._executables[key]
+                    self._stats["graph_recompiles"] += 1
+                    per_model.append({"model": model, "recompile": True})
+                    continue
+                if targeted:
+                    self._stats["targeted_invalidations"] += 1
+                else:
+                    self._stats["full_invalidations"] += 1
+                self._stats["nodes_invalidated"] += n_inv
+                per_model.append({
+                    "model": model, "recompile": False, "targeted": targeted,
+                    "rows_invalidated": n_inv, "rows_cached": rows,
+                    "affected_nodes": int(stale.size) if stale is not None
+                    else data.profile.num_nodes})
         ms = (time.perf_counter() - t0) * 1e3
         self._stats["mutations"] += 1
         self._stats["mutate_ms_total"] += ms

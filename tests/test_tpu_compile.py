@@ -13,6 +13,7 @@ under several test workers only the worker running this file may load it.
 """
 import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -21,12 +22,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro import runtime
 from repro.analyze import plan_lint
 from repro.gnn.executor import plan_model
 from repro.gnn.models import ZooSpec
 from repro.graphs.datasets import make_dataset
 from repro.kernels import dense_engine, fused_gnn, seg_gather, shard_spmm
+from repro.kernels import registry
 from repro.kernels.registry import _feature_block
+from repro.runtime.fit import TrainableExecutable
 
 GRAPHS = ("cora", "pubmed")
 
@@ -179,3 +183,73 @@ def test_fused_over_vmem_is_refused_and_flagged(graphs, one_chip):
         _compile(functools.partial(fused_gnn.fused_gnn_layer, block_b=b,
                                    interpret=False),
                  f32((1, 1, n, n)), f32((1, n, b)), f32((b, 16)))
+
+
+def _custom_call_names(compiled) -> list[str]:
+    """The instruction names of the program's Pallas calls, their
+    ``.N`` suffixes stripped."""
+    text = compiled.as_text()
+    names = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    return [re.sub(r"\.\d+$", "", n) for n in names]
+
+
+def _train_step_compiled(graphs, one_chip, monkeypatch):
+    """The GCN full-batch train step (value_and_grad through the fused
+    kernel's custom VJP, AdamW) compiled at full cora shapes."""
+    monkeypatch.setattr(registry, "_interpret", lambda: False)
+    ds, spec, _ = graphs["cora"]
+    exe = runtime.compile(spec, ds, backend="pallas",
+                          store=runtime.GraphStore())
+    te = TrainableExecutable(exe, ds.labels)
+    avals = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), x.dtype,
+                                       sharding=one_chip),
+        (te.params, te.opt_state, te.data(0)))
+    p, s, batch = avals
+    return te._jit_step.lower(p, s, *batch).compile()
+
+
+@pytest.mark.parametrize("kernel", ["shard_spmm", "fused_gnn",
+                                    "dense_engine", "seg_gather",
+                                    "train_step"])
+def test_pallas_calls_carry_kernel_names(graphs, one_chip, monkeypatch,
+                                         kernel):
+    """Each Pallas call's instruction is named after its kernel's
+    constant, whatever jitted function it sits in: the profiler's trace
+    shows the instruction name."""
+    s, n, dp, bb, f = _layer_shapes(graphs, "cora", 0)
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                            sharding=one_chip)
+    if kernel == "shard_spmm":
+        compiled = _compile(functools.partial(
+            shard_spmm.shard_spmm, block_b=bb, interpret=False),
+            f32((s, s, n, n)), f32((s, n, dp)))
+        want = shard_spmm.KERNEL_NAME
+    elif kernel == "fused_gnn":
+        compiled = _compile(functools.partial(
+            fused_gnn.fused_gnn_layer, block_b=bb, interpret=False),
+            f32((s, s, n, n)), f32((s, n, dp)), f32((dp, f)))
+        want = fused_gnn.KERNEL_NAME
+    elif kernel == "dense_engine":
+        compiled = _compile(functools.partial(
+            dense_engine.dense_engine_matmul, activation="relu", bm=128,
+            bn=128, bk=bb, interpret=False),
+            f32((s * n, dp)), f32((dp, 128)), f32((128,)))
+        want = dense_engine.KERNEL_NAME
+    elif kernel == "seg_gather":
+        e = _max_pair_edges(graphs["cora"][0], n)
+        i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                                sharding=one_chip)
+        compiled = _compile(functools.partial(
+            seg_gather.seg_gather_aggregate, op="max", block_b=bb,
+            interpret=False),
+            i32((s, s, e)), i32((s, s, e)),
+            jax.ShapeDtypeStruct((s, s, e), jnp.bool_, sharding=one_chip),
+            f32((s, n, dp)))
+        want = seg_gather.KERNEL_NAME
+    else:
+        compiled = _train_step_compiled(graphs, one_chip, monkeypatch)
+        want = fused_gnn.KERNEL_NAME
+    names = _custom_call_names(compiled)
+    assert names and set(names) == {want}, names
