@@ -7,7 +7,10 @@ Controller's role — deciding the producer/consumer order and whether the
 two stages can be fine-grain pipelined — becomes a kernel-selection
 decision: graph-first layers with linear aggregation use the *fused*
 kernel (h_agg never leaves VMEM), everything else composes the two engine
-kernels through HBM exactly like the ASIC's feature memory.
+kernels through HBM exactly like the ASIC's feature memory. A layer that
+is linear up to its activation may run either way round; the controller
+makes the Dense Engine the producer where that walks the dense shard grid
+fewer times (``GNNeratorController.linear_layer``).
 """
 from __future__ import annotations
 
@@ -18,7 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sharding import ShardedGraph
-from repro.kernels.registry import KernelBackend, resolve
+from repro.kernels.ref import _activate
+from repro.kernels.registry import KernelBackend, grid_walks, resolve
 
 
 @dataclasses.dataclass(frozen=True)
@@ -136,6 +140,55 @@ class GNNeratorController:
             out = self.dense(agg.reshape(s * n, d), w, b,
                              activation=activation)
         return out.reshape(s, n, -1)
+
+    def producer_order(self, din: int, dout: int) -> tuple[str, int]:
+        """``(order, walks)`` of a linear layer from ``din`` to ``dout``
+        features: ``"dense-first"`` where aggregating the ``dout``
+        extracted features walks the shard grid strictly fewer times than
+        aggregating the ``din`` inputs, else ``"graph-first"``; ``walks``
+        counts the chosen order's full reads of the grid."""
+        first = grid_walks(din, self.graph.block_b)
+        after = grid_walks(dout, self.graph.block_b)
+        if after < first:
+            return "dense-first", after
+        return "graph-first", first
+
+    def linear_layer(self, gt: GraphTensors, h: jax.Array, w: jax.Array, *,
+                     activation: str = "none",
+                     concat_self: bool = False) -> jax.Array:
+        """A layer linear up to its activation, in ``producer_order``.
+
+        ``concat_self=False``: act(A · H · W) (GCN), W (din, dout).
+        ``concat_self=True``: act([A · H ; H] · W) (GraphSAGE-mean),
+        W (2·din, dout). Graph-first aggregates H and extracts after (the
+        fused kernel for GCN, where the controller fuses). Dense-first
+        extracts first and aggregates ``dout`` features: A · (H · W), and
+        A · (H · W_top) + H · W_bot from one dense call against
+        [W_top | W_bot]. A tie keeps graph-first, whose fused intermediate
+        never leaves VMEM.
+        """
+        s, n, din = h.shape
+        dout = w.shape[-1]
+        order, _ = self.producer_order(din, dout)
+        if order == "graph-first":
+            if not concat_self:
+                # graph_first scopes its own stages
+                return self.graph_first(gt, h, w, activation=activation)
+            with jax.named_scope("aggregate"):
+                agg = self.graph.aggregate(gt, h, op="linear")
+            with jax.named_scope("extract"):
+                cat = jnp.concatenate([agg, h], axis=-1)
+                return self.dense(cat.reshape(s * n, 2 * din), w,
+                                  activation=activation).reshape(s, n, dout)
+        with jax.named_scope("extract"):
+            if concat_self:
+                w = jnp.concatenate([w[:din], w[din:]], axis=1)
+            z = self.dense(h.reshape(s * n, din), w).reshape(s, n, -1)
+        with jax.named_scope("aggregate"):
+            out = self.graph.aggregate(gt, z[..., :dout], op="linear")
+            if concat_self:
+                out = out + z[..., dout:]
+            return _activate(out, activation)
 
     def dense_first(self, gt: GraphTensors, h: jax.Array, w_pool: jax.Array,
                     b_pool=None, *, activation: str = "none",

@@ -5,10 +5,14 @@ forward pass on shard-grouped features via the GNNerator engines. All three
 follow the paper's topology — one hidden layer of dimension 16 by default —
 but depth/width are configurable (the scaling benchmarks sweep them).
 
-GCN        : H' = relu(Â H W)                       (graph-first, fused)
-Graphsage  : z̄ = mean_{N(u)∪u} h ; h' = relu(W [z̄; h])   (graph-first)
+GCN        : H' = relu(Â H W)
+Graphsage  : z̄ = mean_{N(u)∪u} h ; h' = relu(W [z̄; h])
 GraphsagePool: z = relu(W_pool h) ; z̄ = max z ; h' = relu(W [z̄; h])
                                                      (dense-first!)
+
+GCN and Graphsage are linear up to their activation: the controller runs
+them graph-first (GCN fused) or dense-first, whichever walks the shard grid
+fewer times (``GNNeratorController.linear_layer``).
 """
 from __future__ import annotations
 
@@ -82,13 +86,9 @@ def make_forward(spec: GNNSpec,
         # h: (S, n, in_dim) shard-grouped (see GraphTensors.group)
         for i, layer in enumerate(params["layers"]):
             act = "relu" if i < n_layers - 1 else "none"
-            if spec.kind == "gcn":
-                h = ctrl.graph_first(gt, h, layer["w"], activation=act)
-            elif spec.kind == "graphsage":
-                agg = ctrl.graph.aggregate(gt, h, op="linear")  # mean norm
-                s, n, d = h.shape
-                cat = jnp.concatenate([agg, h], axis=-1).reshape(s * n, 2 * d)
-                h = ctrl.dense(cat, layer["w"], activation=act).reshape(s, n, -1)
+            if spec.kind in ("gcn", "graphsage"):   # mean norm for sage
+                h = ctrl.linear_layer(gt, h, layer["w"], activation=act,
+                                      concat_self=spec.kind == "graphsage")
             elif spec.kind == "graphsage_pool":
                 zbar = ctrl.dense_first(gt, h, layer["w_pool"],
                                         activation="relu", agg="max")

@@ -545,6 +545,11 @@ class ShardedExecutable(Executable):
 
     # -- introspection -----------------------------------------------------
 
+    def producer_orders(self) -> list[tuple[str, int]]:
+        """None to report: ``layer_body`` aggregates first in every layer,
+        over each device's share of the grid."""
+        return []
+
     def summary(self) -> str:
         head = super().summary()
         plan = self.partition

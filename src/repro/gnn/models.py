@@ -10,8 +10,8 @@ through feature memory.
 
 Architectures (all multi-layer, relu between layers, logits at the end):
 
-  gcn        H' = act(Â H W)                       graph-first, fusable
-  sage_mean  H' = act(W [mean_N∪u(H); H])          graph-first
+  gcn        H' = act(Â H W)                       either order, fusable
+  sage_mean  H' = act(W [mean_N∪u(H); H])          either order
   sage_max   z = relu(H W_p + b_p); z̄ = max_N z;
              H' = act(W [z̄; H])                    dense-first (pool)
   gin        H' = MLP((1+ε) H + Σ_N H)             graph-first, ε learnable
@@ -23,6 +23,11 @@ The GAT attention weights are computed per shard pair as an (S, S, n, n)
 head-block tensor and fed straight to the shard-grid SpMM kernel — the
 aggregation stays on the Graph Engine; only the masked softmax runs on the
 activation unit (plain jnp here).
+
+"Either order": the layer is linear up to its activation, so the
+controller runs it dense-first (aggregating after extraction) where that
+walks the shard grid fewer times, graph-first otherwise
+(``GNNeratorController.linear_layer``).
 """
 from __future__ import annotations
 

@@ -123,6 +123,14 @@ def _feature_block(d: int, block_b: int) -> tuple[int, int]:
     return bb, round_up(d, bb)
 
 
+def grid_walks(d: int, block_b: int) -> int:
+    """Times a graph kernel reads the whole (S, S, n, n) shard grid to
+    aggregate ``d`` features at requested block ``block_b``: once per
+    kernel feature block, after ``_feature_block``'s lane rounding."""
+    bb, dp = _feature_block(d, block_b)
+    return dp // bb
+
+
 def _pad(x, size, axis):
     pad = size - x.shape[axis]
     if pad <= 0:

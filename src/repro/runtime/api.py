@@ -67,7 +67,21 @@ def _as_graph(graph):
         f"tuple, got {type(graph).__name__}")
 
 
-@functools.partial(jax.profiler.annotate_function, name="gnn.compile")
+def _compile_span(fn):
+    """Run ``fn`` under the ``gnn.compile`` host span, whose argument
+    ``dense_first_layers`` counts the layers of the returned Executable
+    that run the Dense Engine first."""
+    @functools.wraps(fn)
+    def wrapper(*args, **kwargs):
+        with jax.profiler.TraceAnnotation("gnn.compile") as span:
+            exe = fn(*args, **kwargs)
+            span.set_metadata(dense_first_layers=sum(
+                o == "dense-first" for o, _ in exe.producer_orders()))
+            return exe
+    return wrapper
+
+
+@_compile_span
 def compile(spec: ZooSpec, graph, *,
             platform: Platform = GNNERATOR,
             backend: str | registry.KernelBackend | None = None,

@@ -404,6 +404,12 @@ class Executable:
 
     # -- introspection / serialization ------------------------------------
 
+    def producer_orders(self) -> list[tuple[str, int]]:
+        """``(order, grid walks)`` of each layer of the forward
+        (:func:`repro.runtime.forward.producer_orders`); empty where the
+        architecture's order is fixed."""
+        return _fwd.producer_orders(self.spec, self.plan.layers)
+
     def summary(self) -> str:
         n_params = sum(int(np.prod(np.shape(x)))
                        for x in jax.tree_util.tree_leaves(self.params))
@@ -429,6 +435,14 @@ class Executable:
                     f"{r['candidates_failed']} failed, "
                     f"{r.get('candidates_pruned', 0)} pruned)")
         lines.append(self.plan.summary())
+        orders = self.producer_orders()
+        if orders:
+            layers = ", ".join(
+                f"L{i} {o} {w} walk{'s' if w != 1 else ''}"
+                for i, (o, w) in enumerate(orders))
+            lines.append(f"  producer order: {layers}; "
+                         f"{sum(w for _, w in orders)} grid walks per "
+                         f"forward")
         return "\n".join(lines)
 
     def plan_json(self) -> dict:

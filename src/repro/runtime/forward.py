@@ -44,6 +44,11 @@ def layer_activation(spec: ZooSpec, i: int) -> str:
     return "relu" if i < len(spec.layer_dims) - 1 else "none"
 
 
+# architectures whose layers are linear up to their activation, so that
+# the controller may run either engine first (``linear_layer``)
+LINEAR_ARCHS = ("gcn", "sage_mean")
+
+
 def _controller(plan, backend: KernelBackend | None) -> GNNeratorController:
     b = plan.B if plan is not None else 128
     fused = plan.fused if plan is not None else True
@@ -100,20 +105,14 @@ def _layer(spec: ZooSpec, layer: dict, gt: GraphTensors, h: jax.Array,
     """One layer of ``spec.arch``, each stage under the named scope
     (``aggregate``, ``extract``, ``fused`` or ``attention``) that its
     operations carry in the compiled program's metadata."""
-    if spec.arch == "gcn":
-        # graph_first scopes its own stages: fused, or aggregate + extract
-        return ctrl.graph_first(gt, h, layer["w"], activation=act)
+    if spec.arch in LINEAR_ARCHS:
+        # linear_layer orders and scopes its own stages
+        return ctrl.linear_layer(gt, h, layer["w"], activation=act,
+                                 concat_self=spec.arch == "sage_mean")
     if spec.arch == "gat":
         with jax.named_scope("attention"):
             return _gat_layer(spec, layer, gt, h, ctrl, activation=act)
     s, n, d = h.shape
-    if spec.arch == "sage_mean":
-        with jax.named_scope("aggregate"):
-            agg = ctrl.graph.aggregate(gt, h, op="linear")  # mean-normalized
-        with jax.named_scope("extract"):
-            cat = jnp.concatenate([agg, h], axis=-1).reshape(s * n, 2 * d)
-            return ctrl.dense(cat, layer["w"],
-                              activation=act).reshape(s, n, -1)
     if spec.arch == "sage_max":
         with jax.named_scope("extract"):
             z = ctrl.dense(h.reshape(s * n, d), layer["w_pool"],
@@ -134,6 +133,22 @@ def _layer(spec: ZooSpec, layer: dict, gt: GraphTensors, h: jax.Array,
             return ctrl.dense(hid, layer["w2"], layer["b2"],
                               activation=act).reshape(s, n, -1)
     return h
+
+
+def producer_orders(spec: ZooSpec,
+                    plans: Sequence | None = None) -> list[tuple[str, int]]:
+    """``(order, grid walks)`` of each layer as :func:`forward` runs it:
+    ``dense-first``, ``graph-first`` or ``graph-first fused``. Empty for
+    an architecture outside ``LINEAR_ARCHS``, whose order is fixed."""
+    if spec.arch not in LINEAR_ARCHS:
+        return []
+    out = []
+    for i, (din, dout) in enumerate(spec.layer_dims):
+        ctrl = _controller(plans[i] if plans is not None else None, None)
+        order, walks = ctrl.producer_order(din, dout)
+        fused = order == "graph-first" and spec.arch == "gcn" and ctrl.fuse
+        out.append((order + " fused" if fused else order, walks))
+    return out
 
 
 def forward(spec: ZooSpec, params: dict, gt: GraphTensors,

@@ -150,6 +150,36 @@ def test_dense_engine_matmul_compiles(graphs, one_chip, name, layer, width):
              f32((s * n, kp)), f32((kp, np_)), f32((np_,)))
 
 
+@pytest.mark.parametrize("width", [16, 3])
+def test_shard_spmm_compiles_dense_first(graphs, one_chip, width):
+    """The aggregation of a dense-first layer at full pubmed shapes: GCN
+    layer 0's 16 extracted features, SAGE-mean layer 1's 3 (padded to
+    8), each one full-width block."""
+    s, n = graphs["pubmed"][2].layers[0].S, graphs["pubmed"][2].shard_n
+    bb, dp = _feature_block(width, 16)
+    assert bb == dp
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                            sharding=one_chip)
+    _compile(functools.partial(shard_spmm.shard_spmm, block_b=bb,
+                               interpret=False),
+             f32((s, s, n, n)), f32((s, n, dp)))
+
+
+@pytest.mark.parametrize("k,m", [(500, 16), (256, 6)])
+def test_dense_engine_compiles_dense_first(one_chip, k, m):
+    """The extraction of a dense-first layer over full pubmed's 20480
+    padded rows, tiled as the pallas backend tiles it, with no bias: GCN
+    layer 0 (500 -> 16), and SAGE-mean layer 1 (256 -> 3 + 3, against
+    [W_top | W_bot])."""
+    bk, kp = _feature_block(k, 128)
+    bn, np_ = _feature_block(m, 128)
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                            sharding=one_chip)
+    _compile(functools.partial(dense_engine.dense_engine_matmul, bm=128,
+                               bn=bn, bk=bk, interpret=False),
+             f32((20480, kp)), f32((kp, np_)))
+
+
 @pytest.mark.parametrize("layer", [0, 1])
 @pytest.mark.parametrize("name", GRAPHS)
 def test_seg_gather_aggregate_compiles(graphs, one_chip, name, layer):
@@ -195,8 +225,9 @@ def _custom_call_names(compiled) -> list[str]:
 
 
 def _train_step_compiled(graphs, one_chip, monkeypatch):
-    """The GCN full-batch train step (value_and_grad through the fused
-    kernel's custom VJP, AdamW) compiled at full cora shapes."""
+    """The GCN full-batch train step (value_and_grad through the kernels'
+    custom VJPs, AdamW) compiled at full cora shapes: layer 0 (1433 ->
+    16) runs dense-first, layer 1 the fused kernel."""
     monkeypatch.setattr(registry, "_interpret", lambda: False)
     ds, spec, _ = graphs["cora"]
     exe = runtime.compile(spec, ds, backend="pallas",
@@ -225,18 +256,18 @@ def test_pallas_calls_carry_kernel_names(graphs, one_chip, monkeypatch,
         compiled = _compile(functools.partial(
             shard_spmm.shard_spmm, block_b=bb, interpret=False),
             f32((s, s, n, n)), f32((s, n, dp)))
-        want = shard_spmm.KERNEL_NAME
+        want = {shard_spmm.KERNEL_NAME}
     elif kernel == "fused_gnn":
         compiled = _compile(functools.partial(
             fused_gnn.fused_gnn_layer, block_b=bb, interpret=False),
             f32((s, s, n, n)), f32((s, n, dp)), f32((dp, f)))
-        want = fused_gnn.KERNEL_NAME
+        want = {fused_gnn.KERNEL_NAME}
     elif kernel == "dense_engine":
         compiled = _compile(functools.partial(
             dense_engine.dense_engine_matmul, activation="relu", bm=128,
             bn=128, bk=bb, interpret=False),
             f32((s * n, dp)), f32((dp, 128)), f32((128,)))
-        want = dense_engine.KERNEL_NAME
+        want = {dense_engine.KERNEL_NAME}
     elif kernel == "seg_gather":
         e = _max_pair_edges(graphs["cora"][0], n)
         i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
@@ -247,9 +278,10 @@ def test_pallas_calls_carry_kernel_names(graphs, one_chip, monkeypatch,
             i32((s, s, e)), i32((s, s, e)),
             jax.ShapeDtypeStruct((s, s, e), jnp.bool_, sharding=one_chip),
             f32((s, n, dp)))
-        want = seg_gather.KERNEL_NAME
+        want = {seg_gather.KERNEL_NAME}
     else:
         compiled = _train_step_compiled(graphs, one_chip, monkeypatch)
-        want = fused_gnn.KERNEL_NAME
+        want = {fused_gnn.KERNEL_NAME, shard_spmm.KERNEL_NAME,
+                dense_engine.KERNEL_NAME}
     names = _custom_call_names(compiled)
-    assert names and set(names) == {want}, names
+    assert names and set(names) == want, names

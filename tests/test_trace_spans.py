@@ -113,6 +113,15 @@ def test_serve_step_names_its_batch(spans):
     assert batches == sorted(batches) and len(set(batches)) == len(batches)
 
 
+def test_compile_counts_its_dense_first_layers(spans):
+    """cora's 1433 features take 12 walks of the grid graph-first and one
+    once extracted to 8: every compile of the gcn runs layer 0 dense-first
+    and layer 1 graph-first."""
+    counts = [st.get("dense_first_layers") for n, _, _, st in spans
+              if n == "gnn.compile"]
+    assert len(counts) >= 2 and all(c == 1 for c in counts), counts
+
+
 def test_jitted_programs_carry_names_and_scopes():
     ds = make_dataset("cora", seed=0, scale=0.05)
     spec = ZooSpec("sage_mean", ds.profile.feature_dim, 8,
