@@ -1,7 +1,9 @@
 """Closed-loop full-batch training: back-to-back steps of one compiled
 ``TrainableExecutable`` (forward through the Pallas kernels the plan
-picks, the custom-VJP backward, AdamW), the next step dispatched as soon
-as the one before it has finished.
+picks, the custom-VJP backward, AdamW). The host dispatches steps ahead of
+the chip, up to the traffic's ``ahead_s`` seconds of them, so that a stall
+of the host shorter than that leaves the chip fed; losses are read after
+the window.
 
 Set-up compiles the model, builds the trainable object and drives it from
 the seed through its first steps with the window's own call and batch;
@@ -64,20 +66,25 @@ class Run:
     def window(self, seconds: float) -> dict:
         p, s = self.state
         span = self.ctx.span
-        losses, prev, n = [], None, 0
+        ahead_s = float(self.ctx.cell.traffic["ahead_s"])
+        losses, n, done = [], 0, 0
         t0 = time.perf_counter()
         while True:
             with span("bench.train_step"):
                 p, s, m = self.step(p, s, self.batch)
-            # keep one step queued behind the running one, no more
-            if prev is not None:
-                prev.block_until_ready()
-            prev = m["loss"]
-            losses.append(prev)
+            losses.append(m["loss"])
             n += 1
             if time.perf_counter() - t0 >= seconds:
                 break
-        jax.block_until_ready((p, s))
+            # queue no more than ahead_s seconds of steps, by the rate of
+            # the steps waited for so far: wait on the oldest beyond that
+            while n - done > max(1.0, ahead_s * done
+                                 / (time.perf_counter() - t0)):
+                losses[done].block_until_ready()
+                done += 1
+        # nothing more is sent; all that was sent counts, over all the time
+        # it took
+        jax.block_until_ready((p, s, losses))
         t1 = time.perf_counter()
         self.state = (p, s)
         vals = np.asarray(jax.device_get(losses), np.float64)

@@ -4,23 +4,45 @@ trace (``trace.reduce``).
 The program names each Pallas call after its kernel; the trace shows the
 call's HLO instruction, which XLA names ``<kernel>.<N>``, one ``N`` per
 call site. A kernel's time is the sum over its instructions among the
-reduction's ``device_ops``. These are the program's names as of their
-introduction: they are the benchmark's own record of them, so a rename in
-the program shows here as a missing kernel, not as a quietly moved
-metric.
+reduction's ``device_ops``.
+
+The benchmark keeps its own record of the program's kernels, one file
+each: ``kernels/<name>.json`` gives the name, the module that defines it
+(``KERNEL_NAME``) and the work kinds (``work.Op.kind``) the kernel runs.
+A rename in the program then shows here as a missing kernel, not as a
+quietly moved metric, and a new kernel is added with a new file.
 """
 from __future__ import annotations
 
 import collections
+import pathlib
 import re
 
 from bench.harness import work
+from bench.harness.common import BENCH, load_json
 
+KERNELS = BENCH / "kernels"
+FIELDS = {"name", "module", "work"}
+
+
+def records(directory=KERNELS) -> dict[str, dict]:
+    """Every kernel file in ``directory``, by name. A file must hold just
+    ``FIELDS``, and be named after its kernel."""
+    out = {}
+    for path in sorted(pathlib.Path(directory).glob("*.json")):
+        rec = load_json(path)
+        if set(rec) != FIELDS or rec["name"] != path.stem:
+            raise ValueError(f"{path}: a kernel file holds {sorted(FIELDS)}"
+                             f" and is named after its kernel, not {rec}")
+        out[rec["name"]] = rec
+    return out
+
+
+NAMES = tuple(records())
 FUSED = "gnn_fused_aggregate_extract"
 SPMM = "gnn_shard_spmm"
 DENSE = "gnn_dense_engine"
 GATHER = "gnn_seg_gather"
-NAMES = (FUSED, SPMM, DENSE, GATHER)
 
 # the share of Pallas time the named kernels may leave unaccounted
 UNACCOUNTED = 0.01
@@ -63,7 +85,8 @@ def device_s(red: dict, name: str) -> float | None:
 
 def roofline_s(ctx: dict, kind: str | None = None) -> float:
     """Least time of one forward's required work (``work.forward_ops``),
-    of the operations of ``kind`` (``agg``, ``dense``) or of all."""
+    of the operations of ``kind`` (``agg``, ``dense`` or a counted
+    operation's own) or of all."""
     return sum(op.roofline_s(ctx["peak"])
                for op in work.forward_ops(ctx["config"], ctx["ref_mod"])
                if kind is None or op.kind == kind)

@@ -4,10 +4,15 @@ from __future__ import annotations
 
 
 def zoo_spec(cfg: dict):
+    """The program's ``ZooSpec`` of a configuration: its arch, widths and
+    depth, and every key of its ``spec`` object as a keyword argument. A
+    key ``ZooSpec`` does not have raises ``TypeError``; none is dropped,
+    so a configuration never runs with a model key other than it states."""
     from repro.gnn.models import ZooSpec
     g = cfg["graph"]
     return ZooSpec(cfg["arch"], g["feature_dim"], cfg["hidden_dim"],
-                   g["num_classes"], num_layers=cfg["num_layers"])
+                   g["num_classes"], num_layers=cfg["num_layers"],
+                   **cfg.get("spec", {}))
 
 
 def compile_program(ctx):

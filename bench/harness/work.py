@@ -4,7 +4,10 @@ that it reads the same whatever implements the operation.
   aggregation over d features: 2 nnz d FLOPs, nnz 8 + 2 N d 4 bytes
     (one f32 weight and one int32 index per nonzero, features read and
     written once), where nnz counts the edges and one self loop per node;
-  dense extraction (k -> m): 2 N k m FLOPs, 4 (N k + k m + N m) bytes.
+  dense extraction (k -> m): 2 N k m FLOPs, 4 (N k + k m + N m) bytes;
+  an operation the reference counts itself, such as an edge softmax's
+    per-edge, per-head scores, exponent, normalisation and weighted sum:
+    the kind, FLOPs and bytes it gives.
 
 It is never the MACs of the dense (n x n) blocks the kernels walk: a
 sparser kernel must raise a share of the roofline, not shrink its base.
@@ -19,7 +22,7 @@ from bench.harness.common import BENCH, load_json
 
 @dataclasses.dataclass(frozen=True)
 class Op:
-    kind: str       # "agg" | "dense"
+    kind: str       # "agg" | "dense" | a counted operation's own kind
     flops: float
     bytes: float
 
@@ -37,7 +40,8 @@ def nnz(cfg: dict) -> int:
 
 def forward_ops(cfg: dict, ref_mod) -> list[Op]:
     """The forward's required operations, in order, from the reference's
-    ``ops(cfg)`` list of ("agg", d) and ("dense", k, m)."""
+    ``ops(cfg)`` list of ("agg", d), ("dense", k, m) and
+    ("counted", kind, flops, bytes)."""
     n, z = cfg["graph"]["num_nodes"], nnz(cfg)
     out = []
     for op in ref_mod.ops(cfg):
@@ -48,6 +52,11 @@ def forward_ops(cfg: dict, ref_mod) -> list[Op]:
             k, m = op[1], op[2]
             out.append(Op("dense", 2.0 * n * k * m,
                           4.0 * (n * k + k * m + n * m)))
+        elif op[0] == "counted":
+            _, kind, flops, nbytes = op
+            if flops < 0 or nbytes < 0:
+                raise ValueError(f"negative work in {op!r}")
+            out.append(Op(kind, float(flops), float(nbytes)))
         else:
             raise ValueError(f"unknown op {op!r}")
     return out

@@ -46,3 +46,49 @@ def test_roofline_time_takes_the_larger_bound():
     assert op.roofline_s(peak) == pytest.approx(2.0)
     with pytest.raises(KeyError):
         work.peak("no such chip")
+
+
+class _Counted:
+    """A reference whose edge softmax counts its own work: per edge and
+    head a score, its exponent, the normalisation and the weighted sum."""
+
+    @staticmethod
+    def ops(cfg):
+        z, heads, d = work.nnz(cfg), 8, 8
+        return [("dense", 500, 64),
+                ("counted", "attention", 2.0 * z * heads * (3 + d),
+                 z * (8.0 + 4 * heads)),
+                ("agg", 64)]
+
+
+def test_counted_op_keeps_its_kind_and_work():
+    from bench.harness import kernels
+    cfg, _ = _config("gcn-pubmed")
+    ops = work.forward_ops(cfg, _Counted)
+    assert [o.kind for o in ops] == ["dense", "attention", "agg"]
+    z = 108_365
+    assert ops[1] == work.Op("attention", 2.0 * z * 8 * 11, z * 40.0)
+    # agg and dense are counted as ever beside it
+    assert ops[2].flops == 2 * z * 64 and ops[0].flops == 2 * 19_717 * 500 * 64
+    peak = work.peak("TPU v5 lite")
+    ctx = {"config": cfg, "ref_mod": _Counted, "peak": peak}
+    assert kernels.roofline_s(ctx, "attention") == pytest.approx(
+        max(ops[1].flops / peak["flops_per_s"],
+            ops[1].bytes / peak["bytes_per_s"]))
+    assert kernels.roofline_s(ctx) == pytest.approx(
+        sum(kernels.roofline_s(ctx, k) for k in ("dense", "attention", "agg")))
+    assert work.forward_flops(cfg, _Counted) == sum(o.flops for o in ops)
+
+
+@pytest.mark.parametrize("op", [("counted", "attention", -1.0, 0.0),
+                                ("softmax", 8)])
+def test_malformed_op_is_refused(op):
+    cfg, _ = _config("gcn-pubmed")
+
+    class Ref:
+        @staticmethod
+        def ops(cfg):
+            return [op]
+
+    with pytest.raises(ValueError):
+        work.forward_ops(cfg, Ref)
