@@ -99,17 +99,23 @@ class GraphEngine:
         """h: (S, n, D) shard-grouped. Linear = weights baked into blocks
         (sum/mean/gcn); max/sum go through the edge-list gather kernel."""
         if op == "linear":
-            return self.spmm(gt.blocks, h)
+            be = self.backend or resolve("graph_aggregate")
+            return be.graph_aggregate(gt.blocks, h, block_b=self.block_b)
         be = self.backend or resolve("gather_aggregate")
         return be.gather_aggregate(gt.edge_src, gt.edge_dst, gt.edge_valid,
                                    h, op=op, block_b=self.block_b)
 
-    def spmm(self, blocks: jax.Array, h: jax.Array) -> jax.Array:
-        """Shard-grid SpMM on explicit (S, S, n, n) blocks — used directly
-        by attention-weighted aggregation (GAT), where the weights are not
-        baked into the cached GraphTensors."""
-        be = self.backend or resolve("graph_aggregate")
-        return be.graph_aggregate(blocks, h, block_b=self.block_b)
+    def edge_softmax_aggregate(self, gt: GraphTensors, z: jax.Array,
+                               s_src: jax.Array, s_dst: jax.Array, *,
+                               negative_slope: float) -> jax.Array:
+        """Attention-weighted aggregation of every head (GAT) in one op:
+        z (S, n, H·F) head-major, s_src/s_dst (S, n, H) per-node scores.
+        The weights are a softmax over each node's in-edges on the binary
+        blocks, so they never exist as a grid of their own."""
+        be = self.backend or resolve("edge_softmax_aggregate")
+        return be.edge_softmax_aggregate(
+            gt.blocks, z, s_src, s_dst, heads=s_src.shape[-1],
+            negative_slope=negative_slope)
 
 
 @dataclasses.dataclass(frozen=True)

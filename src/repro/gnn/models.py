@@ -8,21 +8,23 @@ composed by the GNNeratorController. Per layer, an executor-provided
 whether the two stages run fused (h_agg never leaves VMEM) or two-stage
 through feature memory.
 
-Architectures (all multi-layer, relu between layers, logits at the end):
+Architectures (all multi-layer, relu between layers, logits at the end;
+GAT has ELU between its layers, as published):
 
   gcn        H' = act(Â H W)                       either order, fusable
   sage_mean  H' = act(W [mean_N∪u(H); H])          either order
   sage_max   z = relu(H W_p + b_p); z̄ = max_N z;
              H' = act(W [z̄; H])                    dense-first (pool)
   gin        H' = MLP((1+ε) H + Σ_N H)             graph-first, ε learnable
-  gat        H' = act(‖_heads Σ_u α_vu z_u)        attention-weighted shard
-                                                   SpMM (α baked into the
-                                                   block grid per head)
+  gat        H' = elu(‖_heads Σ_u α_vu z_u)        z = H W, then one edge
+             logits = mean_heads Σ_u α_vu z_u      softmax aggregation of
+                                                   all heads per layer
 
-The GAT attention weights are computed per shard pair as an (S, S, n, n)
-head-block tensor and fed straight to the shard-grid SpMM kernel — the
-aggregation stays on the Graph Engine; only the masked softmax runs on the
-activation unit (plain jnp here).
+GAT's attention runs in one Graph Engine op per layer
+(``GraphEngine.edge_softmax_aggregate``): every head's softmax over each
+node's in-edges and its weighted sum, in one walk of the binary shard
+grid, so the weights α never exist as a grid of their own. Hidden layers
+concatenate ``heads`` heads; the output layer averages ``out_heads``.
 
 "Either order": the layer is linear up to its activation, so the
 controller runs it dense-first (aggregating after extraction) where that
@@ -84,7 +86,8 @@ class ZooSpec:
     hidden_dim: int
     out_dim: int
     num_layers: int = 2
-    heads: int = 2                 # GAT hidden layers (output layer: 1 head)
+    heads: int = 2                 # GAT hidden layers, concatenated
+    out_heads: int = 1             # GAT output layer, averaged
     eps_init: float = 0.0          # GIN ε initial value (learnable)
     negative_slope: float = 0.2    # GAT LeakyReLU
 
@@ -95,6 +98,8 @@ class ZooSpec:
             raise ValueError("need at least one layer")
         if self.arch == "gat" and self.hidden_dim % self.heads:
             raise ValueError("gat: hidden_dim must divide by heads")
+        if self.heads < 1 or self.out_heads < 1:
+            raise ValueError("need at least one head")
 
     @property
     def layer_dims(self) -> list[tuple[int, int]]:
@@ -106,8 +111,10 @@ class ZooSpec:
         """Feature dim live at aggregation time (what the planner blocks)."""
         din, dout = self.layer_dims[layer]
         if self.arch == "gat":
-            # aggregation runs over z = h W (all heads)
-            return dout
+            # aggregation runs over z = h W (all heads; the output layer's
+            # out_heads are averaged after it)
+            last = layer == len(self.layer_dims) - 1
+            return dout * self.out_heads if last else dout
         return din   # gcn/sage_mean/gin aggregate h; sage_max pools at din
 
 
@@ -137,10 +144,10 @@ def init_zoo(key: jax.Array, spec: ZooSpec) -> dict:
                      "w2": _glorot(k2, (dout, dout)),
                      "b2": jnp.zeros((dout,), jnp.float32)}
         elif spec.arch == "gat":
-            heads = spec.heads if i < spec.num_layers - 1 else 1
-            hd = dout // heads
-            if heads * hd != dout:
-                raise ValueError(f"gat layer {i}: {dout} !% {heads} heads")
+            if i < spec.num_layers - 1:
+                heads, hd = spec.heads, dout // spec.heads
+            else:
+                heads, hd = spec.out_heads, dout
             layer = {"w": _glorot(k1, (din, heads * hd)),
                      "a_src": _glorot(k2, (heads, hd)),
                      "a_dst": _glorot(k3, (heads, hd))}

@@ -15,6 +15,8 @@ def _activate(x, activation: str):
         return x
     if activation == "relu":
         return jax.nn.relu(x)
+    if activation == "elu":
+        return jax.nn.elu(x)
     if activation == "gelu":
         return jax.nn.gelu(x)
     if activation == "silu":
@@ -147,6 +149,44 @@ def gin_layer(adj_sum, h, eps, w1, b1, w2, b2, *, activation: str = "none"):
     return dense_engine(hid, w2, b2, activation=activation)
 
 
+def edge_softmax(adj_mask, z, s_src, s_dst, *, negative_slope: float = 0.2):
+    """Attention-weighted aggregation of every head (GAT's edge softmax).
+
+    adj_mask: (V, U) nonzero where edge u->v exists at [v, u]; z: (U, H, F);
+    s_src: (U, H); s_dst: (V, H). Returns (V, H, F):
+    out[v, h] = Σ_u α_vuh z[u, h], α_vuh = softmax over u in N(v) of
+    leakyrelu(s_dst[v, h] + s_src[u, h]); 0 for a row with no edge.
+    """
+    logits = s_dst[:, None, :] + s_src[None, :, :]          # (V, U, H)
+    logits = jax.nn.leaky_relu(logits, negative_slope)
+    mask = (adj_mask != 0)[:, :, None]
+    logits = jnp.where(mask, logits, -jnp.inf)
+    m = jnp.max(logits, axis=1, keepdims=True)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    e = jnp.where(mask, jnp.exp(logits - m), 0.0)
+    denom = jnp.sum(e, axis=1, keepdims=True)
+    alpha = jnp.where(denom > 0, e / jnp.maximum(denom, 1e-30), 0.0)
+    return jnp.einsum("vuh,uhf->vhf", alpha, z.astype(jnp.float32))
+
+
+def edge_softmax_aggregate(blocks, z, s_src, s_dst, *, heads: int,
+                           negative_slope: float = 0.2):
+    """Edge softmax aggregation on the shard grid (the Graph Engine's
+    attention op), flattened to :func:`edge_softmax`.
+
+    blocks: (S, S, n, n) adjacency; z: (S, n, H·F) head-major features;
+    s_src/s_dst: (S, n, H) scores. Returns (S, n, H·F).
+    """
+    s, _, n, _ = blocks.shape
+    d = z.shape[-1]
+    mask = (blocks != 0).transpose(0, 2, 1, 3).reshape(s * n, s * n)
+    out = edge_softmax(mask, z.reshape(s * n, heads, d // heads),
+                       s_src.reshape(s * n, heads).astype(jnp.float32),
+                       s_dst.reshape(s * n, heads).astype(jnp.float32),
+                       negative_slope=negative_slope)
+    return out.reshape(s, n, d).astype(z.dtype)
+
+
 def gat_layer(adj_mask, h, w, a_src, a_dst, *, negative_slope: float = 0.2,
               activation: str = "none", concat_heads: bool = True):
     """Multi-head GAT layer.
@@ -162,16 +202,8 @@ def gat_layer(adj_mask, h, w, a_src, a_dst, *, negative_slope: float = 0.2,
                 preferred_element_type=jnp.float32).reshape(n, heads, f)
     s_src = jnp.einsum("nhf,hf->nh", z, a_src.astype(jnp.float32))
     s_dst = jnp.einsum("nhf,hf->nh", z, a_dst.astype(jnp.float32))
-    logits = s_dst[:, None, :] + s_src[None, :, :]          # (V, U, H)
-    logits = jax.nn.leaky_relu(logits, negative_slope)
-    mask = (adj_mask != 0)[:, :, None]
-    logits = jnp.where(mask, logits, -jnp.inf)
-    m = jnp.max(logits, axis=1, keepdims=True)
-    m = jnp.where(jnp.isfinite(m), m, 0.0)
-    e = jnp.where(mask, jnp.exp(logits - m), 0.0)
-    denom = jnp.sum(e, axis=1, keepdims=True)
-    alpha = jnp.where(denom > 0, e / jnp.maximum(denom, 1e-30), 0.0)
-    out = jnp.einsum("vuh,uhf->vhf", alpha, z)
+    out = edge_softmax(adj_mask, z, s_src, s_dst,
+                       negative_slope=negative_slope)
     out = out.reshape(n, heads * f) if concat_heads else out.mean(axis=1)
     return _activate(out, activation).astype(h.dtype)
 

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import dense_engine as _de
+from repro.kernels import edge_softmax as _es
 from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_gnn as _fg
 from repro.kernels import ref
@@ -42,7 +43,7 @@ DEFAULT_BACKEND = "pallas"
 
 # the ops every backend must provide (= the registry's per-op override keys)
 OP_NAMES = ("dense_matmul", "graph_aggregate", "fused_aggregate_extract",
-            "gather_aggregate", "attention")
+            "gather_aggregate", "edge_softmax_aggregate", "attention")
 
 
 @runtime_checkable
@@ -68,6 +69,13 @@ class KernelBackend(Protocol):
     def gather_aggregate(self, edge_src, edge_dst, edge_valid, h, *,
                          op: str = "max", block_b: int = 128):
         """Edge-list (gather/scatter) aggregation; supports max/sum."""
+        ...
+
+    def edge_softmax_aggregate(self, blocks, z, s_src, s_dst, *, heads: int,
+                               negative_slope: float = 0.2):
+        """GAT attention of every head on the shard grid: out[i, v, head] =
+        Σ_{j,u} α z[j, u, head], α the softmax over the edges into v of
+        LeakyReLU(s_dst[v] + s_src[u]). z (S, n, H·F), scores (S, n, H)."""
         ...
 
     def attention(self, q, k, v, *, causal: bool = True,
@@ -158,6 +166,23 @@ def _gather_loop(edge_src, edge_dst, edge_valid, h, *, op: str):
     return jnp.stack(outs)
 
 
+def _attention_blocks(blocks, s_src, s_dst, negative_slope: float):
+    """One head's attention weights α laid out on the shard grid.
+
+    s_src/s_dst: (S, n) scores. Returns α as (S, S, n, n) blocks
+    [dst_shard, src_shard, v, u], the softmax over all of v's in-neighbours
+    (axes src_shard and u)."""
+    mask = blocks != 0
+    logits = s_dst[:, None, :, None] + s_src[None, :, None, :]
+    logits = jax.nn.leaky_relu(logits, negative_slope)
+    logits = jnp.where(mask, logits, -jnp.inf)
+    m = jnp.max(logits, axis=(1, 3), keepdims=True)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    e = jnp.where(mask, jnp.exp(logits - m), 0.0)
+    denom = jnp.sum(e, axis=(1, 3), keepdims=True)
+    return jnp.where(denom > 0, e / jnp.maximum(denom, 1e-30), 0.0)
+
+
 # --------------------------------------------------------------------------
 # reference backend: the oracles, verbatim
 # --------------------------------------------------------------------------
@@ -182,6 +207,12 @@ class ReferenceBackend:
                          op="max", block_b=128):
         return _gather_loop(edge_src, edge_dst, edge_valid, h, op=op)
 
+    def edge_softmax_aggregate(self, blocks, z, s_src, s_dst, *, heads,
+                               negative_slope=0.2):
+        return ref.edge_softmax_aggregate(blocks, z, s_src, s_dst,
+                                          heads=heads,
+                                          negative_slope=negative_slope)
+
     def attention(self, q, k, v, *, causal=True, window=None, scale=None,
                   bq=128, bk=128):
         return ref.flash_attention(q, k, v, causal=causal, scale=scale,
@@ -198,7 +229,8 @@ class JaxBackend(ReferenceBackend):
     the one op where reference trades speed for readability — the
     per-shard-pair Python gather loop — is replaced by a vmapped segment
     aggregation that scales to large shard grids on CPU/GPU/TPU without
-    Pallas."""
+    Pallas, and the edge softmax runs on the grid itself, one head at a
+    time, instead of on the flattened (S·n, S·n) adjacency."""
 
     name = "jax"
 
@@ -219,6 +251,16 @@ class JaxBackend(ReferenceBackend):
             return jnp.sum(parts, axis=0).astype(h.dtype)
 
         return jax.vmap(one_dst)(edge_src, edge_dst, edge_valid)
+
+    def edge_softmax_aggregate(self, blocks, z, s_src, s_dst, *, heads,
+                               negative_slope=0.2):
+        s, n, d = z.shape
+        zh = z.reshape(s, n, heads, d // heads)
+        outs = [ref.shard_spmm(
+            _attention_blocks(blocks, s_src[..., h], s_dst[..., h],
+                              negative_slope), zh[..., h, :])
+                for h in range(heads)]
+        return jnp.concatenate(outs, axis=-1)
 
     def attention(self, q, k, v, *, causal=True, window=None, scale=None,
                   bq=128, bk=128):
@@ -305,6 +347,20 @@ class PallasBackend:
 
         return _with_ref_vjp(kernel, ref_fn)(
             edge_src, edge_dst, edge_valid, h)
+
+    def edge_softmax_aggregate(self, blocks, z, s_src, s_dst, *, heads,
+                               negative_slope=0.2):
+        def kernel(blocks, z, s_src, s_dst):
+            return _es.edge_softmax_aggregate(
+                blocks, z, jnp.swapaxes(s_src, 1, 2), s_dst, heads=heads,
+                negative_slope=negative_slope, interpret=_interpret())
+
+        def ref_fn(blocks, z, s_src, s_dst):
+            return ref.edge_softmax_aggregate(
+                blocks, z, s_src, s_dst, heads=heads,
+                negative_slope=negative_slope)
+
+        return _with_ref_vjp(kernel, ref_fn)(blocks, z, s_src, s_dst)
 
     def attention(self, q, k, v, *, causal=True, window=None, scale=None,
                   bq=128, bk=128):

@@ -68,15 +68,20 @@ def _as_graph(graph):
 
 
 def _compile_span(fn):
-    """Run ``fn`` under the ``gnn.compile`` host span, whose argument
-    ``dense_first_layers`` counts the layers of the returned Executable
-    that run the Dense Engine first."""
+    """Run ``fn`` under the ``gnn.compile`` host span, whose arguments
+    count the layers of the returned Executable that run the Dense Engine
+    first (``dense_first_layers``) and that run the edge softmax
+    aggregation (``edge_softmax_layers``, every layer of a GAT)."""
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
         with jax.profiler.TraceAnnotation("gnn.compile") as span:
             exe = fn(*args, **kwargs)
-            span.set_metadata(dense_first_layers=sum(
-                o == "dense-first" for o, _ in exe.producer_orders()))
+            spec = exe.spec
+            span.set_metadata(
+                dense_first_layers=sum(
+                    o == "dense-first" for o, _ in exe.producer_orders()),
+                edge_softmax_layers=(len(spec.layer_dims)
+                                     if spec.arch == "gat" else 0))
             return exe
     return wrapper
 

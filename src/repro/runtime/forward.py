@@ -8,10 +8,10 @@ VMEM) or two-stage through feature memory; the kernel backend is threaded
 explicitly so a compiled Executable is pinned to one backend regardless of
 later env changes.
 
-The GAT attention weights are computed per shard pair as an (S, S, n, n)
-head-block tensor and fed straight to the shard-grid SpMM kernel — the
-aggregation stays on the Graph Engine; only the masked softmax runs on the
-activation unit (plain jnp here).
+A GAT layer extracts z = H W for all heads on the Dense Engine, projects
+the per-node attention scores, and hands both to one Graph Engine op that
+takes every head's softmax over each node's in-edges and its weighted sum
+in one walk of the shard grid (``GraphEngine.edge_softmax_aggregate``).
 """
 from __future__ import annotations
 
@@ -25,6 +25,7 @@ from repro.core.engines import (DenseEngine, GNNeratorController, GraphEngine,
                                 GraphTensors)
 from repro.core.sharding import shard_graph
 from repro.gnn.models import ZooSpec, graph_signature
+from repro.kernels.ref import _activate
 from repro.kernels.registry import KernelBackend
 
 
@@ -38,10 +39,13 @@ def build_graph_tensors(edges: np.ndarray, num_nodes: int, n: int,
 
 
 def layer_activation(spec: ZooSpec, i: int) -> str:
-    """Activation for layer i: relu between layers, logits at the end.
-    Shared with the sharded forward (dist/gnn.py) so the two execution
-    paths can never disagree on where nonlinearities sit."""
-    return "relu" if i < len(spec.layer_dims) - 1 else "none"
+    """Activation for layer i: relu between layers (ELU for GAT, as
+    published), logits at the end. Shared with the sharded forward
+    (dist/gnn.py) so the two execution paths can never disagree on where
+    nonlinearities sit."""
+    if i == len(spec.layer_dims) - 1:
+        return "none"
+    return "elu" if spec.arch == "gat" else "relu"
 
 
 # architectures whose layers are linear up to their activation, so that
@@ -57,51 +61,31 @@ def _controller(plan, backend: KernelBackend | None) -> GNNeratorController:
                                fuse=fused)
 
 
-def _gat_attention_blocks(gt: GraphTensors, z_head: jax.Array,
-                          s_src: jax.Array, s_dst: jax.Array,
-                          negative_slope: float) -> jax.Array:
-    """Per-head attention weights laid out on the shard grid.
-
-    z_head: (S, n, F) head features; s_src/s_dst: (S, n) attention scores.
-    Returns α as (S, S, n, n) blocks [dst_shard, src_shard, v, u] ready for
-    the shard-grid SpMM kernel.
-    """
-    mask = gt.blocks != 0                                   # (S, S, n, n)
-    logits = s_dst[:, None, :, None] + s_src[None, :, None, :]
-    logits = jax.nn.leaky_relu(logits, negative_slope)
-    logits = jnp.where(mask, logits, -jnp.inf)
-    # masked softmax over ALL of v's in-neighbors: axes (src_shard, u)
-    m = jnp.max(logits, axis=(1, 3), keepdims=True)
-    m = jnp.where(jnp.isfinite(m), m, 0.0)
-    e = jnp.where(mask, jnp.exp(logits - m), 0.0)
-    denom = jnp.sum(e, axis=(1, 3), keepdims=True)
-    return jnp.where(denom > 0, e / jnp.maximum(denom, 1e-30), 0.0)
-
-
 def _gat_layer(spec: ZooSpec, layer: dict, gt: GraphTensors, h: jax.Array,
-               ctrl: GNNeratorController, *, activation: str) -> jax.Array:
+               ctrl: GNNeratorController, *, activation: str,
+               last: bool) -> jax.Array:
+    """All heads of one GAT layer: z = H W, the scores a·z in float32, and
+    one edge softmax aggregation of every head; heads are concatenated,
+    or averaged in the output layer."""
     s, n, din = h.shape
     heads, hd = layer["a_src"].shape
     z = ctrl.dense(h.reshape(s * n, din), layer["w"])       # (S·n, H·hd)
-    z = z.reshape(s, n, heads, hd)
-    s_src = jnp.einsum("snhf,hf->snh", z.astype(jnp.float32),
-                       layer["a_src"].astype(jnp.float32))
-    s_dst = jnp.einsum("snhf,hf->snh", z.astype(jnp.float32),
-                       layer["a_dst"].astype(jnp.float32))
-    outs = []
-    for hix in range(heads):   # heads stay sequential: one α grid in VMEM
-        alpha = _gat_attention_blocks(gt, z[..., hix, :],
-                                      s_src[..., hix], s_dst[..., hix],
-                                      spec.negative_slope)
-        outs.append(ctrl.graph.spmm(alpha, z[..., hix, :]))
-    out = jnp.concatenate(outs, axis=-1)                    # (S, n, H·hd)
-    if activation == "relu":
-        out = jax.nn.relu(out)
-    return out
+    zh = z.reshape(s, n, heads, hd).astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    s_src = jnp.einsum("snhf,hf->snh", zh,
+                       layer["a_src"].astype(jnp.float32), precision=hi)
+    s_dst = jnp.einsum("snhf,hf->snh", zh,
+                       layer["a_dst"].astype(jnp.float32), precision=hi)
+    out = ctrl.graph.edge_softmax_aggregate(
+        gt, z.reshape(s, n, heads * hd), s_src, s_dst,
+        negative_slope=spec.negative_slope)                 # (S, n, H·hd)
+    if last:
+        out = out.reshape(s, n, heads, hd).mean(axis=2)
+    return _activate(out, activation)
 
 
 def _layer(spec: ZooSpec, layer: dict, gt: GraphTensors, h: jax.Array,
-           ctrl: GNNeratorController, act: str) -> jax.Array:
+           ctrl: GNNeratorController, act: str, last: bool) -> jax.Array:
     """One layer of ``spec.arch``, each stage under the named scope
     (``aggregate``, ``extract``, ``fused`` or ``attention``) that its
     operations carry in the compiled program's metadata."""
@@ -111,7 +95,8 @@ def _layer(spec: ZooSpec, layer: dict, gt: GraphTensors, h: jax.Array,
                                  concat_self=spec.arch == "sage_mean")
     if spec.arch == "gat":
         with jax.named_scope("attention"):
-            return _gat_layer(spec, layer, gt, h, ctrl, activation=act)
+            return _gat_layer(spec, layer, gt, h, ctrl, activation=act,
+                              last=last)
     s, n, d = h.shape
     if spec.arch == "sage_max":
         with jax.named_scope("extract"):
@@ -161,10 +146,11 @@ def forward(spec: ZooSpec, params: dict, gt: GraphTensors,
     where legal, B=128). ``backend=None`` resolves per call from the
     kernel registry (env-var selectable).
     """
-    for i, layer in enumerate(params["layers"]):
+    layers = params["layers"]
+    for i, layer in enumerate(layers):
         plan = plans[i] if plans is not None else None
         ctrl = _controller(plan, backend)
         act = layer_activation(spec, i)
         with jax.named_scope(f"layer{i}"):
-            h = _layer(spec, layer, gt, h, ctrl, act)
+            h = _layer(spec, layer, gt, h, ctrl, act, i == len(layers) - 1)
     return gt.ungroup(h)

@@ -78,6 +78,17 @@ class TestRegistryParity:
         _check("gather_aggregate", lambda: (es, ed, ev, h), op=op,
                block_b=16)
 
+    @settings(deadline=None, max_examples=6)
+    @given(s=st.sampled_from([1, 2, 3]), n=st.sampled_from([8, 16]),
+           heads=st.sampled_from([1, 4]), f=st.sampled_from([3, 8]))
+    def test_edge_softmax_aggregate(self, s, n, heads, f):
+        blocks = (RNG.random((s, s, n, n)) < 0.2).astype(np.float32)
+        z = RNG.standard_normal((s, n, heads * f)).astype(np.float32)
+        s_src = RNG.standard_normal((s, n, heads)).astype(np.float32)
+        s_dst = RNG.standard_normal((s, n, heads)).astype(np.float32)
+        _check("edge_softmax_aggregate", lambda: (blocks, z, s_src, s_dst),
+               heads=heads, negative_slope=0.2)
+
     @settings(deadline=None, max_examples=4)
     @given(sq=st.sampled_from([32, 64]), heads=st.sampled_from([2, 4]),
            window=st.sampled_from([None, 24]))

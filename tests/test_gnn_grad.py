@@ -39,12 +39,13 @@ def _graph(kind: str) -> np.ndarray:
     raise ValueError(kind)
 
 
-def _grads(arch: str, kind: str, backend: str, params: dict | None):
+def _grads(arch: str, kind: str, backend: str, params: dict | None,
+           **spec_kw):
     rng = np.random.default_rng(3)
     feats = rng.standard_normal((N, F)).astype(np.float32)
     labels = jnp.asarray(rng.integers(0, CLASSES, N).astype(np.int32))
     mask = jnp.asarray(rng.random(N) < 0.7)
-    spec = ZooSpec(arch, F, HID, CLASSES, num_layers=2)
+    spec = ZooSpec(arch, F, HID, CLASSES, num_layers=2, **spec_kw)
     exe = runtime.compile(spec, (_graph(kind), N, feats), backend=backend,
                           params=params, max_shard_n=16)
 
@@ -64,6 +65,23 @@ def test_grad_parity_across_backends(arch, kind):
     assert sum(float(jnp.sum(jnp.abs(l))) for l in leaves_ref) > 0
     for backend in BACKENDS[1:]:
         _, g = _grads(arch, kind, backend, params)
+        for a, b in zip(leaves_ref, jax.tree.leaves(g)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("kind", GRAPH_KINDS)
+def test_gat_grad_parity_through_edge_softmax(kind):
+    """GAT with averaged output heads differentiates through the one edge
+    softmax aggregation op on every backend: the pallas kernel's backward
+    is the oracle's, the jax backend's its per-head grid."""
+    params, g_ref = _grads("gat", kind, "reference", None, heads=2,
+                           out_heads=2)
+    leaves_ref = jax.tree.leaves(g_ref)
+    assert all(bool(jnp.isfinite(l).all()) for l in leaves_ref)
+    assert sum(float(jnp.sum(jnp.abs(l))) for l in leaves_ref) > 0
+    for backend in BACKENDS[1:]:
+        _, g = _grads("gat", kind, backend, params, heads=2, out_heads=2)
         for a, b in zip(leaves_ref, jax.tree.leaves(g)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-3, atol=2e-3)

@@ -28,9 +28,12 @@ def _flat_adj(gt) -> np.ndarray:
 
 
 def _ref_forward(arch, layers, a, h):
+    """The published layers: relu between layers, except GAT's ELU; GAT's
+    hidden heads concatenated, its output heads averaged."""
     n_layers = len(layers)
     for i, L in enumerate(layers):
-        act = "relu" if i < n_layers - 1 else "none"
+        last = i == n_layers - 1
+        act = "none" if last else ("elu" if arch == "gat" else "relu")
         if arch == "gcn":
             h = ref.gcn_layer(a, h, L["w"], activation=act)
         elif arch == "sage_mean":
@@ -43,7 +46,7 @@ def _ref_forward(arch, layers, a, h):
                               L["b2"], activation=act)
         elif arch == "gat":
             h = ref.gat_layer(a, h, L["w"], L["a_src"], L["a_dst"],
-                              activation=act)
+                              activation=act, concat_heads=not last)
     return h
 
 
@@ -88,6 +91,32 @@ class TestZooVsReference:
         h = np.zeros((a.shape[0], prof.feature_dim), np.float32)
         h[:prof.num_nodes] = ds.features
         exp = np.asarray(_ref_forward("gcn", params["layers"], a,
+                                      jnp.asarray(h)))[:prof.num_nodes]
+        np.testing.assert_allclose(np.asarray(out), exp, atol=5e-5, rtol=5e-5)
+
+    @pytest.mark.parametrize("out_heads", [1, 8])
+    def test_gat_output_heads_are_averaged(self, out_heads):
+        """GAT as published for PubMed: 8 hidden heads of 8, ELU, and
+        ``out_heads`` output heads averaged; multi-shard grid. Both sides
+        compute in float32 on the CPU, so only summation order differs."""
+        ds = make_dataset("citeseer", seed=4, scale=0.08)
+        prof = ds.profile
+        spec = ZooSpec("gat", prof.feature_dim, 64, prof.num_classes,
+                       num_layers=2, heads=8, out_heads=out_heads)
+        mp = plan_model(spec, prof.num_nodes, ds.edges.shape[0], max_n=64)
+        assert mp.layers[0].S > 1
+        assert mp.layers[1].d_agg == out_heads * prof.num_classes
+        gt = build_zoo_graph(ds.edges, prof.num_nodes, mp.shard_n, "gat")
+        params = init_zoo(jax.random.key(5), spec)
+        last = params["layers"][1]
+        assert last["w"].shape == (64, out_heads * prof.num_classes)
+        assert last["a_src"].shape == (out_heads, prof.num_classes)
+        out = zoo_forward(spec, params, gt, gt.group(jnp.asarray(ds.features)),
+                          plans=mp.layers)
+        a = _flat_adj(gt)
+        h = np.zeros((a.shape[0], prof.feature_dim), np.float32)
+        h[:prof.num_nodes] = ds.features
+        exp = np.asarray(_ref_forward("gat", params["layers"], a,
                                       jnp.asarray(h)))[:prof.num_nodes]
         np.testing.assert_allclose(np.asarray(out), exp, atol=5e-5, rtol=5e-5)
 

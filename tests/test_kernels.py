@@ -7,6 +7,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.dense_engine import dense_engine_matmul
+from repro.kernels.edge_softmax import block_rows, edge_softmax_aggregate
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_gnn import fused_gnn_layer
 from repro.kernels.seg_gather import seg_gather_aggregate
@@ -76,6 +77,59 @@ def test_seg_gather(op, s, n, e, d, bb):
     finally:
         os.environ.pop("REPRO_KERNEL_BACKEND")
     np.testing.assert_allclose(out, exp, atol=1e-4, rtol=1e-4)
+
+
+def _attention_graph(s: int, n: int, heads: int, f: int):
+    """A shard grid with the rows the edge softmax must get right, and
+    scores of ±50: rows of the last shard's padding with no edge, a row
+    (shard 0, node 1) whose neighbours all lie in the last source shard, a
+    row (shard 0, node 2) with only its self loop, and a row (shard 0,
+    node 3) whose first neighbours score -50 and whose last scores +50,
+    so that its running max rises after it has summed."""
+    r = np.random.default_rng(s * 100 + heads * 10 + f)
+    blocks = (r.random((s, s, n, n)) < 0.2).astype(np.float32)
+    blocks[-1, :, n - 3:, :] = 0.0
+    blocks[0, :, 1:4, :] = 0.0
+    blocks[0, -1, 1, 5:9] = 1.0
+    blocks[0, 0, 2, 2] = 1.0
+    blocks[0, 0, 3, 6:8] = 1.0
+    blocks[0, -1, 3, n - 2] = 1.0
+    z = r.standard_normal((s, n, heads * f)).astype(np.float32)
+    s_src = 50.0 * r.choice([-1.0, 1.0], (s, n, heads)).astype(np.float32)
+    s_dst = 50.0 * r.choice([-1.0, 1.0], (s, n, heads)).astype(np.float32)
+    s_src[0, 6:8], s_src[-1, n - 2] = -50.0, 50.0
+    return blocks, z, s_src, s_dst
+
+
+@pytest.mark.parametrize("n,rows", [(16, 16), (1000, 200), (1024, 512),
+                                    (2048, 256)])
+def test_edge_softmax_row_tile(n, rows):
+    """Destination rows per step: a (rows, n) tile of at most 2 MiB of
+    float32, a multiple of 8 dividing n, or n itself."""
+    assert block_rows(n) == rows
+
+
+@pytest.mark.parametrize("s", [1, 3])
+@pytest.mark.parametrize("f", [3, 8])
+@pytest.mark.parametrize("heads", [1, 8])
+def test_edge_softmax_aggregate(heads, f, s):
+    n = 16
+    blocks, z, s_src, s_dst = _attention_graph(s, n, heads, f)
+    # 8 rows at a time: two row tiles per destination shard
+    out = np.asarray(edge_softmax_aggregate(
+        blocks, z, np.swapaxes(s_src, 1, 2), s_dst, heads=heads,
+        negative_slope=0.2, rows=8, interpret=True))
+    exp = np.asarray(ref.edge_softmax_aggregate(
+        blocks, z, s_src, s_dst, heads=heads, negative_slope=0.2))
+    assert np.isfinite(out).all()
+    # padding rows with no edge: exactly 0, never NaN
+    assert (out[-1, n - 3:] == 0).all()
+    # a self loop alone weighs 1
+    np.testing.assert_allclose(out[0, 2], z[0, 2], rtol=1e-6, atol=1e-6)
+    # float32 on both sides (interpret mode); the kernel sums shard by
+    # shard and rescales by exp(m - m'), the oracle in one pass, so only
+    # rounding differs: exp of scores near ±60 is exact to about 1e-5
+    np.testing.assert_allclose(out, exp, rtol=5e-5, atol=5e-5)
 
 
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])

@@ -27,7 +27,8 @@ from repro.analyze import plan_lint
 from repro.gnn.executor import plan_model
 from repro.gnn.models import ZooSpec
 from repro.graphs.datasets import make_dataset
-from repro.kernels import dense_engine, fused_gnn, seg_gather, shard_spmm
+from repro.kernels import (dense_engine, edge_softmax, fused_gnn, seg_gather,
+                           shard_spmm)
 from repro.kernels import registry
 from repro.kernels.registry import _feature_block
 from repro.runtime.fit import TrainableExecutable
@@ -195,6 +196,56 @@ def test_seg_gather_aggregate_compiles(graphs, one_chip, name, layer):
                                   sharding=one_chip))
 
 
+@pytest.mark.parametrize("heads,f", [(8, 8), (8, 3)])
+def test_edge_softmax_aggregate_compiles(graphs, one_chip, heads, f):
+    """GAT's attention at full pubmed shapes: layer 0's 8 heads of 8 and
+    the output layer's 8 heads of 3, over the 20 x 20 grid of 1024."""
+    s, n = graphs["pubmed"][2].layers[0].S, graphs["pubmed"][2].shard_n
+    assert (s, n) == (20, 1024)
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                            sharding=one_chip)
+    _compile(functools.partial(edge_softmax.edge_softmax_aggregate,
+                               heads=heads, negative_slope=0.2,
+                               interpret=False),
+             f32((s, s, n, n)), f32((s, n, heads * f)), f32((s, heads, n)),
+             f32((s, n, heads)))
+
+
+def test_gat_forward_holds_no_grid_but_the_adjacency(one_chip, monkeypatch):
+    """GAT at its PubMed widths (500 -> 8 x 8 -> 8 x 3 averaged) through
+    runtime.compile on the pallas backend: the forward compiled for v5e
+    runs only the edge softmax and dense kernels, and no (S, S, n, n)
+    array other than the adjacency it is given."""
+    monkeypatch.setattr(registry, "_interpret", lambda: False)
+    r = np.random.default_rng(0)
+    # n = 1024, as on full pubmed: a grid of a few MB would be prefetched
+    # into VMEM whole, which copies the adjacency but computes nothing
+    num, d = 2100, 500
+    edges = r.integers(0, num, (8400, 2))
+    feats = r.standard_normal((num, d)).astype(np.float32)
+    spec = ZooSpec("gat", d, 64, 3, heads=8, out_heads=8)
+    exe = runtime.compile(spec, (edges, num, feats), backend="pallas",
+                          max_shard_n=1024, store=runtime.GraphStore())
+    s, n = exe.gt.S, exe.gt.n
+    assert (s, n) == (3, 1024)
+    avals = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), x.dtype,
+                                       sharding=one_chip),
+        (exe.params, exe._h_grouped, exe._graph_args()))
+    p, h, ga = avals
+    compiled = exe._jit_forward.lower(p, h, *ga).compile()
+    names = _custom_call_names(compiled)
+    assert set(names) == {edge_softmax.KERNEL_NAME,
+                          dense_engine.KERNEL_NAME}, names
+    assert names.count(edge_softmax.KERNEL_NAME) == 2
+    # each instruction's result type: the text between "=" and its opcode
+    inst = re.compile(r"\s*%([\w.\-]+) = (.*?) [a-z][\w\-]*\(")
+    held = [m.group(1) for m in map(inst.match,
+                                    compiled.as_text().splitlines())
+            if m and f"[{s},{s},{n},{n}]" in m.group(2)]
+    assert len(held) == 1 and held[0].startswith("blocks"), held
+
+
 def test_fused_over_vmem_is_refused_and_flagged(graphs, one_chip):
     """n=2048, B=128 overflows the fused kernel's VMEM: the compiler
     refuses it, and the plan pass (PL003) flags the same plan."""
@@ -243,7 +294,7 @@ def _train_step_compiled(graphs, one_chip, monkeypatch):
 
 @pytest.mark.parametrize("kernel", ["shard_spmm", "fused_gnn",
                                     "dense_engine", "seg_gather",
-                                    "train_step"])
+                                    "edge_softmax", "train_step"])
 def test_pallas_calls_carry_kernel_names(graphs, one_chip, monkeypatch,
                                          kernel):
     """Each Pallas call's instruction is named after its kernel's
@@ -279,6 +330,13 @@ def test_pallas_calls_carry_kernel_names(graphs, one_chip, monkeypatch,
             jax.ShapeDtypeStruct((s, s, e), jnp.bool_, sharding=one_chip),
             f32((s, n, dp)))
         want = {seg_gather.KERNEL_NAME}
+    elif kernel == "edge_softmax":
+        compiled = _compile(functools.partial(
+            edge_softmax.edge_softmax_aggregate, heads=8,
+            negative_slope=0.2, interpret=False),
+            f32((s, s, n, n)), f32((s, n, 64)), f32((s, 8, n)),
+            f32((s, n, 8)))
+        want = {edge_softmax.KERNEL_NAME}
     else:
         compiled = _train_step_compiled(graphs, one_chip, monkeypatch)
         want = {fused_gnn.KERNEL_NAME, shard_spmm.KERNEL_NAME,

@@ -70,8 +70,12 @@ def spans(tmp_path_factory):
     eng.register_model("gcn", spec)
     srv = Server(eng, SchedulerConfig(max_batch_size=4))
     ids = np.arange(ds.profile.num_nodes)
+    gat = ZooSpec("gat", ds.profile.feature_dim, 16, ds.profile.num_classes,
+                  heads=8, out_heads=2)
     logdir = str(tmp_path_factory.mktemp("trace"))
     with jax.profiler.trace(logdir):
+        runtime.compile(gat, ds, backend="reference", max_shard_n=64,
+                        store=runtime.GraphStore())
         for _ in range(2):
             exe = runtime.compile(spec, ds, backend="reference",
                                   max_shard_n=64, store=runtime.GraphStore())
@@ -118,8 +122,30 @@ def test_compile_counts_its_dense_first_layers(spans):
     once extracted to 8: every compile of the gcn runs layer 0 dense-first
     and layer 1 graph-first."""
     counts = [st.get("dense_first_layers") for n, _, _, st in spans
-              if n == "gnn.compile"]
+              if n == "gnn.compile" and not st.get("edge_softmax_layers")]
     assert len(counts) >= 2 and all(c == 1 for c in counts), counts
+
+
+def test_compile_counts_its_edge_softmax_layers(spans):
+    """Both layers of the one GAT compiled run the edge softmax
+    aggregation, and no layer of any gcn does."""
+    counts = [st.get("edge_softmax_layers") for n, _, _, st in spans
+              if n == "gnn.compile"]
+    assert counts.count(2) == 1 and set(counts) == {0, 2}, counts
+
+
+def test_gat_forward_carries_its_kernels_and_attention_scopes():
+    ds = make_dataset("cora", seed=0, scale=0.05)
+    spec = ZooSpec("gat", ds.profile.feature_dim, 16,
+                   ds.profile.num_classes, heads=8, out_heads=2)
+    exe = runtime.compile(spec, ds, backend="pallas", max_shard_n=64,
+                          store=runtime.GraphStore())
+    text = exe._jit_forward.lower(exe.params, exe._h_grouped,
+                                  *exe._graph_args()).as_text(
+                                      debug_info=True)
+    for name in ("gnn_edge_softmax_aggregate", "gnn_dense_engine",
+                 "layer0/attention", "layer1/attention"):
+        assert name in text, name
 
 
 def test_jitted_programs_carry_names_and_scopes():
