@@ -87,11 +87,13 @@ def kernel_count(exe) -> int:
 
 
 def oracle_forward(spec, graph, params):
-    """The same model through the reference backend at full f32."""
+    """The same model through the reference backend at full f32, its grid
+    uploaded in float32 too (compiled under the same precision)."""
     import jax
     from repro import runtime
-    ref = runtime.compile(spec, graph, backend="reference", params=params)
     with jax.default_matmul_precision("highest"):
+        ref = runtime.compile(spec, graph, backend="reference",
+                              params=params)
         return jax.block_until_ready(ref.forward())
 
 

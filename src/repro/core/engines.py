@@ -15,14 +15,41 @@ fewer times (``GNNeratorController.linear_layer``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Literal
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.sharding import ShardedGraph
+from repro.kernels import registry
 from repro.kernels.ref import _activate
 from repro.kernels.registry import KernelBackend, grid_walks, resolve
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _cast_row_into(grid: jax.Array, row: jax.Array, i) -> jax.Array:
+    """``grid[i] = row`` in the grid's dtype, in place."""
+    return jax.lax.dynamic_update_index_in_dim(
+        grid, row.astype(grid.dtype), i, 0)
+
+
+def upload_grid(blocks: np.ndarray) -> jax.Array:
+    """The host's float32 shard grid on the device, in the dtype that
+    ``registry.device_grid_dtype()`` gives where it is uploaded.
+
+    Another dtype is cast on the device, one destination row of shard
+    pairs at a time into a grid allocated once: the host makes no pass
+    over the grid (a host cast of full PubMed's took about 1 s on a v5e
+    host), and the device holds no float32 copy of more than one row."""
+    dtype = registry.device_grid_dtype()
+    if blocks.dtype == dtype:
+        return jnp.asarray(blocks)
+    grid = jnp.zeros(blocks.shape, dtype)
+    for i in range(blocks.shape[0]):
+        grid = jax.block_until_ready(_cast_row_into(grid, blocks[i], i))
+    return grid
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,7 +67,7 @@ class GraphTensors:
     @classmethod
     def from_sharded(cls, sg: ShardedGraph) -> "GraphTensors":
         return cls(
-            blocks=jnp.asarray(sg.blocks),
+            blocks=upload_grid(sg.blocks),
             edge_src=jnp.asarray(sg.edge_src),
             edge_dst=jnp.asarray(sg.edge_dst),
             edge_valid=jnp.asarray(sg.edge_valid),
@@ -53,7 +80,6 @@ class GraphTensors:
     def occupancy(self):
         """(S, S) edge count per shard (numpy) — lets graphs/partition.py
         plan over cached GraphTensors exactly like a ShardedGraph."""
-        import numpy as np
         return np.asarray(self.edge_valid.sum(axis=-1))
 
     def group(self, h: jax.Array) -> jax.Array:

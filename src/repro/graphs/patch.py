@@ -285,7 +285,7 @@ class PatchState:
         path; otherwise full host->device copies."""
         import jax.numpy as jnp
 
-        from repro.core.engines import GraphTensors
+        from repro.core.engines import GraphTensors, upload_grid
 
         if prev is not None and pairs is not None and \
                 tuple(jnp.shape(prev.edge_src)) == self.edge_src.shape \
@@ -295,16 +295,20 @@ class PatchState:
                 arrs = (prev.blocks, prev.edge_src, prev.edge_dst,
                         prev.edge_valid)
             else:
+                # each update in its device array's dtype: a bfloat16
+                # grid stays bfloat16, cast from the float32 host copy
+                # as a fresh upload would cast it
                 arrs = tuple(
-                    old.at[ai, aj].set(jnp.asarray(new[ai, aj]))
+                    old.at[ai, aj].set(jnp.asarray(
+                        new[ai, aj].astype(old.dtype, copy=False)))
                     for old, new in ((prev.blocks, self.blocks),
                                      (prev.edge_src, self.edge_src),
                                      (prev.edge_dst, self.edge_dst),
                                      (prev.edge_valid, self.edge_valid)))
         else:
-            arrs = tuple(jnp.asarray(a) for a in
-                         (self.blocks, self.edge_src, self.edge_dst,
-                          self.edge_valid))
+            arrs = (upload_grid(self.blocks),) + tuple(
+                jnp.asarray(a) for a in
+                (self.edge_src, self.edge_dst, self.edge_valid))
         return GraphTensors(blocks=arrs[0], edge_src=arrs[1],
                             edge_dst=arrs[2], edge_valid=arrs[3],
                             num_nodes=self.num_nodes, n=self.n, S=self.S)

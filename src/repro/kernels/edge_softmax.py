@@ -67,7 +67,9 @@ def _kernel(a_ref, z_ref, ss_ref, sd_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    edge = a_ref[...] != 0                          # (R, n), all heads
+    # the mask in float32 layout, once for all heads: a bfloat16 tile
+    # compared as it is cost 3% more a layer on v5e (PERF.md §6)
+    edge = a_ref[...].astype(jnp.float32) != 0      # (R, n), all heads
     z = z_ref[...].astype(jnp.float32)              # (n, H·F)
     s_src = ss_ref[...].astype(jnp.float32)         # (H, n)
     s_dst = sd_ref[...].astype(jnp.float32)         # (R, H)

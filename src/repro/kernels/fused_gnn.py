@@ -41,9 +41,11 @@ def _kernel(a_ref, h_ref, w_ref, o_ref, agg_ref, acc_ref, *, nd: int, ns: int,
     def _init_agg():
         agg_ref[...] = jnp.zeros_like(agg_ref)
 
-    # Graph Engine step: aggregate source shard j into the resident block.
+    # Graph Engine step: aggregate source shard j into the resident block
+    # (a bfloat16 grid meets h in bfloat16, as in shard_spmm).
+    a = a_ref[...]
     agg_ref[...] += jnp.dot(
-        a_ref[...], h_ref[...], preferred_element_type=jnp.float32
+        a, h_ref[...].astype(a.dtype), preferred_element_type=jnp.float32
     )
 
     last_j = j == ns - 1

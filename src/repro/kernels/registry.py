@@ -116,6 +116,31 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# matmul precisions that give each f32 operand one bfloat16 MXU pass
+_ONE_BF16_PASS = (None, "default", "bfloat16")
+
+
+def grid_dtype(platform: str, precision: str | None) -> jnp.dtype:
+    """The dtype the shard grid lives in on the device, for the platform
+    the kernels run on and the ``jax_default_matmul_precision`` in effect.
+
+    On a TPU at one bfloat16 pass every consumer of the grid rounds it to
+    bfloat16 anyway (the kernels' and XLA's dots), or only tests it
+    ``!= 0`` (the edge softmax), so storing it in bfloat16 changes no
+    operand and halves the bytes each walk reads. Elsewhere, or at a
+    higher precision, the dots see the float32 value: keep float32."""
+    if platform == "tpu" and precision in _ONE_BF16_PASS:
+        return jnp.dtype(jnp.bfloat16)
+    return jnp.dtype(jnp.float32)
+
+
+def device_grid_dtype() -> jnp.dtype:
+    """:func:`grid_dtype` for this process's backend and the matmul
+    precision in effect where it is called (the grid's upload)."""
+    return grid_dtype(jax.default_backend(),
+                      jax.config.jax_default_matmul_precision)
+
+
 def _feature_block(d: int, block_b: int) -> tuple[int, int]:
     """(kernel block, padded dim) for a requested block over a lane dim.
 

@@ -38,8 +38,13 @@ def _kernel(a_ref, h_ref, o_ref, acc_ref, *, ns: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # a bfloat16 grid (a TPU at the default matmul precision) meets each
+    # feature tile rounded to bfloat16 here, as the MXU's one pass rounds
+    # a float32 operand; rounding h outside the kernel instead made XLA
+    # move the next layer's input out of fast memory (PERF.md §6)
+    a = a_ref[...]
     acc_ref[...] += jnp.dot(
-        a_ref[...], h_ref[...], preferred_element_type=jnp.float32
+        a, h_ref[...].astype(a.dtype), preferred_element_type=jnp.float32
     )
 
     @pl.when(j == ns - 1)

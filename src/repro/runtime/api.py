@@ -71,7 +71,9 @@ def _compile_span(fn):
     """Run ``fn`` under the ``gnn.compile`` host span, whose arguments
     count the layers of the returned Executable that run the Dense Engine
     first (``dense_first_layers``) and that run the edge softmax
-    aggregation (``edge_softmax_layers``, every layer of a GAT)."""
+    aggregation (``edge_softmax_layers``, every layer of a GAT), and name
+    the device shard grid's dtype (``grid_dtype``, bfloat16 on a TPU at
+    the default matmul precision) and bytes (``grid_bytes``)."""
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
         with jax.profiler.TraceAnnotation("gnn.compile") as span:
@@ -81,7 +83,9 @@ def _compile_span(fn):
                 dense_first_layers=sum(
                     o == "dense-first" for o, _ in exe.producer_orders()),
                 edge_softmax_layers=(len(spec.layer_dims)
-                                     if spec.arch == "gat" else 0))
+                                     if spec.arch == "gat" else 0),
+                grid_dtype=str(exe.gt.blocks.dtype),
+                grid_bytes=int(exe.gt.blocks.nbytes))
             return exe
     return wrapper
 

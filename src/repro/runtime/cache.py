@@ -31,6 +31,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.core.engines import GraphTensors
 from repro.gnn.models import graph_signature
+from repro.kernels import registry
 
 
 @dataclasses.dataclass
@@ -94,8 +95,12 @@ class GraphStore:
         # build (and the loser must not clobber the winner's entry)
         with self._lock:
             entry = self._entries.get(key)
+            # a build whose grid is stored in another dtype than the one
+            # in effect here (asked for under another matmul precision)
+            # is built again: a bfloat16 grid must not reach a float32 dot
             if entry is not None and \
-                    not (mutable and entry.patch_state is None):
+                    not (mutable and entry.patch_state is None) and \
+                    entry.gt.blocks.dtype == registry.device_grid_dtype():
                 self.stats["hits"] += 1
                 self._entries.move_to_end(key)
                 if entry.h_grouped is None and features is not None:

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
-from repro.core.engines import GraphTensors
+from repro.core.engines import GraphTensors, upload_grid
 from repro.core.sharding import shard_graph
 from repro.gnn.models import ZooSpec, graph_signature
 from repro.graphs.sampler import NeighborSampler, SubgraphBatch
@@ -207,7 +207,7 @@ class TrainableExecutable:
             h = _pad_axis(feats, s_sub * n_sub, 0).reshape(s_sub, n_sub, -1)
             labels = self._labels[batch.nodes]
             mask = batch.seed_mask & self._train_mask[batch.nodes]
-            return (jnp.asarray(sg.blocks),
+            return (upload_grid(sg.blocks),
                     jnp.asarray(_pad_axis(sg.edge_src, e_cap, 2)),
                     jnp.asarray(_pad_axis(sg.edge_dst, e_cap, 2)),
                     jnp.asarray(_pad_axis(sg.edge_valid, e_cap, 2)),

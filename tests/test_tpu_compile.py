@@ -24,8 +24,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import runtime
 from repro.analyze import plan_lint
+from repro.core.engines import GraphTensors
+from repro.core.sharding import shard_graph
 from repro.gnn.executor import plan_model
-from repro.gnn.models import ZooSpec
+from repro.gnn.models import ZooSpec, graph_signature, init_zoo
 from repro.graphs.datasets import make_dataset
 from repro.kernels import (dense_engine, edge_softmax, fused_gnn, seg_gather,
                            shard_spmm)
@@ -343,3 +345,111 @@ def test_pallas_calls_carry_kernel_names(graphs, one_chip, monkeypatch,
                 dense_engine.KERNEL_NAME}
     names = _custom_call_names(compiled)
     assert names and set(names) == want, names
+
+
+@pytest.mark.parametrize("kernel", ["shard_spmm", "fused_gnn",
+                                    "edge_softmax"])
+def test_kernels_compile_on_a_bfloat16_grid(graphs, one_chip, kernel):
+    """The grid a TPU stores at the default matmul precision, at full
+    pubmed shapes: each kernel takes its bfloat16 tiles (the edge
+    softmax compares them with 0 on v5e, whose VPU has no bfloat16
+    arithmetic)."""
+    s, n, dp, bb, f = _layer_shapes(graphs, "pubmed", 0)
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                            sharding=one_chip)
+    grid = jax.ShapeDtypeStruct((s, s, n, n), jnp.bfloat16,
+                                sharding=one_chip)
+    if kernel == "shard_spmm":
+        _compile(functools.partial(shard_spmm.shard_spmm, block_b=bb,
+                                   interpret=False), grid, f32((s, n, dp)))
+    elif kernel == "fused_gnn":
+        _compile(functools.partial(fused_gnn.fused_gnn_layer, block_b=bb,
+                                   activation="relu", interpret=False),
+                 grid, f32((s, n, dp)), f32((dp, f)))
+    else:
+        _compile(functools.partial(edge_softmax.edge_softmax_aggregate,
+                                   heads=8, negative_slope=0.2,
+                                   interpret=False),
+                 grid, f32((s, n, 64)), f32((s, 8, n)), f32((s, n, 8)))
+
+
+def _entry_grid_instructions(text: str, s: int, n: int) -> list[tuple]:
+    """(name, opcode) of each instruction of the ENTRY computation whose
+    result holds an (s, s, n, n) array: what the program materializes."""
+    lines = text.splitlines()
+    start = next(i for i, ln in enumerate(lines) if ln.startswith("ENTRY"))
+    inst = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(")
+    return [(m.group(1), m.group(3)) for m in map(inst.match, lines[start:])
+            if m and f"[{s},{s},{n},{n}]" in m.group(2)]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_gcn_gradient_reads_the_grid_in_its_stored_dtype(graphs, one_chip,
+                                                         monkeypatch, dtype):
+    """The GCN gradient (value_and_grad through the pallas backend's
+    oracle VJPs) at full pubmed shapes: five instructions read the grid
+    (the two Pallas kernels of the forward and three fusions of the XLA
+    backward), all in the dtype it is stored in, and the program holds
+    no copy or conversion of it."""
+    monkeypatch.setattr(registry, "_interpret", lambda: False)
+    ds, spec, plan = graphs["pubmed"]
+    s, n = plan.layers[0].S, plan.shard_n
+    e = 64
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: init_zoo(jax.random.key(0), spec))
+    p = jax.tree.map(lambda x: sds(x.shape, x.dtype), params)
+    be = registry.get_backend("pallas")
+
+    def loss(p, h, blocks, e_src, e_dst, e_valid):
+        gt = GraphTensors(blocks=blocks, edge_src=e_src, edge_dst=e_dst,
+                          edge_valid=e_valid, num_nodes=s * n, n=n, S=s)
+        out = runtime.forward(spec, p, gt, h, plans=plan.layers, backend=be)
+        return jnp.sum(out * out)
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(
+        p, sds((s, n, spec.layer_dims[0][0]), jnp.float32),
+        sds((s, s, n, n), jnp.dtype(dtype)), sds((s, s, e), jnp.int32),
+        sds((s, s, e), jnp.int32), sds((s, s, e), jnp.bool_)).compile()
+    text = compiled.as_text()
+    held = _entry_grid_instructions(text, s, n)
+    assert [op for _, op in held] == ["parameter"], held
+    grid = held[0][0]
+    short = {"float32": "f32", "bfloat16": "bf16"}[dtype]
+    assert f"{short}[{s},{s},{n},{n}]" in text
+    readers = [ln for ln in text.splitlines()
+               if re.search(rf"\((%{re.escape(grid)}|[^)]*, %"
+                            rf"{re.escape(grid)})[,)]", ln)]
+    kinds = sorted("tpu_custom_call" if "tpu_custom_call" in ln else "fusion"
+                   for ln in readers)
+    assert kinds == ["fusion"] * 3 + ["tpu_custom_call"] * 2, readers
+
+
+@pytest.mark.parametrize("arch", ["gcn", "sage_mean", "gat"])
+def test_a_bfloat16_grid_gives_the_float32_grids_logits_on_the_chip(arch):
+    """On a TPU at the default matmul precision (skipped elsewhere): the
+    compiled forward on the bfloat16 grid it uploads and on the float32
+    host grid agree to float32 accumulation order, since every consumer
+    rounded the float32 grid to bfloat16 already."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs a TPU")
+    r = np.random.default_rng(0)
+    num, d = 600, 64
+    edges = r.integers(0, num, (2400, 2))
+    feats = r.standard_normal((num, d)).astype(np.float32)
+    kw = dict(heads=4, out_heads=2) if arch == "gat" else {}
+    spec = ZooSpec(arch, d, 32, 3, **kw)
+    exe = runtime.compile(spec, (edges, num, feats), backend="pallas",
+                          max_shard_n=128, store=runtime.GraphStore())
+    blocks, *rest = exe._graph_args()
+    assert blocks.dtype == jnp.bfloat16
+    norm, loops = graph_signature(arch)
+    host = shard_graph(edges, num, exe.gt.n, normalize=norm,
+                       add_self_loops=loops).blocks
+    out = np.asarray(exe._jit_forward(exe.params, exe._h_grouped, blocks,
+                                      *rest))
+    ref = np.asarray(exe._jit_forward(exe.params, exe._h_grouped,
+                                      jnp.asarray(host), *rest))
+    scale = np.abs(ref).max()
+    assert scale > 0
+    assert np.abs(out - ref).max() <= 1e-5 * scale

@@ -4,6 +4,7 @@ parent, and is opened again by a second call (none runs only while jit
 traces); the jitted programs carry their names and their layers' scopes.
 """
 import glob
+import math
 import os
 
 import numpy as np
@@ -132,6 +133,19 @@ def test_compile_counts_its_edge_softmax_layers(spans):
     counts = [st.get("edge_softmax_layers") for n, _, _, st in spans
               if n == "gnn.compile"]
     assert counts.count(2) == 1 and set(counts) == {0, 2}, counts
+
+
+def test_compile_names_its_grid_dtype_and_bytes(spans):
+    """Every compile here, on the CPU, keeps the (S, S, n, n) grid in
+    float32: S·n is a multiple of the shard size 64, and the bytes are
+    4 a cell."""
+    grids = [(st.get("grid_dtype"), st.get("grid_bytes"))
+             for n, _, _, st in spans if n == "gnn.compile"]
+    assert len(grids) >= 3, grids
+    for dtype, nbytes in grids:
+        side = math.isqrt(nbytes // 4)
+        assert dtype == "float32" and side * side * 4 == nbytes, grids
+        assert side % 64 == 0, grids
 
 
 def test_gat_forward_carries_its_kernels_and_attention_scopes():
