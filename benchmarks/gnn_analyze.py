@@ -34,7 +34,7 @@ def bench_analyze(budget: int = BUDGET, reps: int = REPS) -> dict:
     from repro import runtime, tune
     from repro.gnn.models import ZooSpec
     from repro.graphs.datasets import make_dataset
-    from repro.kernels.registry import resolve
+    from repro.kernels.registry import get_backend
     from repro.launch.analyze import build_report
 
     # -- the CI gate's cost on this checkout -------------------------------
@@ -56,7 +56,7 @@ def bench_analyze(budget: int = BUDGET, reps: int = REPS) -> dict:
                    num_layers=2)
     t0 = time.perf_counter()
     rec = tune.autotune_plan(spec, ds.edges, ds.profile.num_nodes,
-                             backend=resolve(None, "reference"),
+                             backend=get_backend("reference"),
                              features=ds.features, max_n=MAX_SHARD_N,
                              budget=budget, reps=reps)
     tune_s = time.perf_counter() - t0

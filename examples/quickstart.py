@@ -39,7 +39,7 @@ def main() -> int:
                     help="0 = full-batch; >0 neighbor-samples this many "
                          "seed nodes per step")
     ap.add_argument("--backend", default=None,
-                    choices=["pallas", "jax", "reference", "ref"],
+                    choices=["pallas", "jax", "reference"],
                     help="kernel backend (default: REPRO_KERNEL_BACKEND "
                          "env, else pallas — interpret mode on CPU)")
     args = ap.parse_args()

@@ -30,7 +30,7 @@ def main() -> int:
     ap.add_argument("--scale", type=float, default=0.25,
                     help="graph scale factor (1.0 = full Table-II profile)")
     ap.add_argument("--backend", default=None,
-                    choices=["pallas", "jax", "reference", "ref"],
+                    choices=["pallas", "jax", "reference"],
                     help="kernel backend (default: REPRO_KERNEL_BACKEND "
                          "env var, else reference — fast pure-jnp on CPU)")
     ap.add_argument("--requests", "--num-requests", dest="requests",

@@ -25,7 +25,7 @@ import numpy as np
 from repro.core.sharding import ShardedGraph
 from repro.kernels import registry
 from repro.kernels.ref import _activate
-from repro.kernels.registry import KernelBackend, grid_walks, resolve
+from repro.kernels.registry import KernelBackend, grid_walks
 
 
 @functools.partial(jax.jit, donate_argnums=0)
@@ -99,8 +99,9 @@ class GraphTensors:
 class DenseEngine:
     """Feature extraction: blocked systolic matmul + activation unit.
 
-    ``backend`` pins a :class:`~repro.kernels.registry.KernelBackend`;
-    None resolves per call from the registry (env-var selectable)."""
+    ``backend`` is the :class:`~repro.kernels.registry.KernelBackend` it
+    calls (``runtime.forward.forward`` resolves it once per model); an
+    engine left at None only plans and runs no kernel."""
 
     bm: int = 128
     bn: int = 128
@@ -108,14 +109,14 @@ class DenseEngine:
     backend: KernelBackend | None = None
 
     def __call__(self, x, w, b=None, *, activation: str = "none"):
-        be = self.backend or resolve("dense_matmul")
-        return be.dense_matmul(x, w, b, activation=activation,
-                               bm=self.bm, bn=self.bn, bk=self.bk)
+        return self.backend.dense_matmul(x, w, b, activation=activation,
+                                         bm=self.bm, bn=self.bn, bk=self.bk)
 
 
 @dataclasses.dataclass(frozen=True)
 class GraphEngine:
-    """Aggregation over the shard grid with dimension-blocking."""
+    """Aggregation over the shard grid with dimension-blocking, on the
+    ``backend`` it holds (as :class:`DenseEngine`)."""
 
     block_b: int = 128   # the paper's B (feature block size)
     backend: KernelBackend | None = None
@@ -125,11 +126,11 @@ class GraphEngine:
         """h: (S, n, D) shard-grouped. Linear = weights baked into blocks
         (sum/mean/gcn); max/sum go through the edge-list gather kernel."""
         if op == "linear":
-            be = self.backend or resolve("graph_aggregate")
-            return be.graph_aggregate(gt.blocks, h, block_b=self.block_b)
-        be = self.backend or resolve("gather_aggregate")
-        return be.gather_aggregate(gt.edge_src, gt.edge_dst, gt.edge_valid,
-                                   h, op=op, block_b=self.block_b)
+            return self.backend.graph_aggregate(gt.blocks, h,
+                                                block_b=self.block_b)
+        return self.backend.gather_aggregate(
+            gt.edge_src, gt.edge_dst, gt.edge_valid, h, op=op,
+            block_b=self.block_b)
 
     def edge_softmax_aggregate(self, gt: GraphTensors, z: jax.Array,
                                s_src: jax.Array, s_dst: jax.Array, *,
@@ -138,8 +139,7 @@ class GraphEngine:
         z (S, n, H·F) head-major, s_src/s_dst (S, n, H) per-node scores.
         The weights are a softmax over each node's in-edges on the binary
         blocks, so they never exist as a grid of their own."""
-        be = self.backend or resolve("edge_softmax_aggregate")
-        return be.edge_softmax_aggregate(
+        return self.backend.edge_softmax_aggregate(
             gt.blocks, z, s_src, s_dst, heads=s_src.shape[-1],
             negative_slope=negative_slope)
 
@@ -160,9 +160,8 @@ class GNNeratorController:
                     b=None, *, activation: str = "none") -> jax.Array:
         """act((A · H) · W) — GCN-style layer body on grouped features."""
         if self.fuse and b is None:
-            be = self.graph.backend or resolve("fused_aggregate_extract")
             with jax.named_scope("fused"):
-                return be.fused_aggregate_extract(
+                return self.graph.backend.fused_aggregate_extract(
                     gt.blocks, h, w, activation=activation,
                     block_b=self.graph.block_b)
         with jax.named_scope("aggregate"):
@@ -221,14 +220,3 @@ class GNNeratorController:
             if concat_self:
                 out = out + z[..., dout:]
             return _activate(out, activation)
-
-    def dense_first(self, gt: GraphTensors, h: jax.Array, w_pool: jax.Array,
-                    b_pool=None, *, activation: str = "none",
-                    agg: Literal["max", "sum"] = "max") -> jax.Array:
-        """agg(act(H · W_pool)) — GraphsagePool-style: Dense Engine is the
-        producer, Graph Engine the consumer."""
-        s, n, d = h.shape
-        z = self.dense(h.reshape(s * n, d), w_pool, b_pool,
-                       activation=activation)
-        z = z.reshape(s, n, -1)
-        return self.graph.aggregate(gt, z, op=agg)

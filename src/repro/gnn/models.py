@@ -34,14 +34,9 @@ walks the shard grid fewer times, graph-first otherwise
 from __future__ import annotations
 
 import dataclasses
-import warnings
-from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-
-from repro.core.engines import GraphTensors
 
 ARCHS = ("gcn", "sage_mean", "sage_max", "gin", "gat")
 
@@ -65,18 +60,6 @@ def graph_signature(arch: str) -> tuple[str, bool]:
     graph-specific caching).
     """
     return _GRAPH_SIG[arch]
-
-
-def build_zoo_graph(edges: np.ndarray, num_nodes: int, n: int,
-                    arch: str) -> GraphTensors:
-    """Deprecated: use ``repro.runtime.compile`` (which builds and caches
-    GraphTensors per signature) or ``repro.runtime.forward.build_graph_tensors``."""
-    warnings.warn(
-        "build_zoo_graph is deprecated; use repro.runtime.compile(...) — "
-        "it plans, shards and caches the graph in one call",
-        DeprecationWarning, stacklevel=2)
-    from repro.runtime.forward import build_graph_tensors
-    return build_graph_tensors(edges, num_nodes, n, arch)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -153,19 +136,3 @@ def init_zoo(key: jax.Array, spec: ZooSpec) -> dict:
                      "a_dst": _glorot(k3, (heads, hd))}
         layers.append(layer)
     return {"layers": layers}
-
-
-
-# --------------------------------------------------------------------------
-# Deprecated forward shim (implementation lives in repro.runtime.forward)
-# --------------------------------------------------------------------------
-
-def zoo_forward(spec: ZooSpec, params: dict, gt: GraphTensors,
-                h: jax.Array, *, plans: Sequence | None = None) -> jax.Array:
-    """Deprecated: compile once with ``repro.runtime.compile`` and call
-    ``Executable.forward()`` instead of re-chaining plan/graph/forward."""
-    warnings.warn(
-        "zoo_forward is deprecated; use repro.runtime.compile(...).forward()",
-        DeprecationWarning, stacklevel=2)
-    from repro.runtime.forward import forward
-    return forward(spec, params, gt, h, plans=plans)

@@ -1,3 +1,2 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""The engines' Pallas kernels (one module each), their pure-jnp oracles
+(``ref.py``) and the backend registry that runs them (``registry.py``)."""

@@ -2,7 +2,7 @@
 
 Each function is the semantic ground truth used by tests/test_kernels.py
 (assert_allclose vs the kernel in interpret mode across shape/dtype sweeps)
-and as the CPU fallback backend in kernels/ops.py.
+and behind the ``reference`` backend in kernels/registry.py.
 """
 from __future__ import annotations
 

@@ -13,14 +13,10 @@ Every compute primitive the engines need is one method on the
               written for clarity (explicit per-shard-pair loops), used as
               the oracle everything else is pinned against.
 
-Selection precedence, most specific wins:
-
-  1. an explicit backend passed per call / per ``runtime.compile(...)``,
-  2. a per-op override in ``REPRO_KERNEL_BACKEND_<OP>`` (op upper-cased),
-  3. the global ``REPRO_KERNEL_BACKEND`` env var,
-  4. the default, ``pallas``.
-
-``ref`` is accepted everywhere as a legacy alias for ``reference``.
+:func:`resolve` picks one for a whole model: an explicit backend (name or
+object) passed to ``runtime.compile(...)`` or ``runtime.forward.forward``,
+else the ``REPRO_KERNEL_BACKEND`` env var, else ``pallas``. The engines
+call the backend they are given.
 """
 from __future__ import annotations
 
@@ -40,10 +36,6 @@ from repro.kernels import shard_spmm as _ss
 from repro.utils import round_up
 
 DEFAULT_BACKEND = "pallas"
-
-# the ops every backend must provide (= the registry's per-op override keys)
-OP_NAMES = ("dense_matmul", "graph_aggregate", "fused_aggregate_extract",
-            "gather_aggregate", "edge_softmax_aggregate", "attention")
 
 
 @runtime_checkable
@@ -418,24 +410,18 @@ class PallasBackend:
 # --------------------------------------------------------------------------
 
 _REGISTRY: dict[str, KernelBackend] = {}
-_ALIASES: dict[str, str] = {"ref": "reference"}   # legacy env value
 
 
-def register_backend(backend: KernelBackend, *,
-                     aliases: tuple[str, ...] = ()) -> KernelBackend:
-    """Register a backend under ``backend.name`` (plus optional aliases).
-    Re-registering a name replaces it — deliberate, so tests/plugins can
-    swap implementations."""
+def register_backend(backend: KernelBackend) -> KernelBackend:
+    """Register a backend under ``backend.name``. Re-registering a name
+    replaces it — deliberate, so tests/plugins can swap implementations."""
     _REGISTRY[backend.name] = backend
-    for a in aliases:
-        _ALIASES[a] = backend.name
     return backend
 
 
 def get_backend(name: str) -> KernelBackend:
-    key = _ALIASES.get(name, name)
     try:
-        return _REGISTRY[key]
+        return _REGISTRY[name]
     except KeyError:
         raise ValueError(f"unknown kernel backend {name!r}; "
                          f"registered: {list_backends()}") from None
@@ -445,50 +431,17 @@ def list_backends() -> tuple[str, ...]:
     return tuple(sorted(_REGISTRY))
 
 
-def resolve(op: str | None = None,
-            override: str | KernelBackend | None = None) -> KernelBackend:
-    """Resolve the backend for one op (see module docstring for precedence).
-
-    ``override`` may be a backend name or an actual backend object (e.g. a
-    :func:`composite_backend`); ``op=None`` skips the per-op env override.
-    """
-    if override is not None:
-        if isinstance(override, str):
-            return get_backend(override)
-        return override
-    if op is not None:
-        per_op = os.environ.get(f"REPRO_KERNEL_BACKEND_{op.upper()}")
-        if per_op:
-            return get_backend(per_op)
-    return get_backend(os.environ.get("REPRO_KERNEL_BACKEND",
-                                      DEFAULT_BACKEND))
-
-
-class _CompositeBackend:
-    """Routes each op to its own backend (per-op selection)."""
-
-    def __init__(self, default: KernelBackend,
-                 per_op: dict[str, KernelBackend]):
-        self.default = default
-        self.per_op = per_op
-        ops = ",".join(f"{k}={v.name}" for k, v in sorted(per_op.items()))
-        self.name = f"composite({default.name}; {ops})"
-        for op in OP_NAMES:
-            setattr(self, op, getattr(per_op.get(op, default), op))
-
-
-def composite_backend(default: str | KernelBackend,
-                      per_op: dict[str, str | KernelBackend]) -> KernelBackend:
-    """Build a backend that answers each op from a different registry entry
-    (``runtime.compile(..., op_backends={...})`` uses this)."""
-    for op in per_op:
-        if op not in OP_NAMES:
-            raise ValueError(f"unknown op {op!r}; ops: {OP_NAMES}")
-    return _CompositeBackend(
-        resolve(override=default),
-        {op: resolve(override=b) for op, b in per_op.items()})
+def resolve(override: str | KernelBackend | None = None) -> KernelBackend:
+    """The backend a model runs on: ``override`` (a registered name or a
+    backend object) if given, else ``REPRO_KERNEL_BACKEND``, else
+    ``pallas``."""
+    if override is None:
+        override = os.environ.get("REPRO_KERNEL_BACKEND", DEFAULT_BACKEND)
+    if isinstance(override, str):
+        return get_backend(override)
+    return override
 
 
 register_backend(PallasBackend())
 register_backend(JaxBackend())
-register_backend(ReferenceBackend(), aliases=("ref",))
+register_backend(ReferenceBackend())

@@ -241,7 +241,7 @@ def main() -> None:
     ap.add_argument("--graphs", default="cora")
     ap.add_argument("--models", default="gcn,gat")
     ap.add_argument("--backend", default=None,
-                    choices=["pallas", "jax", "reference", "ref"],
+                    choices=["pallas", "jax", "reference"],
                     help="kernel backend pinned into each compiled "
                          "Executable (default: REPRO_KERNEL_BACKEND env, "
                          "else pallas)")

@@ -41,7 +41,7 @@ def main() -> None:
     ap.add_argument("--scale", type=float, default=1.0,
                     help="dataset node/edge scale factor")
     ap.add_argument("--backend", default=None,
-                    choices=["pallas", "jax", "reference", "ref"])
+                    choices=["pallas", "jax", "reference"])
     ap.add_argument("--plan", choices=["analytic", "autotune"],
                     default="analytic",
                     help="layer-plan source: Table-I cost model, or "

@@ -6,11 +6,9 @@
     classes, probs = exe.predict([0, 7, 9]) # node batch, cached softmax
     print(exe.summary())
 
-One ``compile()`` call replaces the old hand-chained
-``plan_model → build_zoo_graph → init_zoo → zoo_forward`` pipeline (those
-remain as deprecation shims in :mod:`repro.gnn.models`). Kernel backends
-(``pallas`` / ``jax`` / ``reference``) are pluggable per compile and per
-op via :mod:`repro.kernels.registry`. Plans come from one of two
+One ``compile()`` call plans, shards, initializes and jits a model.
+Kernel backends (``pallas`` / ``jax`` / ``reference``) are pluggable per
+compile via :mod:`repro.kernels.registry`. Plans come from one of two
 sources: the analytic Table-I cost model (``plan="analytic"``, default)
 or the empirical autotuner (``plan="autotune"``, :mod:`repro.tune`) that
 measures the analytic top-k on the real backend and memoizes the winner.

@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import functools
 import hashlib
-import os
 
 import jax
 import numpy as np
@@ -94,7 +93,6 @@ def _compile_span(fn):
 def compile(spec: ZooSpec, graph, *,
             platform: Platform = GNNERATOR,
             backend: str | registry.KernelBackend | None = None,
-            op_backends: dict | None = None,
             params: dict | None = None,
             seed: int = 0,
             max_shard_n: int = 1024,
@@ -140,8 +138,6 @@ def compile(spec: ZooSpec, graph, *,
       backend: kernel backend name/object; None resolves from the
         ``REPRO_KERNEL_BACKEND`` env var (default ``pallas``) and is then
         *pinned* into the Executable.
-      op_backends: optional per-op overrides, e.g.
-        ``{"gather_aggregate": "jax"}`` — merged over ``backend``.
       params: adopt an existing param pytree; None initializes from seed.
       max_shard_n: planner cap on nodes per shard.
       store: GraphStore for the signature-keyed GraphTensors build
@@ -184,19 +180,7 @@ def compile(spec: ZooSpec, graph, *,
         raise ValueError(f"analyze must be None, 'off', 'warn' or "
                          f"'error', got {analyze!r}")
     edges, num_nodes, features = _as_graph(graph)
-    # precedence per op: explicit op_backends > explicit backend arg >
-    # REPRO_KERNEL_BACKEND_<OP> env > global env > default. An explicit
-    # backend arg deliberately beats the per-op env vars; when none is
-    # given, the env overrides must survive into the pinned Executable.
-    per_op = dict(op_backends or {})
-    if backend is None:
-        for op in registry.OP_NAMES:
-            env = os.environ.get(f"REPRO_KERNEL_BACKEND_{op.upper()}")
-            if env and op not in per_op:
-                per_op[op] = env
-    be = registry.resolve(None, backend)
-    if per_op:
-        be = registry.composite_backend(be, per_op)
+    be = registry.resolve(backend)
 
     if graph_version is None:
         graph_version = int(getattr(graph, "version", 0))

@@ -1,10 +1,10 @@
 """Zoo-model forward pass on the GNNerator engines (runtime internals).
 
-This is the single implementation behind :meth:`Executable.forward` and the
-deprecated ``repro.gnn.models.zoo_forward`` shim. Per layer, an
-executor-provided :class:`repro.gnn.executor.LayerPlan` picks the feature
-block size B and whether the two stages run fused (h_agg never leaves
-VMEM) or two-stage through feature memory; the kernel backend is threaded
+This is the single-device forward behind :meth:`Executable.forward` and
+``runtime.fit``. Per layer, an executor-provided
+:class:`repro.gnn.executor.LayerPlan` picks the feature block size B and
+whether the two stages run fused (h_agg never leaves VMEM) or two-stage
+through feature memory; the kernel backend is resolved once and threaded
 explicitly so a compiled Executable is pinned to one backend regardless of
 later env changes.
 
@@ -26,7 +26,7 @@ from repro.core.engines import (DenseEngine, GNNeratorController, GraphEngine,
 from repro.core.sharding import shard_graph
 from repro.gnn.models import ZooSpec, graph_signature
 from repro.kernels.ref import _activate
-from repro.kernels.registry import KernelBackend
+from repro.kernels.registry import KernelBackend, resolve
 
 
 def build_graph_tensors(edges: np.ndarray, num_nodes: int, n: int,
@@ -138,14 +138,15 @@ def producer_orders(spec: ZooSpec,
 
 def forward(spec: ZooSpec, params: dict, gt: GraphTensors,
             h: jax.Array, *, plans: Sequence | None = None,
-            backend: KernelBackend | None = None) -> jax.Array:
+            backend: str | KernelBackend | None = None) -> jax.Array:
     """Run the model; h is (S, n, in_dim) shard-grouped (GraphTensors.group).
 
     ``plans`` is an optional per-layer sequence of LayerPlans from
     repro.gnn.executor; None falls back to the default controller (fused
-    where legal, B=128). ``backend=None`` resolves per call from the
-    kernel registry (env-var selectable).
+    where legal, B=128). ``backend`` goes through
+    :func:`repro.kernels.registry.resolve` once, for every layer.
     """
+    backend = resolve(backend)
     layers = params["layers"]
     for i, layer in enumerate(layers):
         plan = plans[i] if plans is not None else None

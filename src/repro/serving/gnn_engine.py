@@ -30,9 +30,8 @@ the request that triggered it, later requests pay only their gather);
 compile time is never folded into request latency — it accrues to
 ``stats["compile_ms_total"]``. ``queue_ms`` is stamped by the Server.
 
-The pre-Server one-shot API (``submit()``/``flush()``) remains as a thin
-synchronous shim emitting ``DeprecationWarning``; ``serve()`` stays as the
-synchronous batch core the shim and the Server path share.
+``serve()`` is the synchronous batch core; the Server path answers each
+micro-batch through the same ``route``/``step``.
 
 Layer execution plans come from the content-hash-memoized planner inside
 ``runtime.compile`` — block size B, traversal order and fused/two-stage
@@ -48,7 +47,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-import warnings
 from collections import OrderedDict
 from typing import Sequence
 
@@ -137,7 +135,6 @@ class GNNServeEngine:
         # that warm requests gather from
         self._executables: dict[tuple[str, str],
                                 runtime.Executable] = {}  # guarded-by: Server._step_lock
-        self._pending: list[NodeRequest] = []  # guarded-by: caller (deprecated sync shim)
         self.max_shard_n = max_shard_n
         self.max_dense_gib = max_dense_gib
         self.backend = backend
@@ -517,31 +514,6 @@ class GNNServeEngine:
             for i, pred in zip(idxs, preds):
                 out[i] = pred
         return out  # type: ignore[return-value]
-
-    # -- deprecated one-shot shim ------------------------------------------
-
-    def submit(self, req: NodeRequest) -> None:
-        """Deprecated: queue one request for the next ``flush()``."""
-        warnings.warn(
-            "GNNServeEngine.submit/flush are deprecated; submit through "
-            "repro.serving.Server for scheduled, ticketed serving",
-            DeprecationWarning, stacklevel=2)
-        self._pending.append(req)
-
-    def flush(self) -> list[Prediction]:
-        """Deprecated: serve all pending requests, micro-batched by
-        (model, graph).
-
-        The queue is cleared only on success: a rejected batch (unknown
-        name, bad node ids) leaves every request queued for the caller to
-        repair or drop."""
-        warnings.warn(
-            "GNNServeEngine.submit/flush are deprecated; submit through "
-            "repro.serving.Server for scheduled, ticketed serving",
-            DeprecationWarning, stacklevel=2)
-        preds = self.serve(self._pending)
-        self._pending = []
-        return preds
 
     def cache_report(self) -> str:
         s = self.stats

@@ -1,7 +1,9 @@
-"""Backend parity: every op in the kernel registry must produce the same
-numbers on `pallas`, `jax` and `reference` over hypothesis-generated shard
-grids (extending the test_gnn_models oracle pattern one level down: the
-reference backend IS the oracle, the others must match it allclose)."""
+"""Backend parity: every op of the KernelBackend protocol must produce the
+same numbers on `pallas`, `jax` and `reference` over hypothesis-generated
+shard grids (extending the test_gnn_models oracle pattern one level down:
+the reference backend IS the oracle, the others must match it allclose);
+and `registry.resolve`'s one order: explicit backend, then
+REPRO_KERNEL_BACKEND, then pallas."""
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -33,8 +35,9 @@ class TestRegistryParity:
         assert set(registry.list_backends()) >= {"pallas", "jax", "reference"}
         for name in registry.list_backends():
             be = registry.get_backend(name)
-            for op in registry.OP_NAMES:
-                assert callable(getattr(be, op)), (name, op)
+            for op, fn in vars(registry.KernelBackend).items():
+                if callable(fn) and not op.startswith("_"):
+                    assert callable(getattr(be, op)), (name, op)
 
     @settings(deadline=None, max_examples=8)
     @given(m=st.sampled_from([3, 16, 64]), k=st.sampled_from([8, 33]),
@@ -117,33 +120,22 @@ class TestRegistryParity:
 class TestResolution:
     def test_env_selects_backend(self, monkeypatch):
         monkeypatch.setenv("REPRO_KERNEL_BACKEND", "reference")
-        assert registry.resolve("dense_matmul").name == "reference"
-        monkeypatch.setenv("REPRO_KERNEL_BACKEND", "ref")   # legacy alias
-        assert registry.resolve("dense_matmul").name == "reference"
+        assert registry.resolve().name == "reference"
         monkeypatch.delenv("REPRO_KERNEL_BACKEND")
-        assert registry.resolve("dense_matmul").name == registry.DEFAULT_BACKEND
-
-    def test_per_op_env_override(self, monkeypatch):
-        monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
-        monkeypatch.setenv("REPRO_KERNEL_BACKEND_GATHER_AGGREGATE", "jax")
-        assert registry.resolve("gather_aggregate").name == "jax"
-        assert registry.resolve("dense_matmul").name == "pallas"
+        assert registry.resolve().name == registry.DEFAULT_BACKEND
 
     def test_explicit_override_wins(self, monkeypatch):
         monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
-        assert registry.resolve("dense_matmul", "reference").name == "reference"
+        assert registry.resolve("reference").name == "reference"
         be = registry.get_backend("jax")
-        assert registry.resolve("dense_matmul", be) is be
+        assert registry.resolve(be) is be
 
-    def test_composite_backend_routes_per_op(self):
-        comp = registry.composite_backend(
-            "reference", {"dense_matmul": "jax"})
-        assert comp.dense_matmul.__self__ is registry.get_backend("jax")
-        assert (comp.graph_aggregate.__self__
-                is registry.get_backend("reference"))
-        with pytest.raises(ValueError):
-            registry.composite_backend("reference", {"nope": "jax"})
-
-    def test_unknown_backend_raises(self):
-        with pytest.raises(ValueError):
-            registry.get_backend("fpga")
+    @pytest.mark.parametrize("name", ["fpga", "ref"])
+    def test_unknown_backend_raises(self, name, monkeypatch):
+        with pytest.raises(ValueError) as err:
+            registry.get_backend(name)
+        for known in registry.list_backends():
+            assert repr(known) in str(err.value)
+        monkeypatch.setenv("REPRO_KERNEL_BACKEND", name)
+        with pytest.raises(ValueError, match=repr(name)):
+            registry.resolve()

@@ -7,10 +7,10 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.dataflow import (Dataflow, best_order, blocked_vs_conventional,
                                  simulate_traffic, table1_costs)
-from repro.core.models import (build_graph_tensors, init_gnn, make_forward,
-                               paper_spec)
 from repro.core.sharding import max_shard_nodes_for_budget, shard_graph
+from repro.gnn.models import ZooSpec, init_zoo
 from repro.graphs.datasets import DATASETS, make_dataset
+from repro.runtime.forward import build_graph_tensors, forward
 
 
 def _toy_graph(n_nodes=50, n_edges=200, seed=0):
@@ -114,16 +114,20 @@ class TestDataflow:
         assert t4.onchip_edge_reads == 4 * t1.onchip_edge_reads
 
 
+def _table3_spec(arch: str, in_dim: int, num_classes: int) -> ZooSpec:
+    """The paper's Table III networks: one hidden layer of 16."""
+    return ZooSpec(arch, in_dim, 16, num_classes, num_layers=2)
+
+
 class TestModels:
-    @pytest.mark.parametrize("kind", ["gcn", "graphsage", "graphsage_pool"])
-    def test_forward_shapes_and_finite(self, kind):
+    @pytest.mark.parametrize("arch", ["gcn", "sage_mean", "sage_max"])
+    def test_forward_shapes_and_finite(self, arch):
         edges = _toy_graph(80, 300, seed=1)
         feats = np.random.default_rng(0).standard_normal((80, 24)).astype(np.float32)
-        gt = build_graph_tensors(edges, 80, n=32, kind=kind)
-        spec = paper_spec(kind, 24, 5)
-        params = init_gnn(jax.random.key(0), spec)
-        fwd = make_forward(spec)
-        out = fwd(params, gt, gt.group(jnp.asarray(feats)))
+        gt = build_graph_tensors(edges, 80, 32, arch)
+        spec = _table3_spec(arch, 24, 5)
+        params = init_zoo(jax.random.key(0), spec)
+        out = forward(spec, params, gt, gt.group(jnp.asarray(feats)))
         assert out.shape == (80, 5)
         assert bool(jnp.isfinite(out).all())
 
@@ -133,10 +137,10 @@ class TestModels:
         edges = _toy_graph(40, 160, seed=7)
         n_nodes, f_in, f_out = 40, 16, 4
         feats = np.random.default_rng(1).standard_normal((n_nodes, f_in)).astype(np.float32)
-        gt = build_graph_tensors(edges, n_nodes, n=16, kind="gcn")
-        spec = paper_spec("gcn", f_in, f_out)
-        params = init_gnn(jax.random.key(1), spec)
-        out = make_forward(spec)(params, gt, gt.group(jnp.asarray(feats)))
+        gt = build_graph_tensors(edges, n_nodes, 16, "gcn")
+        spec = _table3_spec("gcn", f_in, f_out)
+        params = init_zoo(jax.random.key(1), spec)
+        out = forward(spec, params, gt, gt.group(jnp.asarray(feats)))
 
         # dense reference: Â = D^-1/2 (A+I) D^-1/2 (per-direction degrees)
         a = np.zeros((n_nodes, n_nodes), np.float32)
@@ -158,13 +162,13 @@ class TestModels:
         """Changing the shard size n (hence S) must not change results."""
         edges = _toy_graph(60, 240, seed=9)
         feats = np.random.default_rng(2).standard_normal((60, 12)).astype(np.float32)
-        spec = paper_spec("gcn", 12, 3)
-        params = init_gnn(jax.random.key(2), spec)
-        fwd = make_forward(spec)
+        spec = _table3_spec("gcn", 12, 3)
+        params = init_zoo(jax.random.key(2), spec)
         outs = []
         for n in (16, 32, 64):
-            gt = build_graph_tensors(edges, 60, n=n, kind="gcn")
-            outs.append(np.asarray(fwd(params, gt, gt.group(jnp.asarray(feats)))))
+            gt = build_graph_tensors(edges, 60, n, "gcn")
+            outs.append(np.asarray(forward(spec, params, gt,
+                                           gt.group(jnp.asarray(feats)))))
         np.testing.assert_allclose(outs[0], outs[1], atol=1e-3, rtol=1e-3)
         np.testing.assert_allclose(outs[0], outs[2], atol=1e-3, rtol=1e-3)
 

@@ -6,10 +6,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.gnn.executor import plan_model
-from repro.gnn.models import (ARCHS, ZooSpec, build_zoo_graph, init_zoo,
-                              zoo_forward)
+from repro.gnn.models import ARCHS, ZooSpec, init_zoo
 from repro.graphs.datasets import DATASETS, load, make_dataset
 from repro.kernels import ref
+from repro.runtime.forward import build_graph_tensors, forward
 from repro.serving.gnn_engine import GNNServeEngine, NodeRequest
 
 
@@ -18,7 +18,7 @@ def _ref_backend(monkeypatch):
     """Model-level tests target assembly logic (grouping, normalization,
     attention, planning), not kernel numerics — kernel parity is covered by
     tests/test_kernels.py. The jnp backend keeps the sweep fast on CPU."""
-    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "ref")
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "reference")
 
 
 def _flat_adj(gt) -> np.ndarray:
@@ -64,10 +64,10 @@ class TestZooVsReference:
                        num_layers=2, heads=2)
         mp = plan_model(spec, prof.num_nodes, ds.edges.shape[0], max_n=64)
         assert mp.layers[0].S > 1, "test must exercise a multi-shard grid"
-        gt = build_zoo_graph(ds.edges, prof.num_nodes, mp.shard_n, arch)
+        gt = build_graph_tensors(ds.edges, prof.num_nodes, mp.shard_n, arch)
         params = init_zoo(jax.random.key(0), spec)
-        out = zoo_forward(spec, params, gt, gt.group(jnp.asarray(ds.features)),
-                          plans=mp.layers)
+        out = forward(spec, params, gt, gt.group(jnp.asarray(ds.features)),
+                      plans=mp.layers)
 
         a = _flat_adj(gt)
         h = np.zeros((a.shape[0], prof.feature_dim), np.float32)
@@ -83,10 +83,10 @@ class TestZooVsReference:
         spec = ZooSpec("gcn", prof.feature_dim, 8, prof.num_classes,
                        num_layers=3)
         mp = plan_model(spec, prof.num_nodes, ds.edges.shape[0], max_n=32)
-        gt = build_zoo_graph(ds.edges, prof.num_nodes, mp.shard_n, "gcn")
+        gt = build_graph_tensors(ds.edges, prof.num_nodes, mp.shard_n, "gcn")
         params = init_zoo(jax.random.key(1), spec)
-        out = zoo_forward(spec, params, gt, gt.group(jnp.asarray(ds.features)),
-                          plans=mp.layers)
+        out = forward(spec, params, gt, gt.group(jnp.asarray(ds.features)),
+                      plans=mp.layers)
         a = _flat_adj(gt)
         h = np.zeros((a.shape[0], prof.feature_dim), np.float32)
         h[:prof.num_nodes] = ds.features
@@ -106,13 +106,13 @@ class TestZooVsReference:
         mp = plan_model(spec, prof.num_nodes, ds.edges.shape[0], max_n=64)
         assert mp.layers[0].S > 1
         assert mp.layers[1].d_agg == out_heads * prof.num_classes
-        gt = build_zoo_graph(ds.edges, prof.num_nodes, mp.shard_n, "gat")
+        gt = build_graph_tensors(ds.edges, prof.num_nodes, mp.shard_n, "gat")
         params = init_zoo(jax.random.key(5), spec)
         last = params["layers"][1]
         assert last["w"].shape == (64, out_heads * prof.num_classes)
         assert last["a_src"].shape == (out_heads, prof.num_classes)
-        out = zoo_forward(spec, params, gt, gt.group(jnp.asarray(ds.features)),
-                          plans=mp.layers)
+        out = forward(spec, params, gt, gt.group(jnp.asarray(ds.features)),
+                      plans=mp.layers)
         a = _flat_adj(gt)
         h = np.zeros((a.shape[0], prof.feature_dim), np.float32)
         h[:prof.num_nodes] = ds.features
@@ -132,10 +132,10 @@ class TestZooVsReference:
         for arch in ("gcn", "gat"):
             spec = ZooSpec(arch, d, 8, c, num_layers=2, heads=2)
             mp = plan_model(spec, n_nodes, len(e), max_n=16)
-            gt = build_zoo_graph(e, n_nodes, mp.shard_n, arch)
+            gt = build_graph_tensors(e, n_nodes, mp.shard_n, arch)
             params = init_zoo(jax.random.key(0), spec)
-            out = zoo_forward(spec, params, gt, gt.group(jnp.asarray(feats)),
-                              plans=mp.layers)
+            out = forward(spec, params, gt, gt.group(jnp.asarray(feats)),
+                          plans=mp.layers)
             a = _flat_adj(gt)
             h = np.zeros((a.shape[0], d), np.float32)
             h[:n_nodes] = feats
@@ -219,11 +219,11 @@ class TestGNNServing:
         spec = eng._models["gcn"].spec
         params = eng._models["gcn"].params
         mp = eng.model_plan("gcn", "cora")
-        gt = build_zoo_graph(ds.edges, ds.profile.num_nodes, mp.shard_n,
-                             "gcn")
-        logits = zoo_forward(spec, params, gt,
-                             gt.group(jnp.asarray(ds.features)),
-                             plans=mp.layers)
+        gt = build_graph_tensors(ds.edges, ds.profile.num_nodes, mp.shard_n,
+                                 "gcn")
+        logits = forward(spec, params, gt,
+                         gt.group(jnp.asarray(ds.features)),
+                         plans=mp.layers)
         np.testing.assert_array_equal(
             pred.classes, np.argmax(np.asarray(logits)[ids], axis=-1))
         assert pred.probs.shape == (4,)
@@ -235,9 +235,7 @@ class TestGNNServing:
         reqs = [NodeRequest("cora", np.array([i % n, (i * 7) % n]),
                             model=("gcn" if i % 2 else "gat"))
                 for i in range(10)]
-        for r in reqs:
-            eng.submit(r)
-        preds = eng.flush()
+        preds = eng.serve(reqs)
         assert len(preds) == 10
         # answers come back in request order with the right routing
         for r, p in zip(reqs, preds):
@@ -248,7 +246,7 @@ class TestGNNServing:
         assert s["logits_cache_misses"] == 2
         assert s["logits_cache_hits"] == 8
         assert s["batches"] == 2
-        # second flush of the same traffic is all cache hits
+        # a second pass over the same traffic is all cache hits
         preds2 = eng.serve(reqs)
         assert eng.stats["logits_cache_misses"] == 2
         np.testing.assert_array_equal(preds2[0].classes, preds[0].classes)

@@ -5,7 +5,7 @@ import pytest
 import jax.numpy as jnp
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ops, ref
+from repro.kernels import ref, registry
 from repro.kernels.dense_engine import dense_engine_matmul
 from repro.kernels.edge_softmax import block_rows, edge_softmax_aggregate
 from repro.kernels.flash_attention import flash_attention
@@ -70,12 +70,8 @@ def test_seg_gather(op, s, n, e, d, bb):
     out = seg_gather_aggregate(es, ed, ev, h, op=op, block_b=bb,
                                interpret=True)
     # oracle: combine per-pair refs across the src axis
-    import os
-    os.environ["REPRO_KERNEL_BACKEND"] = "ref"
-    try:
-        exp = ops.gather_aggregate(es, ed, ev, h, op=op)
-    finally:
-        os.environ.pop("REPRO_KERNEL_BACKEND")
+    exp = registry.get_backend("reference").gather_aggregate(
+        es, ed, ev, h, op=op)
     np.testing.assert_allclose(out, exp, atol=1e-4, rtol=1e-4)
 
 

@@ -1,5 +1,6 @@
 """repro.runtime: the compile() -> Executable API, plan serialization +
-memoization, backend parity per zoo arch, and the deprecation shims."""
+memoization, backend parity per zoo arch, and the module forward the
+Executable runs."""
 import json
 import warnings
 
@@ -100,35 +101,28 @@ class TestCompile:
         assert not np.allclose(np.asarray(e1.forward()),
                                np.asarray(e2.forward()))
 
-    def test_per_op_env_override_reaches_compile(self, monkeypatch):
-        """Regression: REPRO_KERNEL_BACKEND_<OP> must survive into the
-        pinned Executable when no explicit backend is passed."""
+    def test_compile_pins_the_env_backend(self, monkeypatch):
+        """With no backend argument the compile resolves
+        REPRO_KERNEL_BACKEND once; changing it afterwards (even to a name
+        no backend has) re-routes nothing."""
         monkeypatch.setenv("REPRO_KERNEL_BACKEND", "reference")
-        monkeypatch.setenv("REPRO_KERNEL_BACKEND_GATHER_AGGREGATE", "jax")
         ds = make_dataset("cora", seed=0, scale=0.05)
         exe = runtime.compile(_spec("sage_max", ds.profile), ds,
                               max_shard_n=64)
-        assert exe.backend.gather_aggregate.__self__ is \
-            runtime.get_backend("jax")
-        assert exe.backend.dense_matmul.__self__ is \
-            runtime.get_backend("reference")
-        # an explicit backend argument beats the per-op env override
+        assert exe.backend is runtime.get_backend("reference")
+        monkeypatch.setenv("REPRO_KERNEL_BACKEND", "fpga")
+        out = np.asarray(exe.forward())
+        assert exe.backend is runtime.get_backend("reference")
         pinned = runtime.compile(_spec("sage_max", ds.profile), ds,
                                  backend="reference", max_shard_n=64)
-        assert pinned.backend is runtime.get_backend("reference")
+        np.testing.assert_array_equal(out, np.asarray(pinned.forward()))
 
-    def test_op_backends_override(self):
+    def test_explicit_backend_beats_env_at_compile(self, monkeypatch):
+        monkeypatch.setenv("REPRO_KERNEL_BACKEND", "jax")
         ds = make_dataset("cora", seed=0, scale=0.05)
         exe = runtime.compile(_spec("sage_max", ds.profile), ds,
-                              backend="reference",
-                              op_backends={"gather_aggregate": "jax"},
-                              max_shard_n=64)
-        assert exe.backend_name.startswith("composite(reference")
-        ref_exe = runtime.compile(_spec("sage_max", ds.profile), ds,
-                                  backend="reference", max_shard_n=64)
-        np.testing.assert_allclose(np.asarray(exe.forward()),
-                                   np.asarray(ref_exe.forward()),
-                                   atol=1e-5, rtol=1e-5)
+                              backend="reference", max_shard_n=64)
+        assert exe.backend is runtime.get_backend("reference")
 
     def test_params_roundtrip(self, tmp_path):
         ds = make_dataset("cora", seed=0, scale=0.05)
@@ -250,20 +244,22 @@ class TestPlanCacheAndSerialization:
         assert executor.plan_cache_stats()["misses"] == 0
 
 
-class TestDeprecationShims:
-    def test_old_api_warns_and_matches(self):
-        from repro.gnn.models import build_zoo_graph, zoo_forward
+class TestModuleForward:
+    @pytest.mark.parametrize("arch", ARCHS)
+    def test_module_forward_matches_executable(self, arch):
+        """runtime.forward.forward on a graph built by
+        build_graph_tensors, at the compiled plans and backend, is what
+        Executable.forward() runs."""
+        from repro.runtime.forward import build_graph_tensors, forward
         ds = make_dataset("cora", seed=0, scale=0.05)
-        exe = runtime.compile(_spec("gcn", ds.profile), ds,
+        exe = runtime.compile(_spec(arch, ds.profile), ds,
                               backend="reference", max_shard_n=64)
-        with pytest.warns(DeprecationWarning):
-            gt = build_zoo_graph(ds.edges, ds.profile.num_nodes,
-                                 exe.plan.shard_n, "gcn")
-        with pytest.warns(DeprecationWarning):
-            old = zoo_forward(exe.spec, exe.params, gt,
-                              gt.group(jnp.asarray(ds.features)),
-                              plans=exe.plan.layers)
-        np.testing.assert_allclose(np.asarray(old), np.asarray(exe.forward()),
+        gt = build_graph_tensors(ds.edges, ds.profile.num_nodes,
+                                 exe.plan.shard_n, arch)
+        out = forward(exe.spec, exe.params, gt,
+                      gt.group(jnp.asarray(ds.features)),
+                      plans=exe.plan.layers, backend=exe.backend)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(exe.forward()),
                                    atol=1e-5, rtol=1e-5)
 
     def test_new_consumers_emit_no_deprecation_warnings(self):

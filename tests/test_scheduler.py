@@ -250,12 +250,3 @@ class TestServerOverGNNEngine:
             out = t.poll()
             assert isinstance(out, Rejected) and kind in out.reason
         assert srv.queue_depth() == 0
-
-    def test_submit_flush_shim_warns_and_still_works(self, engine):
-        from repro.serving.gnn_engine import NodeRequest
-
-        with pytest.warns(DeprecationWarning, match="Server"):
-            engine.submit(NodeRequest("cora", np.array([1]), model="gcn"))
-        with pytest.warns(DeprecationWarning, match="Server"):
-            preds = engine.flush()
-        assert len(preds) == 1 and preds[0].classes.shape == (1,)

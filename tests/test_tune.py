@@ -10,7 +10,7 @@ from repro import runtime, tune
 from repro.gnn import executor
 from repro.gnn.models import ZooSpec
 from repro.graphs.datasets import TABLE2_DATASETS, make_dataset
-from repro.kernels.registry import OP_NAMES, resolve
+from repro.kernels.registry import KernelBackend, get_backend
 from repro.tune.measure import Measurement
 from repro.tune.store import TUNER_VERSION, TuneRecord
 
@@ -42,8 +42,9 @@ def _boom(*_a, **_kw):
     raise RuntimeError("kernel exploded")
 
 
-for _op in OP_NAMES:
-    setattr(_BrokenBackend, _op, staticmethod(_boom))
+for _op, _fn in vars(KernelBackend).items():   # the protocol's ops
+    if callable(_fn) and not _op.startswith("_"):
+        setattr(_BrokenBackend, _op, staticmethod(_boom))
 
 
 class TestSearch:
@@ -119,7 +120,7 @@ class TestStaticPruning:
 
     def test_tune_report_records_pruned(self, tmp_path):
         ds, spec = _setup()
-        be = resolve(None, "reference")
+        be = get_backend("reference")
         rec = tune.autotune_plan(spec, ds.edges, ds.profile.num_nodes,
                                  backend=be, features=ds.features, max_n=64,
                                  budget=6, reps=1, cache_dir=tmp_path)
@@ -139,7 +140,7 @@ class TestAutotuneMemoization:
 
     def test_deterministic_and_memoized_in_process(self):
         ds, spec = _setup()
-        be = resolve(None, "reference")
+        be = get_backend("reference")
         kw = dict(backend=be, features=ds.features, max_n=64,
                   budget=4, seed=0, reps=2, warmup=1)
         rec1 = tune.autotune_plan(spec, ds.edges, ds.profile.num_nodes, **kw)
@@ -155,7 +156,7 @@ class TestAutotuneMemoization:
 
     def test_disk_memo_survives_restart(self, tmp_path):
         ds, spec = _setup()
-        be = resolve(None, "reference")
+        be = get_backend("reference")
         kw = dict(backend=be, features=ds.features, max_n=64,
                   budget=3, seed=1, reps=2, cache_dir=tmp_path)
         rec1 = tune.autotune_plan(spec, ds.edges, ds.profile.num_nodes, **kw)
@@ -169,7 +170,7 @@ class TestAutotuneMemoization:
 
     def test_corrupt_disk_entry_falls_back_to_retuning(self, tmp_path):
         ds, spec = _setup()
-        be = resolve(None, "reference")
+        be = get_backend("reference")
         key = tune.tune_key(spec, ds.profile.num_nodes, ds.edges.shape[0],
                             platform=executor.GNNERATOR, max_n=64,
                             block_candidates=executor._BLOCK_CANDIDATES,
@@ -187,7 +188,7 @@ class TestAutotuneMemoization:
 
     def test_stale_tuner_version_invalidates(self, tmp_path):
         ds, spec = _setup()
-        be = resolve(None, "reference")
+        be = get_backend("reference")
         kw = dict(backend=be, features=ds.features, max_n=64,
                   budget=3, seed=2, reps=2, cache_dir=tmp_path)
         tune.autotune_plan(spec, ds.edges, ds.profile.num_nodes, **kw)
@@ -205,7 +206,7 @@ class TestAutotuneMemoization:
     def test_budget_zero_is_analytic_fallback(self):
         ds, spec = _setup()
         rec = tune.autotune_plan(spec, ds.edges, ds.profile.num_nodes,
-                                 backend=resolve(None, "reference"),
+                                 backend=get_backend("reference"),
                                  max_n=64, budget=0)
         assert rec.plan_source == "analytic_fallback"
         assert rec.n_measured == 0 and rec.winner_ms is None
@@ -281,11 +282,11 @@ class TestKeyScoping:
         ds, spec = _setup(scale=0.02)
         kw = dict(features=ds.features, max_n=16, budget=2, reps=1)
         tune.autotune_plan(spec, ds.edges, ds.profile.num_nodes,
-                           backend=resolve(None, "reference"), **kw)
+                           backend=get_backend("reference"), **kw)
         n_ref = tune.tune_cache_stats()["measurements"]
         assert n_ref > 0
         tune.autotune_plan(spec, ds.edges, ds.profile.num_nodes,
-                           backend=resolve(None, "jax"), **kw)
+                           backend=get_backend("jax"), **kw)
         stats = tune.tune_cache_stats()
         assert stats["misses"] == 2               # distinct keys: re-tuned
         assert stats["measurements"] > n_ref
@@ -347,7 +348,7 @@ class TestRuntimeIntegration:
 
     def test_tune_record_json_roundtrip(self, tmp_path):
         ds, spec = _setup()
-        be = resolve(None, "reference")
+        be = get_backend("reference")
         rec = tune.autotune_plan(spec, ds.edges, ds.profile.num_nodes,
                                  backend=be, features=ds.features, max_n=64,
                                  budget=2, reps=1, cache_dir=tmp_path)
