@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.sharding import ShardedGraph
-from repro.kernels import registry
+from repro.kernels import edge_softmax, registry
 from repro.kernels.ref import _activate
 from repro.kernels.registry import KernelBackend, grid_walks
 
@@ -52,6 +52,23 @@ def upload_grid(blocks: np.ndarray) -> jax.Array:
     return grid
 
 
+def upload_edge_tiles(edge_src, edge_dst, edge_valid, n: int, *,
+                      e_cap: int) -> edge_softmax.EdgeTiles:
+    """The device :class:`~repro.kernels.edge_softmax.EdgeTiles` of padded
+    (S, S, E) per-shard edge lists (local ids, as ``shard_graph`` makes
+    them), in the chunk shape ``edge_softmax.tile_shape(n)`` gives, sized
+    for ``e_cap`` edges; the mask in bfloat16, which holds its 0 and 1."""
+    valid = np.asarray(edge_valid)
+    i, j, e = np.nonzero(valid)
+    src = j * n + np.asarray(edge_src)[i, j, e]
+    dst = i * n + np.asarray(edge_dst)[i, j, e]
+    rows, width = edge_softmax.tile_shape(n)
+    host = edge_softmax.edge_tiles(src, dst, valid.shape[0] * n,
+                                   e_cap=e_cap, rows=rows, width=width)
+    tiles = edge_softmax.EdgeTiles(*map(jnp.asarray, host))
+    return tiles._replace(mask=tiles.mask.astype(jnp.bfloat16))
+
+
 @dataclasses.dataclass(frozen=True)
 class GraphTensors:
     """Device-ready arrays for one sharded graph + one normalization."""
@@ -63,6 +80,9 @@ class GraphTensors:
     num_nodes: int
     n: int
     S: int
+    # the edge softmax's column-compacted tiles: built only for a model
+    # that has an edge softmax layer (``with_edge_tiles``)
+    tiles: edge_softmax.EdgeTiles | None = None
 
     @classmethod
     def from_sharded(cls, sg: ShardedGraph) -> "GraphTensors":
@@ -75,6 +95,25 @@ class GraphTensors:
             n=sg.n,
             S=sg.S,
         )
+
+    def with_edge_tiles(self, *, slack: float = 0.0,
+                        like: edge_softmax.EdgeTiles | None = None
+                        ) -> "GraphTensors":
+        """This graph with its edge tiles, sized for as many edges as
+        ``like``'s (the tiles of the graph before a patch) where this
+        graph's edges fit in them, so that the tiles keep their shape;
+        else for the edge count and ``slack`` more, the headroom of a
+        graph that will be patched."""
+        count = int(np.asarray(self.edge_valid).sum())
+        cap = 0
+        if like is not None:
+            t_max, rows, width = like.mask.shape
+            cap = (t_max - self.S * self.n // rows) * width
+        if count > cap:
+            cap = count + int(np.ceil(count * slack))
+        return dataclasses.replace(self, tiles=upload_edge_tiles(
+            self.edge_src, self.edge_dst, self.edge_valid, self.n,
+            e_cap=cap))
 
     @property
     def occupancy(self):
@@ -137,10 +176,11 @@ class GraphEngine:
                                negative_slope: float) -> jax.Array:
         """Attention-weighted aggregation of every head (GAT) in one op:
         z (S, n, H·F) head-major, s_src/s_dst (S, n, H) per-node scores.
-        The weights are a softmax over each node's in-edges on the binary
-        blocks, so they never exist as a grid of their own."""
+        The weights are a softmax over each node's in-edges, so they never
+        exist as a grid of their own: the pallas backend walks the graph's
+        edge tiles (``gt.tiles``), the others the binary blocks."""
         return self.backend.edge_softmax_aggregate(
-            gt.blocks, z, s_src, s_dst, heads=s_src.shape[-1],
+            gt.blocks, gt.tiles, z, s_src, s_dst, heads=s_src.shape[-1],
             negative_slope=negative_slope)
 
 

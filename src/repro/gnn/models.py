@@ -22,8 +22,9 @@ GAT has ELU between its layers, as published):
 
 GAT's attention runs in one Graph Engine op per layer
 (``GraphEngine.edge_softmax_aggregate``): every head's softmax over each
-node's in-edges and its weighted sum, in one walk of the binary shard
-grid, so the weights α never exist as a grid of their own. Hidden layers
+node's in-edges and its weighted sum, in one walk of the graph's edge
+tiles (the source columns of each row tile that hold an edge), so the
+weights α never exist as a grid of their own. Hidden layers
 concatenate ``heads`` heads; the output layer averages ``out_heads``.
 
 "Either order": the layer is linear up to its activation, so the
@@ -50,6 +51,11 @@ _GRAPH_SIG = {
     "gin": ("sum", False),        # (1+ε)·h term replaces the self loop
     "gat": ("sum", True),         # binary mask; α supplies the weights
 }
+
+
+# architectures whose layers run the edge softmax aggregation, and so
+# need their graph's edge tiles (``GraphTensors.with_edge_tiles``)
+EDGE_SOFTMAX_ARCHS = ("gat",)
 
 
 def graph_signature(arch: str) -> tuple[str, bool]:

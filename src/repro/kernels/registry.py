@@ -63,11 +63,13 @@ class KernelBackend(Protocol):
         """Edge-list (gather/scatter) aggregation; supports max/sum."""
         ...
 
-    def edge_softmax_aggregate(self, blocks, z, s_src, s_dst, *, heads: int,
-                               negative_slope: float = 0.2):
-        """GAT attention of every head on the shard grid: out[i, v, head] =
-        Σ_{j,u} α z[j, u, head], α the softmax over the edges into v of
-        LeakyReLU(s_dst[v] + s_src[u]). z (S, n, H·F), scores (S, n, H)."""
+    def edge_softmax_aggregate(self, blocks, tiles, z, s_src, s_dst, *,
+                               heads: int, negative_slope: float = 0.2):
+        """GAT attention of every head: out[i, v, head] = Σ_{j,u} α z[j, u,
+        head], α the softmax over the edges into v of LeakyReLU(s_dst[v] +
+        s_src[u]). The edges are the shard grid's nonzeros, and again the
+        graph's :class:`~repro.kernels.edge_softmax.EdgeTiles` (``tiles``,
+        which a backend may read instead). z (S, n, H·F), scores (S, n, H)."""
         ...
 
     def attention(self, q, k, v, *, causal: bool = True,
@@ -118,9 +120,10 @@ def grid_dtype(platform: str, precision: str | None) -> jnp.dtype:
 
     On a TPU at one bfloat16 pass every consumer of the grid rounds it to
     bfloat16 anyway (the kernels' and XLA's dots), or only tests it
-    ``!= 0`` (the edge softmax), so storing it in bfloat16 changes no
-    operand and halves the bytes each walk reads. Elsewhere, or at a
-    higher precision, the dots see the float32 value: keep float32."""
+    ``!= 0`` (the edge softmax off the pallas backend), so storing it in
+    bfloat16 changes no operand and halves the bytes each walk reads.
+    Elsewhere, or at a higher precision, the dots see the float32 value:
+    keep float32."""
     if platform == "tpu" and precision in _ONE_BF16_PASS:
         return jnp.dtype(jnp.bfloat16)
     return jnp.dtype(jnp.float32)
@@ -224,8 +227,8 @@ class ReferenceBackend:
                          op="max", block_b=128):
         return _gather_loop(edge_src, edge_dst, edge_valid, h, op=op)
 
-    def edge_softmax_aggregate(self, blocks, z, s_src, s_dst, *, heads,
-                               negative_slope=0.2):
+    def edge_softmax_aggregate(self, blocks, tiles, z, s_src, s_dst, *,
+                               heads, negative_slope=0.2):
         return ref.edge_softmax_aggregate(blocks, z, s_src, s_dst,
                                           heads=heads,
                                           negative_slope=negative_slope)
@@ -269,8 +272,8 @@ class JaxBackend(ReferenceBackend):
 
         return jax.vmap(one_dst)(edge_src, edge_dst, edge_valid)
 
-    def edge_softmax_aggregate(self, blocks, z, s_src, s_dst, *, heads,
-                               negative_slope=0.2):
+    def edge_softmax_aggregate(self, blocks, tiles, z, s_src, s_dst, *,
+                               heads, negative_slope=0.2):
         s, n, d = z.shape
         zh = z.reshape(s, n, heads, d // heads)
         outs = [ref.shard_spmm(
@@ -365,19 +368,26 @@ class PallasBackend:
         return _with_ref_vjp(kernel, ref_fn)(
             edge_src, edge_dst, edge_valid, h)
 
-    def edge_softmax_aggregate(self, blocks, z, s_src, s_dst, *, heads,
-                               negative_slope=0.2):
-        def kernel(blocks, z, s_src, s_dst):
+    def edge_softmax_aggregate(self, blocks, tiles, z, s_src, s_dst, *,
+                               heads, negative_slope=0.2):
+        """The kernel walks ``tiles``; the backward differentiates the
+        oracle on ``blocks``."""
+        if tiles is None:
+            raise ValueError("the pallas edge softmax walks the graph's "
+                             "edge tiles: build them with "
+                             "GraphTensors.with_edge_tiles()")
+
+        def kernel(blocks, tiles, z, s_src, s_dst):
             return _es.edge_softmax_aggregate(
-                blocks, z, jnp.swapaxes(s_src, 1, 2), s_dst, heads=heads,
+                tiles, z, s_src, s_dst, heads=heads,
                 negative_slope=negative_slope, interpret=_interpret())
 
-        def ref_fn(blocks, z, s_src, s_dst):
+        def ref_fn(blocks, tiles, z, s_src, s_dst):
             return ref.edge_softmax_aggregate(
                 blocks, z, s_src, s_dst, heads=heads,
                 negative_slope=negative_slope)
 
-        return _with_ref_vjp(kernel, ref_fn)(blocks, z, s_src, s_dst)
+        return _with_ref_vjp(kernel, ref_fn)(blocks, tiles, z, s_src, s_dst)
 
     def attention(self, q, k, v, *, causal=True, window=None, scale=None,
                   bq=128, bk=128):

@@ -18,7 +18,7 @@ import numpy as np
 
 from repro.core.perf_model import GNNERATOR, Platform
 from repro.gnn.executor import plan_model
-from repro.gnn.models import ZooSpec, init_zoo
+from repro.gnn.models import EDGE_SOFTMAX_ARCHS, ZooSpec, init_zoo
 from repro.kernels import registry
 from repro.runtime.cache import GraphStore, default_store
 from repro.runtime.executable import Executable
@@ -72,19 +72,31 @@ def _compile_span(fn):
     first (``dense_first_layers``) and that run the edge softmax
     aggregation (``edge_softmax_layers``, every layer of a GAT), and name
     the device shard grid's dtype (``grid_dtype``, bfloat16 on a TPU at
-    the default matmul precision) and bytes (``grid_bytes``)."""
+    the default matmul precision) and bytes (``grid_bytes``). A model
+    with edge softmax layers also counts the edge tiles' chunks each
+    layer walks (``edge_softmax_chunks``) and their entries' share of the
+    S²n² the dense grid holds (``edge_softmax_entry_share``, in %)."""
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
         with jax.profiler.TraceAnnotation("gnn.compile") as span:
             exe = fn(*args, **kwargs)
-            spec = exe.spec
-            span.set_metadata(
+            spec, gt = exe.spec, exe.gt
+            attention = spec.arch in EDGE_SOFTMAX_ARCHS
+            meta = dict(
                 dense_first_layers=sum(
                     o == "dense-first" for o, _ in exe.producer_orders()),
                 edge_softmax_layers=(len(spec.layer_dims)
-                                     if spec.arch == "gat" else 0),
-                grid_dtype=str(exe.gt.blocks.dtype),
-                grid_bytes=int(exe.gt.blocks.nbytes))
+                                     if attention else 0),
+                grid_dtype=str(gt.blocks.dtype),
+                grid_bytes=int(gt.blocks.nbytes))
+            if attention:
+                chunks = int(gt.tiles.count[0])
+                _, rows, width = gt.tiles.mask.shape
+                meta.update(
+                    edge_softmax_chunks=chunks,
+                    edge_softmax_entry_share=100.0 * chunks * rows * width
+                    / (gt.S * gt.n) ** 2)
+            span.set_metadata(**meta)
             return exe
     return wrapper
 

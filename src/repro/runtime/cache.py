@@ -30,7 +30,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core.engines import GraphTensors
-from repro.gnn.models import graph_signature
+from repro.gnn.models import EDGE_SOFTMAX_ARCHS, graph_signature
 from repro.kernels import registry
 
 
@@ -82,13 +82,16 @@ class GraphStore:
         and ``version`` its mutation generation — both are part of the
         cache key, so a mutated graph can never hit a pre-delta build.
         Features are grouped once and cached alongside; an entry built
-        featureless is upgraded in place on the first featureful request.
+        featureless is upgraded in place on the first featureful request,
+        and one built without edge tiles on the first request of an
+        architecture that walks them (``EDGE_SOFTMAX_ARCHS``).
         ``mutable=True`` builds through a PatchState with ``edge_slack``
         slack capacity so later :meth:`patch` calls stay in-template.
         """
         from repro.core.sharding import shard_graph
 
         norm, loops = graph_signature(arch)
+        tiles = arch in EDGE_SOFTMAX_ARCHS
         key = (graph_key, version, norm, loops, shard_n)
         # fetch-or-build is atomic under the store lock: two threads
         # racing on the same signature must not both pay the sharded
@@ -105,6 +108,9 @@ class GraphStore:
                 self._entries.move_to_end(key)
                 if entry.h_grouped is None and features is not None:
                     entry.h_grouped = entry.gt.group(jnp.asarray(features))
+                if tiles and entry.gt.tiles is None:
+                    entry.gt = entry.gt.with_edge_tiles(
+                        slack=edge_slack if entry.patch_state else 0.0)
                 return entry
             self.stats["misses"] += 1
             t0 = time.perf_counter()
@@ -121,6 +127,9 @@ class GraphStore:
             with TraceAnnotation("gnn.compile.upload"):
                 gt = ps.to_graph_tensors() if mutable \
                     else GraphTensors.from_sharded(sg)
+                if tiles:
+                    gt = gt.with_edge_tiles(
+                        slack=edge_slack if mutable else 0.0)
                 h = gt.group(jnp.asarray(features)) \
                     if features is not None else None
             entry = GraphEntry(gt=gt, h_grouped=h,
@@ -174,8 +183,13 @@ class GraphStore:
                 res = ps.apply(delta)
                 prev = None if res.rebuilt else entry.gt
                 with TraceAnnotation("graph.upload"):
-                    entry.gt = ps.to_graph_tensors(prev=prev,
-                                                   pairs=res.pairs)
+                    gt = ps.to_graph_tensors(prev=prev, pairs=res.pairs)
+                    if entry.gt.tiles is not None:
+                        # the tiles keep their shape while the edges fit
+                        gt = gt.with_edge_tiles(
+                            slack=ps.slack,
+                            like=None if res.rebuilt else entry.gt.tiles)
+                    entry.gt = gt
                     if features is not None and \
                             entry.h_grouped is not None:
                         entry.h_grouped = entry.gt.group(

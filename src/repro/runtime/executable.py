@@ -19,6 +19,7 @@ Executable.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import json
 import pathlib
@@ -31,6 +32,7 @@ import numpy as np
 from repro.core.engines import GraphTensors
 from repro.gnn.executor import ModelPlan
 from repro.gnn.models import ZooSpec
+from repro.kernels.edge_softmax import EdgeTiles
 from repro.kernels.registry import KernelBackend
 from repro.runtime import forward as _fwd
 
@@ -153,8 +155,10 @@ class Executable:
     @staticmethod
     def _graph_args_of(gt: GraphTensors) -> tuple:
         """The graph arrays passed as jit arguments (the mutable part of
-        the forward's signature; S/n stay trace constants)."""
-        return (gt.blocks, gt.edge_src, gt.edge_dst, gt.edge_valid)
+        the forward's signature; S/n stay trace constants), the edge
+        tiles last where the graph has them."""
+        args = (gt.blocks, gt.edge_src, gt.edge_dst, gt.edge_valid)
+        return args if gt.tiles is None else args + (gt.tiles,)
 
     def _graph_args(self) -> tuple:
         return self._graph_args_of(self.gt)
@@ -171,9 +175,10 @@ class Executable:
         n, S = self.gt.n, self.gt.S
 
         # the function's name names the jitted program (``jit_gnn_forward``)
-        def gnn_forward(p, h, blocks, e_src, e_dst, e_valid):
+        def gnn_forward(p, h, blocks, e_src, e_dst, e_valid, tiles=None):
             g = GraphTensors(blocks=blocks, edge_src=e_src, edge_dst=e_dst,
-                             edge_valid=e_valid, num_nodes=S * n, n=n, S=S)
+                             edge_valid=e_valid, num_nodes=S * n, n=n, S=S,
+                             tiles=tiles)
             return _fwd.forward(spec, p, g, h, plans=plan.layers,
                                 backend=backend)
 
@@ -358,11 +363,19 @@ class Executable:
         invalidation targeted; None, or a node-count change, flushes the
         whole softmax cache. ``refine_nodes`` is a placement re-score
         hint for subclasses that maintain a vertex placement (the fennel
-        partitioner in ``dist.gnn``) — ignored here. Returns the number
-        of cached rows invalidated (for the serving engine's stats)."""
+        partitioner in ``dist.gnn``) — ignored here. The edge tiles
+        follow the compiled graph: dropped where it has none, built in
+        its tiles' shape where ``gt`` has none. Returns the number of
+        cached rows invalidated (for the serving engine's stats)."""
+        if self.gt.tiles is None:
+            gt = dataclasses.replace(gt, tiles=None)
+        elif gt.tiles is None:
+            gt = gt.with_edge_tiles(like=self.gt.tiles)
         old, new = self._graph_args(), self._graph_args_of(gt)
-        for name, o, nw in zip(("blocks", "edge_src", "edge_dst",
-                                "edge_valid"), old, new):
+        names = ("blocks", "edge_src", "edge_dst", "edge_valid") + tuple(
+            f"tiles.{f}" for f in EdgeTiles._fields)
+        for name, o, nw in zip(names, jax.tree.leaves(old),
+                               jax.tree.leaves(new)):
             if jnp.shape(o) != jnp.shape(nw) or o.dtype != nw.dtype:
                 raise ValueError(
                     f"graph template break: {name} was "

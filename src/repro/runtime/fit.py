@@ -40,9 +40,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
-from repro.core.engines import GraphTensors, upload_grid
+from repro.core.engines import GraphTensors, upload_edge_tiles, upload_grid
 from repro.core.sharding import shard_graph
-from repro.gnn.models import ZooSpec, graph_signature
+from repro.gnn.models import EDGE_SOFTMAX_ARCHS, ZooSpec, graph_signature
 from repro.graphs.sampler import NeighborSampler, SubgraphBatch
 from repro.runtime import forward as _fwd
 from repro.runtime.executable import (Executable, _flatten_params,
@@ -197,6 +197,7 @@ class TrainableExecutable:
         # appends on every slot)
         e_cap = min(n_sub * n_sub + n_sub, smp.edge_cap + budget)
         self._mb_shape = (s_sub, n_sub, e_cap)
+        tiles = self.spec.arch in EDGE_SOFTMAX_ARCHS
 
         def build(step: int):
             batch: SubgraphBatch = smp.sample(step)
@@ -207,11 +208,19 @@ class TrainableExecutable:
             h = _pad_axis(feats, s_sub * n_sub, 0).reshape(s_sub, n_sub, -1)
             labels = self._labels[batch.nodes]
             mask = batch.seed_mask & self._train_mask[batch.nodes]
-            return (upload_grid(sg.blocks),
-                    jnp.asarray(_pad_axis(sg.edge_src, e_cap, 2)),
-                    jnp.asarray(_pad_axis(sg.edge_dst, e_cap, 2)),
-                    jnp.asarray(_pad_axis(sg.edge_valid, e_cap, 2)),
-                    jnp.asarray(h), jnp.asarray(labels), jnp.asarray(mask))
+            arrays = (upload_grid(sg.blocks),
+                      jnp.asarray(_pad_axis(sg.edge_src, e_cap, 2)),
+                      jnp.asarray(_pad_axis(sg.edge_dst, e_cap, 2)),
+                      jnp.asarray(_pad_axis(sg.edge_valid, e_cap, 2)),
+                      jnp.asarray(h), jnp.asarray(labels),
+                      jnp.asarray(mask))
+            if not tiles:
+                return arrays
+            # one chunk count for every step: the sampler's edge bound
+            # and a self loop a node
+            return arrays + (upload_edge_tiles(
+                sg.edge_src, sg.edge_dst, sg.edge_valid, n_sub,
+                e_cap=smp.edge_cap + budget),)
 
         return build
 
@@ -223,10 +232,10 @@ class TrainableExecutable:
         plans = self.minibatch_plan.layers
 
         def gnn_train_step(params, opt_state, blocks, e_src, e_dst, e_valid,
-                           h, labels, mask):
+                           h, labels, mask, tiles=None):
             gt = GraphTensors(blocks=blocks, edge_src=e_src, edge_dst=e_dst,
                               edge_valid=e_valid, num_nodes=budget,
-                              n=n_sub, S=s_sub)
+                              n=n_sub, S=s_sub, tiles=tiles)
 
             def loss_fn(p):
                 logits = _fwd.forward(spec, p, gt, h, plans=plans,

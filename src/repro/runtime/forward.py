@@ -11,7 +11,7 @@ later env changes.
 A GAT layer extracts z = H W for all heads on the Dense Engine, projects
 the per-node attention scores, and hands both to one Graph Engine op that
 takes every head's softmax over each node's in-edges and its weighted sum
-in one walk of the shard grid (``GraphEngine.edge_softmax_aggregate``).
+in one walk of the graph's edge tiles (``GraphEngine.edge_softmax_aggregate``).
 """
 from __future__ import annotations
 
@@ -24,18 +24,20 @@ import numpy as np
 from repro.core.engines import (DenseEngine, GNNeratorController, GraphEngine,
                                 GraphTensors)
 from repro.core.sharding import shard_graph
-from repro.gnn.models import ZooSpec, graph_signature
+from repro.gnn.models import EDGE_SOFTMAX_ARCHS, ZooSpec, graph_signature
 from repro.kernels.ref import _activate
 from repro.kernels.registry import KernelBackend, resolve
 
 
 def build_graph_tensors(edges: np.ndarray, num_nodes: int, n: int,
                         arch: str) -> GraphTensors:
-    """Shard + normalize a graph for the given zoo architecture."""
+    """Shard + normalize a graph for the given zoo architecture, with its
+    edge tiles where the architecture walks them."""
     norm, loops = graph_signature(arch)
     sg = shard_graph(edges, num_nodes, n, normalize=norm,
                      add_self_loops=loops)
-    return GraphTensors.from_sharded(sg)
+    gt = GraphTensors.from_sharded(sg)
+    return gt.with_edge_tiles() if arch in EDGE_SOFTMAX_ARCHS else gt
 
 
 def layer_activation(spec: ZooSpec, i: int) -> str:

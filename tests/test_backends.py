@@ -4,11 +4,12 @@ shard grids (extending the test_gnn_models oracle pattern one level down:
 the reference backend IS the oracle, the others must match it allclose);
 and `registry.resolve`'s one order: explicit backend, then
 REPRO_KERNEL_BACKEND, then pallas."""
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import registry
+from repro.kernels import edge_softmax, registry
 
 RNG = np.random.default_rng(42)
 TOL = dict(atol=1e-4, rtol=1e-4)
@@ -89,8 +90,17 @@ class TestRegistryParity:
         z = RNG.standard_normal((s, n, heads * f)).astype(np.float32)
         s_src = RNG.standard_normal((s, n, heads)).astype(np.float32)
         s_dst = RNG.standard_normal((s, n, heads)).astype(np.float32)
-        _check("edge_softmax_aggregate", lambda: (blocks, z, s_src, s_dst),
-               heads=heads, negative_slope=0.2)
+        # the edge tiles, which the pallas kernel walks in place of the grid
+        dst, src = np.nonzero((blocks != 0).transpose(0, 2, 1, 3)
+                              .reshape(s * n, s * n))
+        rows, width = edge_softmax.tile_shape(n)
+        host = edge_softmax.edge_tiles(src, dst, s * n, e_cap=src.size,
+                                       rows=rows, width=width)
+        tiles = edge_softmax.EdgeTiles(*map(jnp.asarray, host))
+        tiles = tiles._replace(mask=tiles.mask.astype(jnp.bfloat16))
+        _check("edge_softmax_aggregate",
+               lambda: (blocks, tiles, z, s_src, s_dst), heads=heads,
+               negative_slope=0.2)
 
     @settings(deadline=None, max_examples=4)
     @given(sq=st.sampled_from([32, 64]), heads=st.sampled_from([2, 4]),

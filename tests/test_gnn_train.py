@@ -121,6 +121,23 @@ class TestMiniBatch:
         assert np.isfinite(res.history[-1][1])
         assert res.history[-1][1] < res.history[0][1]
 
+    def test_gat_minibatch_keeps_one_tile_shape_and_trace(self):
+        """GAT mini-batches on the pallas backend: every sampled subgraph
+        gets edge tiles of one shape (the sampler's edge bound sizes
+        them), so the train step traces once and the loss stays finite."""
+        ds = make_dataset("cora", seed=0, scale=0.1)
+        spec = ZooSpec("gat", ds.profile.feature_dim, 8,
+                       ds.profile.num_classes, heads=2, out_heads=1)
+        res = runtime.fit(spec, ds, steps=3, lr=1e-2, batch_nodes=16,
+                          fanout=(4, 3), backend="pallas",
+                          log_every=1, log=lambda s: None)
+        assert np.isfinite([loss for _, loss in res.history]).all()
+        tr = res.trainable
+        shapes = {tuple(a.shape for a in tr.data(step)[-1])
+                  for step in range(4)}
+        assert len(shapes) == 1
+        assert tr._jit_step._cache_size() == 1
+
     def test_minibatch_rejects_mesh(self):
         ds = make_dataset("cora", seed=0, scale=0.1)
         spec = ZooSpec("gcn", ds.profile.feature_dim, 8,

@@ -15,7 +15,7 @@ from repro.core.engines import upload_grid
 from repro.core.sharding import shard_graph
 from repro.gnn.models import ZooSpec
 from repro.graphs.patch import PatchState
-from repro.kernels import registry
+from repro.kernels import edge_softmax, registry
 
 BF16, F32 = jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)
 NUM, N = 40, 16
@@ -85,9 +85,17 @@ def test_a_bfloat16_grid_computes_what_its_float32_upcast_does(consumer):
         z = h[..., :heads * 6]
         s_src = jnp.asarray(rng.standard_normal((s, N, heads)), jnp.float32)
         s_dst = jnp.asarray(rng.standard_normal((s, N, heads)), jnp.float32)
+        # the edge tiles the kernel walks in place of the grid
+        dst, src = np.nonzero(np.asarray(blocks != 0).transpose(0, 2, 1, 3)
+                              .reshape(s * N, s * N))
+        rows, width = edge_softmax.tile_shape(N)
+        host = edge_softmax.edge_tiles(src, dst, s * N, e_cap=src.size,
+                                       rows=rows, width=width)
+        tiles = edge_softmax.EdgeTiles(*map(jnp.asarray, host))
+        tiles = tiles._replace(mask=tiles.mask.astype(BF16))
 
         def call(a):
-            return be.edge_softmax_aggregate(a, z, s_src, s_dst,
+            return be.edge_softmax_aggregate(a, tiles, z, s_src, s_dst,
                                              heads=heads)
     else:
         def call(a):

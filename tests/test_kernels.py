@@ -7,7 +7,9 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ref, registry
 from repro.kernels.dense_engine import dense_engine_matmul
-from repro.kernels.edge_softmax import block_rows, edge_softmax_aggregate
+from repro.kernels.edge_softmax import (EdgeTiles, chunk_bound,
+                                       edge_softmax_aggregate, edge_tiles,
+                                       tile_shape)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_gnn import fused_gnn_layer
 from repro.kernels.seg_gather import seg_gather_aggregate
@@ -97,12 +99,47 @@ def _attention_graph(s: int, n: int, heads: int, f: int):
     return blocks, z, s_src, s_dst
 
 
-@pytest.mark.parametrize("n,rows", [(16, 16), (1000, 200), (1024, 512),
-                                    (2048, 256)])
-def test_edge_softmax_row_tile(n, rows):
-    """Destination rows per step: a (rows, n) tile of at most 2 MiB of
-    float32, a multiple of 8 dividing n, or n itself."""
-    assert block_rows(n) == rows
+def _grid_edges(blocks):
+    """(src, dst) global node ids of the nonzeros of an (S, S, n, n) grid."""
+    s, _, n, _ = blocks.shape
+    dst, src = np.nonzero((blocks != 0).transpose(0, 2, 1, 3)
+                          .reshape(s * n, s * n))
+    return src, dst
+
+
+def _tiles(blocks, *, rows=None, width=None, e_cap=None):
+    """The device edge tiles of a dense grid, in the tile rule's shape
+    unless ``rows`` and ``width`` are given, sized for its edge count
+    unless ``e_cap`` is."""
+    s, _, n, _ = blocks.shape
+    src, dst = _grid_edges(blocks)
+    r, w = tile_shape(n)
+    host = edge_tiles(src, dst, s * n, e_cap=e_cap or src.size,
+                      rows=rows or r, width=width or w)
+    tiles = EdgeTiles(*map(jnp.asarray, host))
+    return tiles._replace(mask=tiles.mask.astype(jnp.bfloat16))
+
+
+@pytest.mark.parametrize("n,shape", [(16, (16, 128)), (1000, (200, 128)),
+                                     (1024, (256, 128)), (2048, (256, 128))])
+def test_edge_softmax_row_tile(n, shape):
+    """Destination rows and source columns of a chunk: rows a multiple of
+    8 dividing n (or n itself), at most 256, and 128 columns."""
+    assert tile_shape(n) == shape
+
+
+def _check_edge_softmax(blocks, z, s_src, s_dst, heads, tiles):
+    out = np.asarray(edge_softmax_aggregate(
+        tiles, z, s_src, s_dst, heads=heads, negative_slope=0.2,
+        interpret=True))
+    exp = np.asarray(ref.edge_softmax_aggregate(
+        blocks, z, s_src, s_dst, heads=heads, negative_slope=0.2))
+    assert np.isfinite(out).all()
+    # float32 on both sides (interpret mode); the kernel sums chunk by
+    # chunk and rescales by exp(m - m'), the oracle in one pass, so only
+    # rounding differs: exp of scores near ±60 is exact to about 1e-5
+    np.testing.assert_allclose(out, exp, rtol=5e-5, atol=5e-5)
+    return out
 
 
 @pytest.mark.parametrize("s", [1, 3])
@@ -111,21 +148,103 @@ def test_edge_softmax_row_tile(n, rows):
 def test_edge_softmax_aggregate(heads, f, s):
     n = 16
     blocks, z, s_src, s_dst = _attention_graph(s, n, heads, f)
-    # 8 rows at a time: two row tiles per destination shard
-    out = np.asarray(edge_softmax_aggregate(
-        blocks, z, np.swapaxes(s_src, 1, 2), s_dst, heads=heads,
-        negative_slope=0.2, rows=8, interpret=True))
-    exp = np.asarray(ref.edge_softmax_aggregate(
-        blocks, z, s_src, s_dst, heads=heads, negative_slope=0.2))
-    assert np.isfinite(out).all()
+    out = _check_edge_softmax(blocks, z, s_src, s_dst, heads,
+                              _tiles(blocks))
     # padding rows with no edge: exactly 0, never NaN
     assert (out[-1, n - 3:] == 0).all()
     # a self loop alone weighs 1
     np.testing.assert_allclose(out[0, 2], z[0, 2], rtol=1e-6, atol=1e-6)
-    # float32 on both sides (interpret mode); the kernel sums shard by
-    # shard and rescales by exp(m - m'), the oracle in one pass, so only
-    # rounding differs: exp of scores near ±60 is exact to about 1e-5
-    np.testing.assert_allclose(out, exp, rtol=5e-5, atol=5e-5)
+
+
+@pytest.mark.parametrize("f", [3, 8])
+@pytest.mark.parametrize("heads", [1, 8])
+@pytest.mark.parametrize("layout", ["hub", "padded", "empty_tile"])
+def test_edge_softmax_walks_compacted_tiles(layout, heads, f):
+    """Chunks of 8 rows and 8 columns on a 2 x 16 grid: a hub row whose 21
+    sources span three chunks of its row tile; chunks past those in use
+    (room for three times the edges), which the kernel skips; and a row
+    tile (the last shard's rows 8-15) with no edge, whose one empty chunk
+    writes its rows as 0."""
+    s, n = 2, 16
+    blocks, z, s_src, s_dst = _attention_graph(s, n, heads, f)
+    e_cap = None
+    if layout == "hub":
+        blocks[0, :, 4, :] = 0.0
+        blocks[0, 0, 4, :11] = 1.0
+        blocks[0, 1, 4, 6:] = 1.0
+    elif layout == "padded":
+        e_cap = 3 * int((blocks != 0).sum())
+    else:
+        blocks[-1, :, 8:, :] = 0.0
+    tiles = _tiles(blocks, rows=8, width=8, e_cap=e_cap)
+    used, t_max = int(tiles.count[0]), tiles.cols.shape[0]
+    tile_of = np.asarray(tiles.chunk_tile)[:used]
+    if layout == "hub":
+        assert (tile_of == 0).sum() >= 3
+    elif layout == "padded":
+        assert t_max > 2 * used
+    else:
+        assert (tile_of == 3).sum() == 1
+        assert not np.asarray(tiles.mask)[np.flatnonzero(tile_of == 3)].any()
+    out = _check_edge_softmax(blocks, z, s_src, s_dst, heads, tiles)
+    if layout == "empty_tile":
+        assert (out[-1, 8:] == 0).all()
+    np.testing.assert_allclose(out[0, 2], z[0, 2], rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_edge_tiles_scatter_back_to_the_grid(seed):
+    """Scattering each chunk's mask back through its columns gives the
+    grid's nonzeros exactly, the chunks in use stay within the static
+    bound, each row tile's chunks lie in a row between its first and its
+    last flag, and the chunks past the count hold no edge."""
+    r = np.random.default_rng(seed)
+    s = int(r.integers(1, 4))
+    n = int(r.choice([8, 16, 32]))
+    blocks = (r.random((s, s, n, n)) < r.uniform(0.01, 0.4)).astype(
+        np.float32)
+    blocks[:, :, int(r.integers(n)), :] = 1.0       # a hub row
+    rows = int(r.choice([8, n]))
+    width = int(r.choice([8, 16, 128]))
+    src, dst = _grid_edges(blocks)
+    t = edge_tiles(src, dst, s * n, e_cap=src.size, rows=rows, width=width)
+    used = int(t.count[0])
+    assert used <= t.cols.shape[0] == chunk_bound(s * n, src.size, rows,
+                                                  width)
+    back = np.zeros((s * n, s * n), bool)
+    k, v, c = np.nonzero(t.mask[:used])
+    back[t.chunk_tile[k] * rows + v, t.cols[k, c]] = True
+    want = (blocks != 0).transpose(0, 2, 1, 3).reshape(s * n, s * n)
+    np.testing.assert_array_equal(back, want)
+    assert not t.mask[used:].any()
+    tile_of = t.chunk_tile[:used]
+    assert (np.diff(tile_of) >= 0).all()
+    assert set(tile_of) == set(range(s * n // rows))
+    np.testing.assert_array_equal(
+        t.first[:used], np.r_[1, np.diff(tile_of) != 0])
+    np.testing.assert_array_equal(
+        t.last[:used], np.r_[np.diff(tile_of) != 0, 1])
+
+
+def test_edge_tiles_shapes_depend_on_the_bound_alone():
+    """Two graphs of one (S, n, E_cap, R, C), one of them with no edge,
+    give tiles of one shape; edges that need more chunks than E_cap
+    allows are refused."""
+    r = np.random.default_rng(0)
+    s, n, e_cap = 3, 16, 400
+    shapes = []
+    for density in (0.0, 0.1):
+        blocks = (r.random((s, s, n, n)) < density).astype(np.float32)
+        src, dst = _grid_edges(blocks)
+        assert src.size <= e_cap
+        t = edge_tiles(src, dst, s * n, e_cap=e_cap, rows=8, width=16)
+        shapes.append([a.shape for a in t])
+    assert shapes[0] == shapes[1]
+    assert shapes[0][1] == (chunk_bound(s * n, e_cap, 8, 16), 8, 16)
+    # 48 distinct sources in each of 6 row tiles: 18 chunks, 7 allowed
+    src, dst = _grid_edges(np.ones((s, s, n, n), np.float32))
+    with pytest.raises(ValueError, match="chunks"):
+        edge_tiles(src, dst, s * n, e_cap=16, rows=8, width=16)
 
 
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])

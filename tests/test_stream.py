@@ -100,6 +100,40 @@ def test_mutation_forward_parity_no_retrace(arch):
     np.testing.assert_array_equal(out, np.asarray(fresh.forward()))
 
 
+def test_gat_logits_after_a_patch_equal_a_fresh_build():
+    """GAT on the pallas backend (its kernel walks the edge tiles): after
+    edge churn through GraphStore.patch the patched tiles keep their
+    shape, the forward does not retrace, and its logits are a fresh
+    compile's of the post-delta graph."""
+    ds = _ds()
+    spec = _spec(ds, "gat")
+    store = runtime.GraphStore()
+    exe = runtime.compile(spec, ds, backend="pallas", max_shard_n=64,
+                          store=store, graph_key="g", seed=0,
+                          mutable_graph=True)
+    exe.forward()
+    traces = exe._jit_forward._cache_size()
+    shapes = [a.shape for a in exe.gt.tiles]
+    norm, loops = graph_signature("gat")
+    rng = np.random.default_rng(2)
+    for _ in range(2):
+        delta = random_delta(ds, rng, edge_ops=6)
+        old_v = ds.version
+        apply_to_graph_data(ds, delta)
+        patched = store.patch("g", delta, old_version=old_v,
+                              new_version=ds.version)
+        entry, res = patched[(norm, loops, exe.plan.shard_n)]
+        assert not res.rebuilt
+        assert [a.shape for a in entry.gt.tiles] == shapes
+        exe.update_graph(entry.gt, entry.h_grouped)
+    out = np.asarray(exe.forward())
+    assert exe._jit_forward._cache_size() == traces
+    fresh = runtime.compile(spec, ds, backend="pallas", max_shard_n=64,
+                            params=exe.params, store=runtime.GraphStore())
+    np.testing.assert_allclose(out, np.asarray(fresh.forward()),
+                               rtol=1e-6, atol=1e-6)
+
+
 # --------------------------------------------------------------------------
 # versioned store keys (the in-place-mutation collision bugfix)
 # --------------------------------------------------------------------------

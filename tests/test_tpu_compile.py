@@ -198,18 +198,34 @@ def test_seg_gather_aggregate_compiles(graphs, one_chip, name, layer):
                                   sharding=one_chip))
 
 
+def _edge_tiles(graphs, name, one_chip):
+    """(S, n, tile avals) of a graph's edge tiles, in the tile rule's
+    shape and sized for its edges and self loops."""
+    ds, _, plan = graphs[name]
+    s, n = plan.layers[0].S, plan.shard_n
+    rows, width = edge_softmax.tile_shape(n)
+    t = edge_softmax.chunk_bound(
+        s * n, ds.edges.shape[0] + ds.profile.num_nodes, rows, width)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    return s, n, edge_softmax.EdgeTiles(
+        i32((t, width)), sds((t, rows, width), jnp.bfloat16), i32((t,)),
+        i32((t,)), i32((t,)), i32((1,)))
+
+
 @pytest.mark.parametrize("heads,f", [(8, 8), (8, 3)])
 def test_edge_softmax_aggregate_compiles(graphs, one_chip, heads, f):
     """GAT's attention at full pubmed shapes: layer 0's 8 heads of 8 and
-    the output layer's 8 heads of 3, over the 20 x 20 grid of 1024."""
-    s, n = graphs["pubmed"][2].layers[0].S, graphs["pubmed"][2].shard_n
+    the output layer's 8 heads of 3, over the edge tiles of the 20 x 20
+    grid of 1024."""
+    s, n, tiles = _edge_tiles(graphs, "pubmed", one_chip)
     assert (s, n) == (20, 1024)
     f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
                             sharding=one_chip)
     _compile(functools.partial(edge_softmax.edge_softmax_aggregate,
                                heads=heads, negative_slope=0.2,
                                interpret=False),
-             f32((s, s, n, n)), f32((s, n, heads * f)), f32((s, heads, n)),
+             tiles, f32((s, n, heads * f)), f32((s, n, heads)),
              f32((s, n, heads)))
 
 
@@ -333,11 +349,11 @@ def test_pallas_calls_carry_kernel_names(graphs, one_chip, monkeypatch,
             f32((s, n, dp)))
         want = {seg_gather.KERNEL_NAME}
     elif kernel == "edge_softmax":
+        s, n, tiles = _edge_tiles(graphs, "cora", one_chip)
         compiled = _compile(functools.partial(
             edge_softmax.edge_softmax_aggregate, heads=8,
             negative_slope=0.2, interpret=False),
-            f32((s, s, n, n)), f32((s, n, 64)), f32((s, 8, n)),
-            f32((s, n, 8)))
+            tiles, f32((s, n, 64)), f32((s, n, 8)), f32((s, n, 8)))
         want = {edge_softmax.KERNEL_NAME}
     else:
         compiled = _train_step_compiled(graphs, one_chip, monkeypatch)
@@ -352,8 +368,8 @@ def test_pallas_calls_carry_kernel_names(graphs, one_chip, monkeypatch,
 def test_kernels_compile_on_a_bfloat16_grid(graphs, one_chip, kernel):
     """The grid a TPU stores at the default matmul precision, at full
     pubmed shapes: each kernel takes its bfloat16 tiles (the edge
-    softmax compares them with 0 on v5e, whose VPU has no bfloat16
-    arithmetic)."""
+    softmax those of its edge tiles' mask, which it compares with 0 on
+    v5e, whose VPU has no bfloat16 arithmetic)."""
     s, n, dp, bb, f = _layer_shapes(graphs, "pubmed", 0)
     f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
                             sharding=one_chip)
@@ -367,10 +383,12 @@ def test_kernels_compile_on_a_bfloat16_grid(graphs, one_chip, kernel):
                                    activation="relu", interpret=False),
                  grid, f32((s, n, dp)), f32((dp, f)))
     else:
+        _, _, tiles = _edge_tiles(graphs, "pubmed", one_chip)
+        assert tiles.mask.dtype == grid.dtype
         _compile(functools.partial(edge_softmax.edge_softmax_aggregate,
                                    heads=8, negative_slope=0.2,
                                    interpret=False),
-                 grid, f32((s, n, 64)), f32((s, 8, n)), f32((s, n, 8)))
+                 tiles, f32((s, n, 64)), f32((s, n, 8)), f32((s, n, 8)))
 
 
 def _entry_grid_instructions(text: str, s: int, n: int) -> list[tuple]:

@@ -135,6 +135,26 @@ def test_compile_counts_its_edge_softmax_layers(spans):
     assert counts.count(2) == 1 and set(counts) == {0, 2}, counts
 
 
+def test_compile_counts_its_edge_tiles(spans):
+    """The GAT compile counts the edge tiles' chunks each layer walks and
+    their share of the S²n² pairs of the dense grid: at least one chunk
+    of 64 rows and 128 columns a row tile, and no more than the grid's
+    own entries; no gcn compile carries either number."""
+    gat = [st for n, _, _, st in spans
+           if n == "gnn.compile" and st.get("edge_softmax_layers")]
+    gcn = [st for n, _, _, st in spans
+           if n == "gnn.compile" and not st.get("edge_softmax_layers")]
+    assert len(gat) == 1 and len(gcn) >= 2
+    (st,) = gat
+    side = math.isqrt(st["grid_bytes"] // 4)
+    chunks, share = st["edge_softmax_chunks"], st["edge_softmax_entry_share"]
+    assert chunks >= side // 64
+    assert share == pytest.approx(100.0 * chunks * 64 * 128 / side ** 2)
+    for st in gcn:
+        assert "edge_softmax_chunks" not in st
+        assert "edge_softmax_entry_share" not in st
+
+
 def test_compile_names_its_grid_dtype_and_bytes(spans):
     """Every compile here, on the CPU, keeps the (S, S, n, n) grid in
     float32: S·n is a multiple of the shard size 64, and the bytes are
